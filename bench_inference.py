@@ -45,9 +45,9 @@ import numpy as np
 SMOKE = os.environ.get("INFERENCE_SMOKE") == "1"
 import jax
 
-from hefl_tpu.utils.probe import setup_backend
+from hefl_tpu.utils.device import select_platform
 
-setup_backend("bench_inference.py", "cpu" if SMOKE else None)
+select_platform("bench_inference.py", cpu=SMOKE)
 
 REPS = int(os.environ.get("INFERENCE_REPS", "20"))
 ARTIFACT_PATH = os.environ.get("BENCH_INFER_PATH", "BENCH_INFER.json")

@@ -77,14 +77,13 @@ def main() -> None:
     seed = int(os.environ.get("FLAGSHIP_SEED", "0"))
     smoke = os.environ.get("FLAGSHIP_SMOKE") == "1"
     platform = os.environ.get("FLAGSHIP_PLATFORM", "cpu")
-    from hefl_tpu.utils.probe import setup_backend
+    from hefl_tpu.utils.device import select_platform, setup_compile_cache
 
-    setup_backend("flagship_acc.py", platform or None)
+    select_platform("flagship_acc.py", cpu=platform == "cpu")
     import jax
     import jax.numpy as jnp
 
-    jax.config.update("jax_compilation_cache_dir", ".jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    setup_compile_cache()
 
     from hefl_tpu.ckks.keys import keygen
     from hefl_tpu.ckks.packing import PackSpec

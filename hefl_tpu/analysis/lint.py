@@ -239,18 +239,14 @@ def _out_size(eqn) -> int:
 
 
 def _eqn_sources(eqn) -> list[str]:
-    """`file:function` strings of an eqn's user traceback frames (empty
-    when source info is unavailable — source-scoped allowlist entries then
-    conservatively do NOT match)."""
-    try:
-        from jax._src import source_info_util
-
-        return [
-            f"{f.file_name}:{f.function_name}"
-            for f in source_info_util.user_frames(eqn.source_info)
-        ]
-    except Exception:
+    """`file:function` strings of an eqn's traceback frames (empty when the
+    eqn carries no traceback — source-scoped allowlist entries then
+    conservatively do NOT match). JAX-internal frames are left in: no
+    allowlist pattern names one."""
+    tb = eqn.source_info.traceback
+    if tb is None:
         return []
+    return [f"{f.file_name}:{f.function_name}" for f in tb.frames]
 
 
 def _allowed(

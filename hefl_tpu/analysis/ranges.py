@@ -16,7 +16,7 @@ abstract interpretation of the real jaxprs:
     rather than wrapped), floats with ±inf for float dtypes.
   * :func:`eval_jaxpr_ranges` — the interpreter: propagates intervals
     through add/mul/shift/and/or/select/reduce/convert/psum/... including
-    sub-jaxprs (pjit, shard_map, custom_{j,v}jp, cond branches), recording
+    sub-jaxprs (jit, shard_map, custom_{j,v}jp, cond branches), recording
     a :class:`RangeFinding` at the exact eqn whose INTEGER output interval
     escapes the declared ceiling or its dtype — the "offending op".
   * **loop fixpoints** (ISSUE 12) — `lax.scan` / `lax.while_loop` carries
@@ -543,9 +543,8 @@ class _RangeInterpreter:
             if outs is not None and len(outs) == len(eqn.outvars):
                 return outs
             return [TOP for _ in eqn.outvars]
-        if name in ("pjit", "closed_call", "custom_jvp_call",
-                    "custom_vjp_call", "remat", "checkpoint", "shard_map",
-                    "core_call"):
+        if name in ("jit", "closed_call", "custom_jvp_call",
+                    "custom_vjp_call", "remat2", "shard_map", "core_call"):
             sub = _sub_jaxpr(eqn.params)
             if sub is not None:
                 if name == "shard_map":
@@ -888,7 +887,7 @@ def eval_jaxpr_ranges(
     check_dtype: bool = True,
     axis_sizes: dict | None = None,
 ) -> RangeResult:
-    """Propagate intervals through `closed_jaxpr` (recursing into pjit /
+    """Propagate intervals through `closed_jaxpr` (recursing into jit /
     shard_map / custom-vjp sub-jaxprs).
 
     `ceiling` declares the exact-integer carrier bound every integer-dtype
@@ -968,7 +967,7 @@ def certify_packing(
     probe, args = quantize.packing_sum_probe(bits, k, fbits, guard_eff, clients)
     # x64 only for TRACING: the probe's avals must be able to NAME an
     # int64 carrier; the analysis itself computes in unbounded ints.
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(probe)(*args)
 
     qm = quantize.qmax(bits)
@@ -1184,7 +1183,7 @@ def certify_fold_inductive(
     prime = int(prime)
     canonical = Interval(0, prime - 1)
     probe, args = stream.fold_loop_probe(prime)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(probe)(*args)
 
     res = eval_jaxpr_ranges(
@@ -1372,7 +1371,7 @@ def certify_inference(
     probe, args = he_inference.rotation_ladder_range_probe(
         prime, digit_bits, num_digits
     )
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(probe)(*args)
 
     in_ivs = [
@@ -1457,7 +1456,7 @@ def certify_inference(
     hprobe, hargs = ckks_ops.hoisted_gadget_probe(
         prime, digit_bits, num_digits
     )
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         hclosed = jax.make_jaxpr(hprobe)(*hargs)
     # The hoisted path skips centering, so its digits must be canonical AS
     # EXTRACTED: the 2**w gadget bound has to sit inside [0, p-1].
@@ -1481,7 +1480,7 @@ def certify_inference(
     mprobe, margs = he_inference.mlp_bsgs_range_probe(
         prime, digit_bits, num_digits
     )
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         mclosed = jax.make_jaxpr(mprobe)(*margs)
     probe_checks(
         "mlp compose", mclosed,
@@ -1570,7 +1569,7 @@ def certify_keyswitch(
     canonical = Interval(0, prime - 1)
     wall = (1 << quantize.MAX_PACKED_BITS) - 1
     probe, args = ops.keyswitch_gadget_probe(prime, digit_bits, num_digits)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(probe)(*args)
 
     res = eval_jaxpr_ranges(
@@ -1692,7 +1691,7 @@ def certify_transciphering(
     probe, args = hhe_cipher.transcipher_sum_probe(
         bits, k, fbits, guard_eff, clients
     )
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(probe)(*args)
 
     noise_per_client = (1 << max(guard_bits - 1, 0)) - 1
@@ -1740,7 +1739,7 @@ def certify_transciphering(
     # their uint32 carriers at every iteration of the service's lifetime,
     # established as a while-loop post-fixpoint, not sampled at one round.
     cprobe, cargs = hhe_cipher.keystream_counter_probe()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         cclosed = jax.make_jaxpr(cprobe)(*cargs)
     word = Interval(0, (1 << 31) - 1)
     cres = eval_jaxpr_ranges(cclosed, [

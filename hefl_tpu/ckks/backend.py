@@ -75,8 +75,9 @@ def _autoselect(ctx) -> str:
     from hefl_tpu.utils.roofline import steady_seconds
 
     with jax.ensure_compile_time_eval():
-        # Probe inputs built inside the eval context (concrete even when an
-        # outer jit is tracing — see augment._autoselect_backend).
+        # Probe INPUTS built inside the eval context (concrete even when an
+        # outer jit is tracing — see augment._autoselect_backend). The
+        # candidates themselves are compiled OUTSIDE it, below.
         b, num_l, n = _probe_shapes(ctx)
         rng = np.random.default_rng(0)
         p_col = np.asarray(ctx.ntt.p)[:, 0]
@@ -92,24 +93,6 @@ def _autoselect(ctx) -> str:
         ks_b = mk(num_c, num_l, n)
         ks_a = mk(num_c, num_l, n)
         coeff = mk(b, num_l, n)
-        # BOTH candidates jitted: production encrypt runs inside jitted
-        # round programs, so an eager per-primitive XLA op chain would time
-        # dispatch overhead (~100 dispatches for the 4 stage-unrolled NTTs)
-        # against the kernel's single dispatch and bias the probe.
-        cands = {
-            "xla": jax.jit(lambda mm: ops._encrypt_core_xla(
-                ctx, mm, u, e0, e1, bk, ak)[0]),
-            "pallas": jax.jit(lambda mm: pallas_ntt.encrypt_fused_pallas(
-                ctx.ntt, mm, u, e0, e1, bk, ak)[0]),
-        }
-        ks_cands = {
-            "xla": jax.jit(lambda cc: ops._keyswitch_coeff_xla(
-                ctx, cc, ks_b, ks_a)[0]),
-            "pallas": jax.jit(lambda cc: pallas_ntt.keyswitch_fused_pallas(
-                ctx.ntt, cc, ks_b, ks_a,
-                digit_bits=ctx.ksk_digit_bits,
-                num_digits=ctx.ksk_num_digits)[0]),
-        }
         # Hoisted-rotation probe (ISSUE 18): the batched digit x key
         # product sweep the BSGS serving path dispatches per query — a
         # small step count suffices, the kernel's per-step work is what
@@ -119,21 +102,45 @@ def _autoselect(ctx) -> str:
         h_d = mk(num_r, num_l, n)
         h_b = mk(num_s, num_r, num_l, n)
         h_a = mk(num_s, num_r, num_l, n)
-        hoist_cands = {
-            "xla": jax.jit(lambda cc: ops._hoisted_products_xla(
-                ctx, cc, h_d, h_b, h_a)[0]),
-            "pallas": jax.jit(lambda cc: pallas_ntt.hoisted_rotations_pallas(
-                ctx.ntt, cc, h_d, h_b, h_a)[0]),
-        }
         single = mk(num_l, n)
-        timings = {name: steady_seconds(fn, m) for name, fn in cands.items()}
-        ks_timings = {
-            name: steady_seconds(fn, coeff) for name, fn in ks_cands.items()
-        }
-        hoist_timings = {
-            name: steady_seconds(fn, single)
-            for name, fn in hoist_cands.items()
-        }
+    # BOTH candidates jitted: production encrypt runs inside jitted
+    # round programs, so an eager per-primitive XLA op chain would time
+    # dispatch overhead (~100 dispatches for the 4 stage-unrolled NTTs)
+    # against the kernel's single dispatch and bias the probe. Each is
+    # compiled ahead of time, outside the eval context: a Pallas kernel
+    # cannot be traced under it (its grid primitives have no eager
+    # evaluation rule), and a compiled executable runs concretely on
+    # concrete inputs whatever outer trace is active.
+    def timed(fn, arg) -> float:
+        return steady_seconds(jax.jit(fn).lower(arg).compile(), arg)
+
+    cands = {
+        "xla": lambda mm: ops._encrypt_core_xla(
+            ctx, mm, u, e0, e1, bk, ak)[0],
+        "pallas": lambda mm: pallas_ntt.encrypt_fused_pallas(
+            ctx.ntt, mm, u, e0, e1, bk, ak)[0],
+    }
+    ks_cands = {
+        "xla": lambda cc: ops._keyswitch_coeff_xla(
+            ctx, cc, ks_b, ks_a)[0],
+        "pallas": lambda cc: pallas_ntt.keyswitch_fused_pallas(
+            ctx.ntt, cc, ks_b, ks_a,
+            digit_bits=ctx.ksk_digit_bits,
+            num_digits=ctx.ksk_num_digits)[0],
+    }
+    hoist_cands = {
+        "xla": lambda cc: ops._hoisted_products_xla(
+            ctx, cc, h_d, h_b, h_a)[0],
+        "pallas": lambda cc: pallas_ntt.hoisted_rotations_pallas(
+            ctx.ntt, cc, h_d, h_b, h_a)[0],
+    }
+    timings = {name: timed(fn, m) for name, fn in cands.items()}
+    ks_timings = {
+        name: timed(fn, coeff) for name, fn in ks_cands.items()
+    }
+    hoist_timings = {
+        name: timed(fn, single) for name, fn in hoist_cands.items()
+    }
     _AUTO_TIMINGS_MS = {}
     for name in HE_BACKENDS:
         _AUTO_TIMINGS_MS[name] = round(

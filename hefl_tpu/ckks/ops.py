@@ -726,7 +726,7 @@ def keyswitch_gadget_probe(prime: int, digit_bits: int, num_digits: int):
     the 2**w gadget bound and the canonical range [0, p-1] — the fused
     kernel's `sub_mod` centering assumes canonical digits, so a digit
     width that overflows the prime is refuted here, statically.
-    Trace under `jax.experimental.enable_x64()`. -> (fn, example_args).
+    Trace under `jax.enable_x64(True)`. -> (fn, example_args).
     """
     p = int(prime)
     w = int(digit_bits)
@@ -768,7 +768,7 @@ def hoisted_gadget_probe(prime: int, digit_bits: int, num_digits: int):
     accumulator so the invariant holds for ANY number of hoisted steps.
     Int64 carrier, `%` as the allowlisted probe modulo, exactly like the
     ladder and key-switch probes. Trace under
-    `jax.experimental.enable_x64()`. -> (fn, example_args).
+    `jax.enable_x64(True)`. -> (fn, example_args).
 
     Returning the raw digits lets the certificate check them against BOTH
     the 2**w gadget bound and the canonical range [0, p-1]: the hoisted

@@ -398,7 +398,7 @@ def packing_sum_probe(
     63 for unsafe configs — that is the point: tracing still succeeds
     (shift amounts are small constants) and the audited loop-body pass
     reports the shift as the offending op. Trace under
-    `jax.experimental.enable_x64()` so the int64 carrier is nameable.
+    `jax.enable_x64(True)` so the int64 carrier is nameable.
     -> (fn, example_args).
     """
     import jax as _jax

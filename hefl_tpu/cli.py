@@ -565,15 +565,11 @@ def config_from_args(args: argparse.Namespace) -> ExperimentConfig:
 
 
 def main(argv: list[str] | None = None) -> int:
-    # Persistent XLA compilation cache (same default as bench.py/results.py):
-    # the flagship round program costs ~40 s to compile; repeated CLI runs
-    # must not re-pay it. HEFL_COMPILE_CACHE= (empty) disables.
-    cache_dir = os.environ.get("HEFL_COMPILE_CACHE", ".jax_cache")
-    if cache_dir:
-        import jax
+    # Persistent XLA compilation cache: repeated CLI runs must not re-pay
+    # the round program's compile.
+    from hefl_tpu.utils.device import setup_compile_cache
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    setup_compile_cache()
     args = build_parser().parse_args(argv)
     if args.preset is not None:
         from hefl_tpu.presets import PRESETS

@@ -22,7 +22,7 @@ Row-shift backends (`HEFL_AUG_SHIFT` / `TrainConfig.aug_backend`):
                   the width axis (an XLA gather on ONE axis, not the 2-D
                   general gather). This is exactly Keras' bilinear kernel,
                   convex (no overshoot, no clamp pass), and O(W) per row.
-                  Measured fastest everywhere tried so far (PROFILE.md:
+                  Measured fastest everywhere tried so far (on CPU only:
                   the FFT shear cost 120 ms/batch on CPU; this path is
                   >20x cheaper at the same shape).
   * ``fft``     — bandlimited (sinc) shift through XLA's native real FFT:

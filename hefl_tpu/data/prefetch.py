@@ -38,7 +38,7 @@ def _key(arrays) -> tuple[int, ...]:
     return tuple(id(a) for a in arrays)
 
 
-def _put(arrays) -> tuple:
+def _put(arrays, sharding=None) -> tuple:
     # device_put is async: it enqueues the transfer and returns
     # immediately; consumers block only when they actually need the bytes.
     # Each entry is (buffer, owned): `owned` is False when the "copy" was
@@ -46,7 +46,9 @@ def _put(arrays) -> tuple:
     # which case retirement must NOT delete it — it is the caller's.
     out = []
     for a in arrays:
-        buf = jax.device_put(jnp.asarray(a))
+        buf = jax.device_put(
+            a if sharding is not None else jnp.asarray(a), sharding
+        )
         out.append((buf, buf is not a))
     return tuple(out)
 
@@ -66,7 +68,11 @@ def _delete(entries) -> None:
 
 
 class RoundPrefetcher:
-    def __init__(self):
+    def __init__(self, sharding=None):
+        """`sharding` (e.g. `parallel.client_sharding(mesh)`) places every
+        staged array with it — each device receives only its own client
+        block. None keeps the default single-device placement."""
+        self._sharding = sharding
         self._cur = self._next = None
         self._cur_key = self._next_key = None
 
@@ -78,7 +84,7 @@ class RoundPrefetcher:
             return
         if self._next is not None:
             _delete(self._next)  # superseded before use
-        self._next, self._next_key = _put(arrays), key
+        self._next, self._next_key = _put(arrays, self._sharding), key
 
     def get(self, *arrays) -> tuple:
         """Device buffers for this round's arrays (prefetched if staged,
@@ -94,7 +100,7 @@ class RoundPrefetcher:
             self._cur, self._cur_key = self._next, self._next_key
             self._next = self._next_key = None
         else:
-            self._cur, self._cur_key = _put(arrays), key
+            self._cur, self._cur_key = _put(arrays, self._sharding), key
         if stale is not None:
             _delete(stale)
         return _bufs(self._cur)

@@ -60,6 +60,7 @@ from hefl_tpu.obs import metrics as obs_metrics
 from hefl_tpu.obs import scopes as obs_scopes
 from hefl_tpu.parallel import (
     client_mesh_size,
+    client_sharding,
     ct_shard_count,
     make_mesh,
     make_mesh_2d,
@@ -567,7 +568,10 @@ def run_experiment(
     # holds one resident copy (the historical jnp.asarray-once behavior);
     # per-round data (client sampling, streaming shards) overlaps its copy
     # with the previous round's compute via prefetcher.prefetch below.
-    prefetcher = RoundPrefetcher()
+    # Placed with the mesh's client sharding: each device receives its own
+    # client block once, instead of the whole client axis landing on the
+    # first device and every round resharding it.
+    prefetcher = RoundPrefetcher(client_sharding(mesh))
     xs_d, ys_d = prefetcher.get(xs, ys)
 
     ctx = sk = pk = spec = pspec = None

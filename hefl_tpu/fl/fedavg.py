@@ -477,7 +477,7 @@ def train_clients(
 @partial(jax.jit, static_argnums=(0, 3))
 def _predict_all(module, params, x_u8, batch_size: int):
     """Whole-dataset inference as ONE device program: a lax.scan over fixed
-    batches, so a remote/tunneled device pays a single dispatch + transfer
+    batches, so the device pays a single dispatch + transfer
     instead of one host round-trip per batch."""
     nb = x_u8.shape[0] // batch_size
     xb = x_u8.reshape(nb, batch_size, *x_u8.shape[1:])

@@ -44,7 +44,7 @@ producers bench.py embeds and run_perf_smoke.sh gates: flat-vs-hierarchical
 bytes-per-round ratio >= cohort/hosts * 0.8 and bitwise-equal committed
 aggregates in every tested arrival order (identity, reversed, shuffled,
 each with duplicate redeliveries). `python -m hefl_tpu.fl.hierarchy` writes
-the standalone BENCH_DCN.json (run_tpu_suite.sh stage 9).
+the standalone BENCH_DCN.json.
 
 Fault-tolerant DCN (ISSUE 17): the tier->root uplink is a FAULTY link.
 `ship_all(t0)` runs each tier's ship as a delivery timeline on the
@@ -864,7 +864,7 @@ def dcn_compare_smoke_record() -> dict:
 
 
 def _main() -> int:
-    """Standalone BENCH_DCN writer (run_tpu_suite.sh stage 9):
+    """Standalone BENCH_DCN writer:
     `python -m hefl_tpu.fl.hierarchy --out BENCH_DCN.json`."""
     import argparse
     import json

@@ -412,6 +412,28 @@ def _build_sharded_he(ctx: CkksContext, mesh):
     return enc, dec
 
 
+def _on_one_device(ct: Ciphertext) -> Ciphertext:
+    """The owner's copy of a round output: a single-device ciphertext.
+
+    A round program returns its aggregate replicated over the round's mesh.
+    Decrypting that as it stands makes the owner-side decrypt a program
+    over all of the mesh's devices, which a Mosaic kernel refuses ("cannot
+    be automatically partitioned") and which would repeat the same work on
+    every chip anyway. A replicated array's first shard IS the whole array:
+    take that buffer (no copy); anything else is gathered onto one device.
+    """
+    def one(a):
+        sharding = getattr(a, "sharding", None)
+        if sharding is None or len(sharding.device_set) == 1:
+            return a
+        first = a.addressable_shards[0]
+        if sharding.is_fully_replicated:
+            return first.data
+        return jax.device_put(a, first.device)
+
+    return Ciphertext(c0=one(ct.c0), c1=one(ct.c1), scale=ct.scale)
+
+
 def _onto_mesh(mesh, arr: jax.Array, sharded: bool) -> jax.Array:
     """Reshard one array onto the ct mesh (row-sharded or replicated).
 
@@ -556,7 +578,7 @@ def decrypt_average(
         if mesh is not None:
             res = decrypt_sharded(ctx, sk, ct_sum, mesh)
         else:
-            res = ops.decrypt(ctx, sk, ct_sum)
+            res = ops.decrypt(ctx, sk, _on_one_device(ct_sum))
         if packing is not None:
             v = encoding.decode_int_center(ctx.ntt, res)
             if hhe:

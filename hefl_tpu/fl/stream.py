@@ -284,7 +284,7 @@ def fold_loop_probe(prime: int):
     one-step trace only covered a single fold. The count-down counter
     makes the loop's post-fixpoint immediate for the analyzer; the `%`
     mirrors `OnlineAccumulator._add`'s host-side numpy modulo. Trace
-    under `jax.experimental.enable_x64()` (int64 carrier)."""
+    under `jax.enable_x64(True)` (int64 carrier)."""
     p = np.asarray([[int(prime)]], np.int64)
 
     def probe(count, acc, row):

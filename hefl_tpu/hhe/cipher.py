@@ -319,7 +319,7 @@ def transcipher_sum_probe(bits: int, k: int, fbits: int, guard: int,
          recovered_shifted [m])    # sum(v) + E + 2**(guard-1): the
                                    # mod-2**62 recovery window [0, 2**62)
 
-    Trace under `jax.experimental.enable_x64` (the int64 carrier must be
+    Trace under `jax.enable_x64(True)` (the int64 carrier must be
     nameable; the analysis computes in unbounded ints).
     -> (fn, example_args).
     """
@@ -385,7 +385,7 @@ def keystream_counter_probe():
     intentionally and stays exempt from range analysis, exactly like the
     Montgomery cores — its words enter here as [0, 2**31) inputs, which
     is the only fact `keystream_pair`'s masking exports. Trace under
-    `jax.experimental.enable_x64()`. -> (fn, example_args).
+    `jax.enable_x64(True)`. -> (fn, example_args).
     """
 
     def probe(rounds, r0, mask, v_hi, v_lo, z_hi, z_lo):

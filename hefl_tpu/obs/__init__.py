@@ -9,8 +9,7 @@ Three legs, one subsystem (ISSUE 5):
   * `obs.trace` — parses a `jax.profiler.start_trace` trace-viewer dump and
     joins its device-op events back to the scopes through the compiled
     program's own HLO, yielding per-phase device time from ONE program —
-    the ground truth that replaces cross-program ablation subtraction in
-    PROFILE.md.
+    the ground truth that replaces cross-program ablation subtraction.
   * `obs.events` / `obs.metrics` — a JSONL run-event log (events.jsonl
     next to checkpoints; HEFL_EVENTS=0 opt-out) and a process-wide
     counter/gauge registry (exclusions by cause, retries, resumes,

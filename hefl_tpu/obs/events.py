@@ -24,8 +24,9 @@ Event kinds emitted by the current producers (fields beyond ts/event):
     checkpoint_save    round, path
     autoselect         decision, device_kind, winner, source(probe|cache),
                        timings_ms
-    compile            seconds                          (one per NEW executable
-                       XLA built — the no-new-compile guard, queryable)
+    compile            seconds, fun_name, cache_hit     (one per NEW executable:
+                       compiled, or loaded from the persistent cache
+                       when cache_hit — the no-new-compile guard)
     profiler_trace     dir                              (a --profile trace was
                        written; feed it to obs.trace)
     experiment_end     rounds, device_peak_bytes, metrics{...snapshot}

@@ -297,15 +297,31 @@ reset = REGISTRY.reset
 # --------------------------------------------------------------------------
 
 _LISTENERS_INSTALLED = False
+# Set by the persistent-cache-hit event, which JAX records INSIDE the
+# backend-compile span it belongs to; read and cleared when that span ends.
+_cache_hit_pending = False
 
 
-def _on_event_duration(name: str, duration: float, **_kw: Any) -> None:
+def _on_event(name: str, **_kw: Any) -> None:
+    global _cache_hit_pending
+    if name == "/jax/compilation_cache/cache_hits":
+        _cache_hit_pending = True
+
+
+def _on_event_duration(name: str, duration: float, **kw: Any) -> None:
+    global _cache_hit_pending
     if name == "/jax/core/compile/backend_compile_duration":
+        hit, _cache_hit_pending = _cache_hit_pending, False
         counter("jax.new_executables").inc()
         counter("jax.compile_seconds").inc(round(duration, 4))
+        if hit:  # loaded from the persistent cache, not compiled
+            counter("jax.persistent_cache_hits").inc()
         from hefl_tpu.obs import events
 
-        events.emit("compile", seconds=round(duration, 4))
+        events.emit(
+            "compile", seconds=round(duration, 4),
+            fun_name=kw.get("fun_name"), cache_hit=hit,
+        )
 
 
 def install_jax_listeners() -> None:
@@ -314,9 +330,10 @@ def install_jax_listeners() -> None:
     global _LISTENERS_INSTALLED
     if _LISTENERS_INSTALLED:
         return
-    from jax._src import monitoring
+    import jax.monitoring
 
-    monitoring.register_event_duration_secs_listener(_on_event_duration)
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
     _LISTENERS_INSTALLED = True
 
 

@@ -1,6 +1,6 @@
 """Profiler-trace attribution: per-phase device time from ONE program.
 
-PROFILE.md's phase table has so far been computed by SUBTRACTING two
+profile_round.py's phase table was first computed by SUBTRACTING two
 separately-compiled program variants — the method the ROADMAP calls out as
 unreliable (XLA fuses each variant differently; raw deltas go negative on
 fast rounds). This module replaces it with ground truth from a single

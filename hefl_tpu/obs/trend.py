@@ -1,7 +1,7 @@
 """Bench-history trend table + regression gate (ISSUE 20, leg 3).
 
-The repo commits its perf evidence (BENCH_r0N.json, BENCH_SMOKE_CPU.json,
-BENCH_LOAD.json) but nothing machine-read the trajectory — a regression
+The repo commits its perf evidence (BENCH_SMOKE_CPU.json, BENCH_LOAD.json;
+chip records named BENCH_r<N>.json when there are any) but nothing machine-read the trajectory — a regression
 could land silently as long as its own round's artifact was internally
 consistent. This module ingests the committed history, renders TREND.md
 (one row per tracked metric: points, best, latest, delta) and FAILS

@@ -15,6 +15,7 @@ from hefl_tpu.parallel.mesh import (
     CT_AXIS,
     HOST_AXIS,
     client_axes,
+    client_sharding,
     client_mesh_size,
     ct_shard_count,
     dcn_link_names,
@@ -41,6 +42,7 @@ __all__ = [
     "HOST_AXIS",
     "make_ct_mesh",
     "client_axes",
+    "client_sharding",
     "client_mesh_size",
     "ct_shard_count",
     "dcn_link_names",

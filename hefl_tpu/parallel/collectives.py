@@ -18,17 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-def _axis_size(axis_name) -> int:
-    """`jax.lax.axis_size` appeared after 0.4.x; older JAX exposes the
-    traced axis size through `core.axis_frame`."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax.core import axis_frame  # pragma: no cover
-
-    size = axis_frame(axis_name)  # 0.4.x returns the size directly
-    return getattr(size, "size", size)
-
-
 # p < 2**27 (keys.DEFAULT_PRIME_BITS) and sums must stay < 2**32.
 MAX_PSUM_CLIENTS = 32
 
@@ -153,7 +142,7 @@ def reduce_mod(residues: jax.Array, p: jax.Array, axis_name: str) -> jax.Array:
     """Modular all-reduce over one axis, picking the sound backend: the
     fused lazy psum up to MAX_PSUM_CLIENTS participants, the canonical
     ppermute ring beyond."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     return (psum_mod if n <= MAX_PSUM_CLIENTS else ring_psum_mod)(
         residues, p, axis_name
     )
@@ -240,7 +229,7 @@ def ring_psum_mod(residues: jax.Array, p: jax.Array, axis_name: str) -> jax.Arra
     reduce-scatter ring) and a serial chain — the right tool past the lazy
     bound or when per-hop canonicality is wanted, not a psum replacement.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     from hefl_tpu.ckks.modular import add_mod
 

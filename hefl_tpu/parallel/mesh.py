@@ -31,7 +31,7 @@ import os
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 CLIENT_AXIS = "clients"
 HOST_AXIS = "hosts"
@@ -39,24 +39,12 @@ CT_AXIS = "ct"
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable `shard_map`: jax >= 0.5 exports it at top level
-    with `check_vma`; 0.4.x has it under `jax.experimental` with the same
-    knob named `check_rep`; the releases in between export it at top level
-    but still spell the knob `check_rep`. Every round program builds
-    through here."""
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    for kwarg in ("check_vma", "check_rep"):
-        try:
-            return _shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **{kwarg: check_vma},
-            )
-        except TypeError:  # this jax spells the replication-check knob
-            continue       # the other way
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """`jax.shard_map` with the replication check off by default. Every
+    round program builds through here."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
 
 
 def client_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -65,6 +53,13 @@ def client_axes(mesh: Mesh) -> tuple[str, ...]:
     if HOST_AXIS in mesh.axis_names:
         return (HOST_AXIS, CLIENT_AXIS)
     return (CLIENT_AXIS,)
+
+
+def client_sharding(mesh: Mesh) -> NamedSharding:
+    """Leading (client) axis split over the mesh's client axes, the rest
+    replicated — the layout every round program's `P(axes)` in_spec asks
+    for, so federated arrays placed with it are never resharded per round."""
+    return NamedSharding(mesh, PartitionSpec(client_axes(mesh)))
 
 
 def client_mesh_size(mesh: Mesh) -> int:

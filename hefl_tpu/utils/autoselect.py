@@ -9,9 +9,10 @@ home, since both caches answer "what did we already learn about compiling
 / running on this exact device" — so the probe runs once per (device kind,
 decision), not once per process.
 
-Storage is one JSON file, ``hefl_autoselect.json``, inside the directory
-named by the ``jax_compilation_cache_dir`` config (the same knob cli.py /
-bench.py already set). No compile-cache dir configured => no persistence
+Storage is one JSON file, ``hefl_autoselect.json``, inside the compile-cache
+directory JAX resolved (``utils.device.compile_cache_dir``: the
+``JAX_COMPILATION_CACHE_DIR`` environment variable, else the checkout's
+fixed ``.jax_cache`` that cli.py and the drivers set). No compile-cache dir configured => no persistence
 (the in-process cache still applies). ``HEFL_AUTOSELECT_CACHE=0`` disables
 persistence explicitly — the test suite sets it so auto-selection tests
 always exercise the live micro-timing path.
@@ -32,9 +33,9 @@ _FILENAME = "hefl_autoselect.json"
 def _cache_file() -> str | None:
     if os.environ.get("HEFL_AUTOSELECT_CACHE", "1") == "0":
         return None
-    import jax
+    from hefl_tpu.utils.device import compile_cache_dir
 
-    cache_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
+    cache_dir = compile_cache_dir()
     if not cache_dir:
         return None
     return os.path.join(cache_dir, _FILENAME)

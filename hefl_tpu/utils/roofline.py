@@ -4,12 +4,12 @@ Before this module each driver carried its own copy of the peak-FLOPs
 table and its own `cost_analysis()` plumbing (`bench.py._PEAK_BF16`,
 `mfu_probe.PEAK_FLOPS`), and `profile_round.py` attributed phase cost by
 raw subtraction across separately-compiled programs — which on sub-second
-rounds produced NEGATIVE rows (PROFILE.md's −17.7% validation row). This
+rounds produced NEGATIVE rows (a −17.7% validation row). This
 module is the single source for:
 
-  * the bf16 peak-FLOPs table by device kind (public spec sheets), with a
-    clearly-labeled CPU placeholder so smoke artifacts carry comparable
-    (shape-meaningful, absolute-meaningless) MFU columns instead of nulls;
+  * the bf16 peak-FLOPs table by device kind (public spec sheets); a CPU
+    has no entry (its utilization columns are null) and an accelerator
+    that is not in the table is an error, never a guess;
   * `program_flops` — XLA's own `cost_analysis()['flops']` off a lowered/
     compiled program (never a hand FLOP model);
   * `phase_stats` — the {seconds, flops, mfu, images_per_s} record every
@@ -38,11 +38,6 @@ PEAK_BF16_FLOPS: dict[str, float] = {
     "v5": 459e12,
 }
 
-# Order-of-magnitude CPU placeholder (one AVX-512 core-ish). Absolute MFU
-# against it is meaningless — only batch-scaling shape and phase ratios
-# are — so every record derived from it carries `peak_is_placeholder`.
-CPU_PLACEHOLDER_FLOPS = 1e11
-
 
 def device_kind(device: Any) -> str:
     """Best-effort device-kind string for any JAX device (or a str)."""
@@ -51,16 +46,20 @@ def device_kind(device: Any) -> str:
     return str(getattr(device, "device_kind", device))
 
 
-def peak_flops(device: Any) -> tuple[float | None, bool]:
-    """-> (peak bf16 FLOP/s, is_placeholder). None when the device kind is
-    unknown and not a CPU (never guess a real accelerator's peak)."""
+def peak_flops(device: Any) -> float | None:
+    """-> peak bf16 FLOP/s of a TPU in the table; None for a CPU (a CPU
+    run reports counts, its utilization is null). Any other device kind
+    raises: a measurement never runs against a guessed peak."""
     kind = device_kind(device).lower()
     for tag, peak in PEAK_BF16_FLOPS.items():
         if tag in kind:
-            return peak, False
-    if "cpu" in kind or kind in ("", "none"):
-        return CPU_PLACEHOLDER_FLOPS, True
-    return None, False
+            return peak
+    if "cpu" in kind:
+        return None
+    raise ValueError(
+        f"device kind {kind!r} is not in roofline.PEAK_BF16_FLOPS — add it "
+        "with its published peak"
+    )
 
 
 def program_flops(fn=None, *args, compiled=None) -> float | None:
@@ -86,7 +85,7 @@ def program_flops(fn=None, *args, compiled=None) -> float | None:
 
 def mfu(flops: float | None, seconds: float | None, device: Any) -> float | None:
     """Model FLOPs utilization: program FLOPs / wall seconds / device peak."""
-    peak, _ = peak_flops(device)
+    peak = peak_flops(device)
     if not flops or not seconds or not peak:
         return None
     return flops / seconds / peak
@@ -101,9 +100,7 @@ def clamp_utilization(rec: dict[str, Any], field: str) -> dict[str, Any]:
     The flag is the generic impossible-row marker, not a diagnosis: the
     cause is EITHER a sub-`TIMING_FLOOR_S` phase the host clock could not
     resolve (fixed by `steady_seconds`' repetition chain) OR an understated
-    peak model — `peak_is_placeholder` / `peak_is_estimate` on the same row
-    says which. A long phase flagged here with a placeholder peak is a
-    peak-table problem, not a timing one."""
+    peak model (`peak_is_estimate` on the same row)."""
     v = rec.get(field)
     if v is not None and v > 1.0:
         rec[f"{field}_raw"] = v
@@ -121,7 +118,7 @@ def phase_stats(
     """One phase's roofline record: the unit every BENCH/PROFILE artifact
     embeds. Fields are always PRESENT (null when not computable) so
     downstream checkers can demand the schema without demanding hardware."""
-    peak, placeholder = peak_flops(device) if device is not None else (None, False)
+    peak = peak_flops(device) if device is not None else None
     rec: dict[str, Any] = {
         # 6 decimals: a 0.3 ms phase must round to 0.0003, never to a bare
         # 0.0 that reads as "did not run".
@@ -136,8 +133,6 @@ def phase_stats(
             round(images / seconds, 2) if (images and seconds) else None
         ),
     }
-    if placeholder and rec["mfu"] is not None:
-        rec["peak_is_placeholder"] = True
     return clamp_utilization(rec, "mfu")
 
 
@@ -184,7 +179,7 @@ def backend_compare(
 
 # Below this, one dispatch's wall clock is dominated by timer/dispatch
 # noise, not the phase: a 0.3 ms aggregate timed as a single call produced
-# the impossible util_vs_peak_int_ops 6.19 row (>1) in PROFILE.md. Phases
+# an impossible util_vs_peak_int_ops 6.19 row (>1). Phases
 # under the floor are re-timed over a back-to-back repetition chain.
 TIMING_FLOOR_S = 2e-3
 _TIMING_TARGET_S = 2e-2   # total measured span a repetition chain aims for
@@ -204,7 +199,7 @@ def steady_seconds(fn, *args, reps: int = 3, warmup: int = 1) -> float:
     re-timed as a chain of N back-to-back calls with one trailing block —
     the per-call average of a span long enough for the host timer to
     resolve — so no artifact ever publishes a single-dispatch timing of a
-    phase the clock cannot see (the source of PROFILE.md's impossible
+    phase the clock cannot see (the source of an impossible
     `util_vs_peak_int_ops: 6.19` aggregate row).
     """
     import time
@@ -257,17 +252,13 @@ _NTT_OPS_PER_ELEM_STAGE = 14
 # VPU is roughly 1/16th of the MXU's mac rate); every row derived from them
 # carries `peak_is_estimate`. Interpret utilization shape, not absolutes.
 _PEAK_INT_DIVISOR = 16.0
-CPU_PLACEHOLDER_INT_OPS = 2e10
 
 
-def peak_int_ops(device: Any) -> tuple[float | None, bool]:
-    """-> (estimated peak uint32 ops/s, is_estimate). Always an estimate."""
-    peak, placeholder = peak_flops(device)
-    if peak is None:
-        return None, True
-    if placeholder:
-        return CPU_PLACEHOLDER_INT_OPS, True
-    return peak / _PEAK_INT_DIVISOR, True
+def peak_int_ops(device: Any) -> float | None:
+    """-> estimated peak uint32 ops/s (always an estimate); None for a
+    CPU, like `peak_flops`."""
+    peak = peak_flops(device)
+    return None if peak is None else peak / _PEAK_INT_DIVISOR
 
 
 def he_phase_counts(
@@ -311,7 +302,7 @@ def he_phase_stats(
     rates null only when `seconds` is. `util_vs_peak_int_ops` divides by
     the ESTIMATED VPU peak and carries `peak_is_estimate` accordingly.
     """
-    peak, estimate = peak_int_ops(device) if device is not None else (None, True)
+    peak = peak_int_ops(device) if device is not None else None
     int_ops = counts["int_ops"]
     byts = counts["bytes"]
     rec: dict[str, Any] = {
@@ -324,7 +315,7 @@ def he_phase_stats(
             round(int_ops / seconds / peak, 5) if (seconds and peak) else None
         ),
     }
-    if estimate and rec["util_vs_peak_int_ops"] is not None:
+    if rec["util_vs_peak_int_ops"] is not None:
         rec["peak_is_estimate"] = True
     return clamp_utilization(rec, "util_vs_peak_int_ops")
 

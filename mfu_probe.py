@@ -35,12 +35,12 @@ def main() -> None:
     smoke = os.environ.get("MFU_SMOKE") == "1"
     import jax
 
-    from hefl_tpu.utils.probe import setup_backend
+    from hefl_tpu.utils.device import select_platform, setup_compile_cache
 
-    setup_backend("mfu_probe.py", "cpu" if smoke else None)
+    select_platform("mfu_probe.py", cpu=smoke)
     import jax.numpy as jnp
 
-    jax.config.update("jax_compilation_cache_dir", ".jax_cache")
+    setup_compile_cache()
 
     from hefl_tpu.data.augment import backend_report, random_augment, rescale
     from hefl_tpu.fl.config import TrainConfig
@@ -51,20 +51,16 @@ def main() -> None:
 
     dev = jax.devices()[0]
     kind = roofline.device_kind(dev)
-    peak, placeholder = roofline.peak_flops(dev)
-    if placeholder:
-        print(
-            f"WARNING: CPU-placeholder peak for device kind {kind!r} — "
-            "absolute MFU values are meaningless, only the batch-scaling "
-            "shape is",
-            file=sys.stderr,
-        )
-    print(f"device: {kind} (peak bf16 ~{(peak or 0) / 1e12:.0f} TFLOP/s)",
-          file=sys.stderr)
+    peak = roofline.peak_flops(dev)  # None on the CPU smoke: MFU is null
+    print(
+        f"device: {kind} (peak bf16 "
+        + (f"~{peak / 1e12:.0f} TFLOP/s)" if peak else "not defined)"),
+        file=sys.stderr,
+    )
 
     module = MedCNN()
     cfg = TrainConfig()
-    key = jax.random.PRNGKey(0)
+    key = jax.random.key(0)
     hw = 256  # 6 pool stages need the full input; smaller collapses to 0
     params = module.init(key, jnp.zeros((1, hw, hw, 3), jnp.float32))["params"]
 
@@ -116,7 +112,7 @@ def main() -> None:
                 "step_ms": round(dt * 1e3, 3),
                 "images_per_s": round(bs / dt, 1),
                 "xla_flops": flops,
-                "mfu": round(roofline.mfu(flops, dt, dev) or 0.0, 4),
+                "mfu": roofline.mfu(flops, dt, dev),
             }
         )
         print(f"  batch {bs}: {dt * 1e3:.2f} ms", file=sys.stderr)
@@ -126,7 +122,8 @@ def main() -> None:
     for r in rows:
         print(
             f"| {r['batch']} | {r['step_ms']:.3f} | {r['images_per_s']:.0f} "
-            f"| {r['xla_flops'] / 1e9:.1f} | {r['mfu']:.3f} |"
+            f"| {r['xla_flops'] / 1e9:.1f} | "
+            + ("null |" if r["mfu"] is None else f"{r['mfu']:.4f} |")
         )
     lat = rows[0]["step_ms"]
     big = rows[-1]["step_ms"]
@@ -143,7 +140,6 @@ def main() -> None:
             {
                 "device": kind,
                 "peak_flops": peak,
-                "peak_is_placeholder": placeholder,
                 "augment_backend": backend_report(),
                 "rows": rows,
                 "verdict": verdict,

@@ -72,14 +72,13 @@ def main(argv: list[str] | None = None) -> None:
 
     import jax
 
-    from hefl_tpu.utils.probe import setup_backend
+    from hefl_tpu.utils.device import select_platform, setup_compile_cache
 
     smoke = os.environ.get("PROFILE_SMOKE") == "1"
-    setup_backend("profile_round.py", "cpu" if smoke else None)
+    select_platform("profile_round.py", cpu=smoke)
     import jax.numpy as jnp
 
-    jax.config.update("jax_compilation_cache_dir", ".jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    setup_compile_cache()
 
     from hefl_tpu.obs import metrics as obs_metrics
 
@@ -612,7 +611,7 @@ def main(argv: list[str] | None = None) -> None:
     tr = phase_roofline["train_only"]
     print(
         f"train-phase roofline: MFU {tr['mfu']} | {tr['images_per_s']} "
-        f"images/s ({'placeholder peak' if tr.get('peak_is_placeholder') else 'spec peak'})"
+        f"images/s"
     )
     print()
     print("| augment backend (full warp) | ms / batch |")

@@ -74,7 +74,7 @@
 # Artifact: CHAOS_SMOKE.json (accuracy curves + per-round exclusions
 # + the events.jsonl cross-checks, streaming + crash-recovery + HHE +
 # cohort-only + hierarchical twins included).
-# Wired into run_tpu_suite.sh as stage 0b (CPU-only, no TPU probe needed).
+# CPU-only: needs no chip.
 set -euo pipefail
 cd "$(dirname "$0")"
 

@@ -7,7 +7,7 @@
 #   (b) the attribution JSON carries phase_roofline records for every
 #       phase and the augment backend choice;
 #   (c) no clamped attribution row is negative, and any negative RAW delta
-#       is flagged attribution_unreliable (the PROFILE.md -17.7% row class
+#       is flagged attribution_unreliable (the -17.7% validation row class
 #       of bug fails here, on CPU, instead of poisoning TPU evidence);
 #   (d) the client_fusion backend record and the fused-vs-vmap comparison
 #       rows (seconds/mfu/images_per_s per backend + speedup) are present
@@ -88,13 +88,12 @@
 #       artifact (now run with --sweep) must carry the commit-latency-
 #       percentiles-vs-(cohort, quorum) family: >= 3 points, every point
 #       committed with p50 <= p95 <= p99.
-# Wired into run_tpu_suite.sh as stage 0 (cheap pre-stage, no backend
-# probe needed — both harnesses pin themselves to CPU in smoke mode).
+# Needs no chip: both harnesses pin themselves to CPU in smoke mode.
 set -euo pipefail
 cd "$(dirname "$0")"
 
 workdir=$(mktemp -d)
-# mfu_probe.json is TPU-suite evidence when produced WITHOUT MFU_SMOKE;
+# mfu_probe.json is TPU evidence when produced WITHOUT MFU_SMOKE;
 # shelter any committed copy from the smoke run's overwrite. The restore
 # lives in the EXIT trap so a failure or Ctrl-C between the overwrite and
 # the restore cannot clobber committed evidence (the backup would
@@ -675,11 +674,17 @@ probe = json.load(open(mfu_path))
 if "peak_flops" not in probe or not probe.get("rows"):
     fail.append("mfu_probe.json: missing peak_flops/rows")
 for row in probe.get("rows", []):
-    for field in ("mfu", "images_per_s", "xla_flops"):
+    for field in ("images_per_s", "xla_flops"):
         if row.get(field) is None:
             fail.append(
                 f"mfu_probe.json row batch={row.get('batch')}: missing {field}"
             )
+    # A CPU has no peak: the smoke's mfu is present and null.
+    if "mfu" not in row or row["mfu"] is not None:
+        fail.append(
+            f"mfu_probe.json row batch={row.get('batch')}: a CPU smoke row "
+            "must carry mfu: null"
+        )
 if "augment_backend" not in probe:
     fail.append("mfu_probe.json: missing augment_backend")
 
@@ -775,12 +780,17 @@ else:
                     )
     for phase in ("decrypt", "evaluate"):
         row = (rec.get("phase_roofline") or {}).get(phase) or {}
-        for field in ("flops", "mfu"):
-            if row.get(field) is None:
-                fail.append(
-                    f"profile: phase_roofline[{phase!r}].{field} is still "
-                    "null — the HE roofline must fill it"
-                )
+        if row.get("flops") is None:
+            fail.append(
+                f"profile: phase_roofline[{phase!r}].flops is still "
+                "null — the HE roofline must fill it"
+            )
+        # A CPU has no peak: the smoke's utilization is present and null.
+        if "mfu" not in row or row["mfu"] is not None:
+            fail.append(
+                f"profile: phase_roofline[{phase!r}].mfu must be null on "
+                "the CPU smoke"
+            )
     # (f) trace-native attribution: per-phase device time from ONE
     # program's trace, agreeing with the traced wall clock.
     if rec.get("attribution_source") != "trace":

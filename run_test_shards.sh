@@ -157,6 +157,7 @@ echo "== lossy-DCN shard (fsync=always): $((SECONDS - t0))s"
 t0=$SECONDS
 python -m hefl_tpu.obs.trend --quiet
 if python -m hefl_tpu.obs.trend --quiet \
+    --extra tests/fixtures/BENCH_r98_seeded_baseline.json \
     --extra tests/fixtures/BENCH_r99_seeded_regression.json \
     > /dev/null 2>&1; then
   echo "TREND SHARD FAILED: the seeded regression fixture did NOT trip" \

@@ -445,15 +445,16 @@ def test_compile_listener_counts_new_executables():
 
 def test_utilization_clamped_and_flagged():
     counts = {"int_ops": 1e12, "bytes": 1e6}
-    rec = roofline.he_phase_stats(1e-4, counts, device="cpu")  # util >> 1
+    dev = "TPU v5 lite"
+    rec = roofline.he_phase_stats(1e-6, counts, device=dev)  # util >> 1
     assert rec["util_vs_peak_int_ops"] == 1.0
     assert rec["timing_floor_suspect"] is True
     assert rec["util_vs_peak_int_ops_raw"] > 1.0
-    ok = roofline.he_phase_stats(100.0, counts, device="cpu")
+    ok = roofline.he_phase_stats(100.0, counts, device=dev)
     assert ok["util_vs_peak_int_ops"] < 1.0
     assert "timing_floor_suspect" not in ok
     # phase_stats mfu gets the same guard.
-    ps = roofline.phase_stats(1e-9, flops=1e12, device="cpu")
+    ps = roofline.phase_stats(1e-9, flops=1e12, device=dev)
     assert ps["mfu"] == 1.0 and ps["timing_floor_suspect"] is True
 
 

@@ -361,18 +361,24 @@ def test_train_round_flops_within_analytic_envelope():
 
 
 def test_roofline_schema_and_clamp():
-    rec = roofline.phase_stats(2.0, flops=4e11, device="cpu", images=100)
+    rec = roofline.phase_stats(
+        2.0, flops=8e14, device="TPU v5 lite", images=100
+    )
     assert set(rec) >= {"seconds", "flops", "mfu", "images_per_s"}
-    # 4e11/2.0 over the placeholder peak is an impossible 2.0 utilization:
+    # 8e14/2.0 over the v5e peak is an impossible ~2.03 utilization:
     # clamped to 1.0 with the raw value kept and the timing-floor flag set
     # (ISSUE 5 — no artifact ships utilization > 1 unflagged).
     assert rec["mfu"] == 1.0
-    assert rec["mfu_raw"] == pytest.approx(
-        4e11 / 2.0 / roofline.CPU_PLACEHOLDER_FLOPS
-    )
+    assert rec["mfu_raw"] == pytest.approx(8e14 / 2.0 / 197e12, rel=1e-4)
     assert rec["timing_floor_suspect"] is True
-    assert rec["peak_is_placeholder"] is True
     assert rec["images_per_s"] == 50.0
+    # A CPU has no peak: its utilization is null, never a placeholder.
+    cpu = roofline.phase_stats(2.0, flops=4e11, device="cpu", images=100)
+    assert cpu["mfu"] is None and cpu["images_per_s"] == 50.0
+    assert roofline.peak_flops("cpu") is None
+    # An accelerator that is not in the table is an error, not a guess.
+    with pytest.raises(ValueError, match="not in roofline"):
+        roofline.peak_flops("Some Future Chip")
     # null-safe: fields PRESENT but null when not computable
     empty = roofline.phase_stats(None)
     assert empty["mfu"] is None and empty["seconds"] is None
@@ -380,5 +386,4 @@ def test_roofline_schema_and_clamp():
     assert clamped == {"a": 1.5, "b": 0.0} and bad is True
     clamped, bad = roofline.clamp_attribution({"a": 0.3})
     assert bad is False
-    peak, placeholder = roofline.peak_flops("TPU v5 lite")
-    assert peak == 197e12 and placeholder is False
+    assert roofline.peak_flops("TPU v5 lite") == 197e12

@@ -368,3 +368,27 @@ def test_with_plain_reference_isolates_he_error():
         jax.tree_util.tree_leaves(plain_ref),
     ):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+def test_owner_decrypt_takes_a_single_device_copy():
+    # A round output is replicated over the round's mesh; the owner-side
+    # decrypt must be a ONE-device program (on a TPU a Mosaic kernel over a
+    # multi-device array is refused: "cannot be automatically partitioned").
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from hefl_tpu.ckks.ops import Ciphertext
+    from hefl_tpu.fl.secure import _on_one_device
+
+    mesh = make_mesh(4)
+    assert mesh.devices.size == 4
+    x = np.arange(4 * 6, dtype=np.uint32).reshape(4, 6)
+    rep = jax.device_put(x, NamedSharding(mesh, P()))
+    split = jax.device_put(x, NamedSharding(mesh, P("clients")))
+    out = _on_one_device(Ciphertext(c0=rep, c1=split, scale=2.0))
+    for got in (out.c0, out.c1):
+        assert len(got.sharding.device_set) == 1
+        np.testing.assert_array_equal(np.asarray(got), x)
+    assert out.scale == 2.0
+    # already on one device: passed through untouched
+    single = jnp.asarray(x)
+    assert _on_one_device(Ciphertext(c0=single, c1=single, scale=1.0)).c0 is single

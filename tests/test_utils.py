@@ -197,15 +197,23 @@ def test_he_roofline_rows_are_non_null():
     rows = roofline.he_roofline(
         {"encrypt": 0.05, "aggregate": 0.001, "decrypt": 0.02},
         n=4096, num_limbs=3, n_ct=55, num_clients=2, encrypt_clients=1,
+        device="TPU v5 lite",
+    )
+    cpu_rows = roofline.he_roofline(
+        {"encrypt": 0.05, "aggregate": 0.001, "decrypt": 0.02},
+        n=4096, num_limbs=3, n_ct=55, num_clients=2, encrypt_clients=1,
         device="cpu",
     )
     for phase in ("encrypt", "aggregate", "decrypt"):
         row = rows[phase]
         for field in ("seconds", "int_ops", "bytes", "int_ops_per_s", "bytes_per_s"):
             assert row[field] is not None, (phase, field)
+            assert cpu_rows[phase][field] == row[field]
         assert row["int_ops"] > 0 and row["bytes"] > 0
-        # CPU peaks are placeholders/estimates and must say so.
+        # The VPU int peak is an estimate and must say so; a CPU has no
+        # peak at all, so its utilization is null.
         assert row.get("peak_is_estimate") is True
+        assert cpu_rows[phase]["util_vs_peak_int_ops"] is None
     # Encrypt dominates decrypt at the same geometry (4 NTTs vs 1).
     assert rows["encrypt"]["int_ops"] > rows["decrypt"]["int_ops"]
     geo = rows["geometry"]
