@@ -108,10 +108,12 @@ def build_parser() -> argparse.ArgumentParser:
                    dest="events")
     p.add_argument("--span-trace", default=None, metavar="PATH",
                    dest="span_trace",
-                   help="write every streaming round's lifecycle span tree "
-                        "(obs.spans: arrival/fold/ship/commit/recovery on "
-                        "the engine's virtual clock) as Chrome trace-viewer "
-                        "JSON (.gz honored); streaming runs only")
+                   help="write the run's host spans (obs.spans: set-up, rounds, "
+                        "phases and their steps, on the profiler's clock) "
+                        "and every streaming round's lifecycle span tree "
+                        "(arrival/fold/ship/commit/recovery on the engine's "
+                        "virtual clock) as Chrome trace-viewer JSON (.gz "
+                        "honored)")
     p.add_argument("--json", action="store_true", help="emit history as JSON lines")
     p.add_argument("--dp-noise", type=float, default=0.0, metavar="SIGMA",
                    help="DP-FedAvg central noise multiplier (0 = off): clip "

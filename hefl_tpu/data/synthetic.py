@@ -22,6 +22,8 @@ import dataclasses
 
 import numpy as np
 
+from hefl_tpu.obs import spans as obs_spans
+
 
 @dataclasses.dataclass(frozen=True)
 class DatasetSpec:
@@ -180,6 +182,9 @@ def make_dataset(
     if name not in DATASETS:
         raise ValueError(f"unknown dataset {name!r}; available: {sorted(DATASETS)}")
     spec = DATASETS[name]
-    tr = make_split(spec, n_train or spec.n_train, seed)
-    te = make_split(spec, n_test or spec.n_test, seed + 1)
+    # Timed here and not at run_experiment's call: a driver that makes a
+    # seed's data once for several calls (the benchmark) is timed too.
+    with obs_spans.span("hefl.setup.data"):
+        tr = make_split(spec, n_train or spec.n_train, seed)
+        te = make_split(spec, n_test or spec.n_test, seed + 1)
     return tr, te, spec

@@ -58,6 +58,7 @@ from hefl_tpu.models import count_params, create_model
 from hefl_tpu.obs import events as obs_events
 from hefl_tpu.obs import metrics as obs_metrics
 from hefl_tpu.obs import scopes as obs_scopes
+from hefl_tpu.obs import spans as obs_spans
 from hefl_tpu.parallel import (
     client_mesh_size,
     client_sharding,
@@ -153,12 +154,12 @@ class ExperimentConfig:
     # "" = disabled for this run. HEFL_EVENTS=0 disables globally without
     # code changes (the test suite sets it).
     events_path: str | None = None
-    # Round-lifecycle span export (obs.spans, ISSUE 20): every streaming
-    # round's span tree (arrival/fold/ship/commit/recovery on the
-    # engine's virtual clock) written as ONE Chrome trace-viewer JSON
-    # (.gz honored) at the end of the run — the engine-side timeline
-    # rendered by the same tooling as device traces. Streaming runs
-    # only; None = no export.
+    # Span export (obs.spans): this call's host spans (set-up steps, rounds,
+    # phases and their steps, on the profiler's clock) and every streaming
+    # round's span tree (arrival/fold/ship/commit/recovery on the engine's
+    # virtual clock) written as ONE Chrome trace-viewer JSON (.gz honored)
+    # at the end of the run — rendered by the same tooling as device
+    # traces. None = no export.
     span_trace_path: str | None = None
     # Durable aggregation service (fl.journal / fl.server): a write-ahead
     # round journal recording every streaming-engine transition, with
@@ -194,6 +195,12 @@ class ExperimentConfig:
     # HE throughput scaled by K. 0/1 keeps the historical 1-D client mesh
     # (HEFL_MESH_CT can still flip the default at the mesh layer for CI).
     mesh_ct: int = 0
+
+
+# The host's steps inside the train phase, as spans: `<prefix>dispatch`,
+# `<prefix>prefetch`, `<prefix>device_wait`.
+_ENC_STEP = obs_spans.PHASE_PREFIX + "train+encrypt+aggregate."
+_PLAIN_STEP = obs_spans.PHASE_PREFIX + "train+aggregate."
 
 
 def _train_roofline_inputs(module, params, train_cfg: TrainConfig,
@@ -275,6 +282,11 @@ def run_experiment(
     `history[r]` = {round, phases (seconds per phase), accuracy, precision,
     recall, f1, val_acc (per-client)} — the reference's cell-4/cell-5
     DataFrames as one record per round.
+
+    Every host-side timing of the call is a span of `obs.spans`' recorder
+    carrying this call's number: `hefl.setup` and its children for the
+    start, then one `hefl.round` a round holding the PhaseTimer phases
+    (`hefl.phase.<phase>`) and their steps (`hefl.phase.<phase>.<step>`).
     """
     say = print if verbose else (lambda *_: None)
     if cfg.dp is not None and (not cfg.encrypted or cfg.centralized):
@@ -426,6 +438,16 @@ def run_experiment(
                 - cfg.faults.max_scheduled_exclusions(cfg.num_clients),
             )
         dp_cfg = dataclasses.replace(dp_cfg, min_surviving=floor)
+    # One call of the span recorder, begun and ended by hand, and spans with
+    # no handle kept (obs_spans.start/stop): NOT a wrapper function, and no
+    # new local variable. The tracing of the round program runs hundreds of
+    # Python frames above this one, and CPython 3.12 is 40-100x slower on a
+    # call that straddles a 16 KB chunk of its frame stack; one more frame,
+    # or this frame a few slots larger, moved that boundary onto a hot call
+    # and cost the resnet20 cell 8-15 s of set-up (PERF.md, PR 24;
+    # tests/test_experiment.py pins the frame's size). An error that passes
+    # leaves the call open; the next call ends it first.
+    call_id = obs_spans.begin_call()
     # Observability (obs): route this run's structured events to one JSONL
     # file (events.jsonl next to the checkpoint by default; events_path=""
     # or HEFL_EVENTS=0 disables) and start counting new XLA executables /
@@ -471,13 +493,18 @@ def run_experiment(
             min_surviving=dp_cfg.min_surviving,
             num_clients=cfg.num_clients,
         )
+    # The call's start, as one span with a child a step. start()/stop() in
+    # place of `with`, here and for a round's span, because the regions are
+    # hundreds of lines; ending the call closes what an error left open.
+    obs_spans.start(obs_spans.SETUP)
     train_cfg = cfg.train
     if cfg.data_dir is not None:
         # The reference's primary workflow: point the tool at a folder of
         # class-subdir images (FLPyfhelin.py:38-55, notebook `image/Train`).
-        (x, y), (xt, yt), class_names = load_folder_splits(
-            cfg.data_dir, image_size=cfg.image_size, seed=cfg.seed
-        )
+        with obs_spans.span("hefl.setup.data"):
+            (x, y), (xt, yt), class_names = load_folder_splits(
+                cfg.data_dir, image_size=cfg.image_size, seed=cfg.seed
+            )
         say(f"data dir {cfg.data_dir}: classes {class_names}, "
             f"train {x.shape}, test {xt.shape}")
         if train_cfg.num_classes != len(class_names):
@@ -485,22 +512,26 @@ def run_experiment(
                 train_cfg, num_classes=len(class_names)
             )
     else:
+        # make_dataset records its own `hefl.setup.data` span
         (x, y), (xt, yt), _ = make_dataset(
             cfg.dataset, seed=cfg.seed, n_train=cfg.n_train, n_test=cfg.n_test
         )
     # Hoist the test set to device ONCE: evaluate() every round would
     # otherwise pay the full host->device copy (78 MB at the medical spec)
     # per round (VERDICT r2 weak #7).
-    xt_d = jax.device_put(jnp.asarray(xt))
+    with obs_spans.span("hefl.setup.stage"):
+        xt_d = jax.device_put(jnp.asarray(xt))
 
-    module, params = create_model(
-        cfg.model,
-        num_classes=train_cfg.num_classes,
-        input_shape=tuple(int(d) for d in x.shape[1:]),
-    )
+    with obs_spans.span("hefl.setup.model"):
+        module, params = create_model(
+            cfg.model,
+            num_classes=train_cfg.num_classes,
+            input_shape=tuple(int(d) for d in x.shape[1:]),
+        )
     key = jax.random.key(cfg.seed)
 
     if cfg.centralized:
+        obs_spans.stop(obs_spans.SETUP)
         # The reference's `train_server` baseline (FLPyfhelin.py:161-177):
         # one model, the whole training set, same callback semantics. Not a
         # federated round — no partition, no mesh, no HE.
@@ -543,6 +574,7 @@ def run_experiment(
             save_params(cfg.save_model_path, params)
             say(f"saved model to {cfg.save_model_path}")
         _record_round_obs(0, phases, dev)
+        obs_spans.end_call()
         return {
             "history": [record],
             "final_metrics": record,
@@ -550,33 +582,35 @@ def run_experiment(
             "obs": _finish_run_obs(metrics_base, rounds=1),
         }
 
-    xs, ys = stack_federated(x, y, _partition(cfg, y))
-    # Round topology: the 1-D client mesh, or — with mesh_ct > 1 — the
-    # 2-D ("clients", "ct") mesh whose ct axis shards the in-round HE
-    # rows within each client block (ISSUE 15; bitwise-identical rounds).
-    mesh = (
-        make_mesh_2d(cfg.num_clients, cfg.mesh_ct)
-        if cfg.mesh_ct > 1
-        else make_mesh(cfg.num_clients)
-    )
-    # Hoist the padding gather: pad the federated arrays to the mesh ONCE
-    # here (host-side) instead of letting every round re-run the
-    # device-side xs[pad_idx] gather; the round wrappers get the real
-    # client count via num_real_clients and skip their own data gather.
-    xs, ys, num_real = pad_federated(xs, ys, client_mesh_size(mesh))
-    # Double-buffered host->device staging: with a static dataset this
-    # holds one resident copy (the historical jnp.asarray-once behavior);
-    # per-round data (client sampling, streaming shards) overlaps its copy
-    # with the previous round's compute via prefetcher.prefetch below.
-    # Placed with the mesh's client sharding: each device receives its own
-    # client block once, instead of the whole client axis landing on the
-    # first device and every round resharding it.
-    prefetcher = RoundPrefetcher(client_sharding(mesh))
-    xs_d, ys_d = prefetcher.get(xs, ys)
+    with obs_spans.span("hefl.setup.stage"):
+        xs, ys = stack_federated(x, y, _partition(cfg, y))
+        # Round topology: the 1-D client mesh, or — with mesh_ct > 1 — the
+        # 2-D ("clients", "ct") mesh whose ct axis shards the in-round HE
+        # rows within each client block (ISSUE 15; bitwise-identical rounds).
+        mesh = (
+            make_mesh_2d(cfg.num_clients, cfg.mesh_ct)
+            if cfg.mesh_ct > 1
+            else make_mesh(cfg.num_clients)
+        )
+        # Hoist the padding gather: pad the federated arrays to the mesh ONCE
+        # here (host-side) instead of letting every round re-run the
+        # device-side xs[pad_idx] gather; the round wrappers get the real
+        # client count via num_real_clients and skip their own data gather.
+        xs, ys, num_real = pad_federated(xs, ys, client_mesh_size(mesh))
+        # Double-buffered host->device staging: with a static dataset this
+        # holds one resident copy (the historical jnp.asarray-once behavior);
+        # per-round data (client sampling, streaming shards) overlaps its copy
+        # with the previous round's compute via prefetcher.prefetch below.
+        # Placed with the mesh's client sharding: each device receives its own
+        # client block once, instead of the whole client axis landing on the
+        # first device and every round resharding it.
+        prefetcher = RoundPrefetcher(client_sharding(mesh))
+        xs_d, ys_d = prefetcher.get(xs, ys)
 
     ctx = sk = pk = spec = pspec = None
     if cfg.encrypted:
-        ctx = cfg.he.build()
+        with obs_spans.span("hefl.setup.context"):
+            ctx = cfg.he.build()
         # Pre-flight static analysis (ISSUE 8): certify the aggregation
         # no-wrap bounds and the packed headroom for THIS config before
         # any training work — fails loudly with the offending op named,
@@ -584,9 +618,11 @@ def run_experiment(
         # run's metrics snapshot.
         from hefl_tpu import analysis
 
-        analysis.check_experiment(cfg, ctx=ctx, say=say)
+        with obs_spans.span("hefl.setup.preflight"):
+            analysis.check_experiment(cfg, ctx=ctx, say=say)
         key, k_he = jax.random.split(key)
-        sk, pk = keygen(ctx, k_he)
+        with obs_spans.span("hefl.setup.keygen"):
+            sk, pk = keygen(ctx, k_he)
         spec = PackSpec.for_params(params, ctx.n)
         say(
             f"CKKS context: N={ctx.n} L={ctx.num_primes} "
@@ -632,10 +668,11 @@ def run_experiment(
     dev = jax.devices()[0]
     # Train-phase roofline inputs (geometry is per-configuration, so one
     # cost-analysis compile serves every round).
-    train_flops, train_images = _train_roofline_inputs(
-        module, params, train_cfg, x.shape[1:], int(xs.shape[1]),
-        cfg.num_clients,
-    )
+    with obs_spans.span("hefl.setup.roofline_inputs"):
+        train_flops, train_images = _train_roofline_inputs(
+            module, params, train_cfg, x.shape[1:], int(xs.shape[1]),
+            cfg.num_clients,
+        )
     train_phase = "train+encrypt+aggregate" if cfg.encrypted else "train+aggregate"
 
     # Robustness mode: any of fault injection, a client count that needs
@@ -656,47 +693,48 @@ def run_experiment(
     engine = None
     server = None
     if streaming:
-        jp = cfg.journal_path
-        if cfg.serve and not jp:
-            # Serve mode defaults the journal next to the checkpoint —
-            # the "durable artifacts of this run" directory.
-            jp = os.path.join(
-                os.path.dirname(cfg.checkpoint_path) or "."
-                if cfg.checkpoint_path
-                else ".",
-                "journal.wal",
-            )
-        if jp:
-            # Durable aggregation service: the engine wrapped in the
-            # recover-then-serve write-ahead-journal lifecycle
-            # (fl.server). Construction IS recovery — a journal left by
-            # a crashed process is replayed here, torn tail truncated,
-            # carried uploads and the dedup window rebuilt.
-            from hefl_tpu.fl import AggregationServer
-
-            engine = server = AggregationServer(
-                cfg.stream, cfg.faults, journal_path=jp,
-                fsync_policy=cfg.fsync_policy, crash=cfg.crash,
-            )
-            rec = server.recovered
-            if not rec.fresh_journal:
-                say(
-                    f"journal {jp}: recovered {rec.records} records "
-                    f"(sealed rounds {list(rec.sealed_rounds)}, open "
-                    f"round {rec.open_round}, {rec.carried_uploads} "
-                    f"carried uploads"
-                    + (
-                        f", torn tail of {rec.torn_bytes_truncated} bytes "
-                        "truncated"
-                        if rec.torn_bytes_truncated
-                        else ""
-                    )
-                    + ")"
+        with obs_spans.span("hefl.setup.engine"):
+            jp = cfg.journal_path
+            if cfg.serve and not jp:
+                # Serve mode defaults the journal next to the checkpoint —
+                # the "durable artifacts of this run" directory.
+                jp = os.path.join(
+                    os.path.dirname(cfg.checkpoint_path) or "."
+                    if cfg.checkpoint_path
+                    else ".",
+                    "journal.wal",
                 )
-        else:
-            from hefl_tpu.fl import StreamEngine
+            if jp:
+                # Durable aggregation service: the engine wrapped in the
+                # recover-then-serve write-ahead-journal lifecycle
+                # (fl.server). Construction IS recovery — a journal left by
+                # a crashed process is replayed here, torn tail truncated,
+                # carried uploads and the dedup window rebuilt.
+                from hefl_tpu.fl import AggregationServer
 
-            engine = StreamEngine(cfg.stream, cfg.faults)
+                engine = server = AggregationServer(
+                    cfg.stream, cfg.faults, journal_path=jp,
+                    fsync_policy=cfg.fsync_policy, crash=cfg.crash,
+                )
+                rec = server.recovered
+                if not rec.fresh_journal:
+                    say(
+                        f"journal {jp}: recovered {rec.records} records "
+                        f"(sealed rounds {list(rec.sealed_rounds)}, open "
+                        f"round {rec.open_round}, {rec.carried_uploads} "
+                        f"carried uploads"
+                        + (
+                            f", torn tail of {rec.torn_bytes_truncated} bytes "
+                            "truncated"
+                            if rec.torn_bytes_truncated
+                            else ""
+                        )
+                        + ")"
+                    )
+            else:
+                from hefl_tpu.fl import StreamEngine
+
+                engine = StreamEngine(cfg.stream, cfg.faults)
         robust = True
     dp_sample_rate = 1.0
     if streaming and 0 < cfg.stream.cohort_size < cfg.num_clients:
@@ -706,7 +744,9 @@ def run_experiment(
 
     history: list[dict[str, Any]] = []
     span_tracers: list[Any] = []   # one SpanTracer per streaming round
+    obs_spans.stop(obs_spans.SETUP)
     for r in range(start_round, cfg.rounds):
+        obs_spans.start(obs_spans.ROUND, round=r)
         # Tracing (SURVEY.md §5): the reference brackets phases with
         # time.time()+print; we keep that (PhaseTimer below) and add a real
         # profiler trace of the first executed round on request.
@@ -742,6 +782,10 @@ def run_experiment(
                 smeta = None
                 if cfg.encrypted:
                     with timer.phase("train+encrypt+aggregate"):
+                        # the host's steps inside the phase, as spans:
+                        # dispatch (the round's entry point until it
+                        # returns), prefetch, device_wait
+                        obs_spans.start(_ENC_STEP + "dispatch")
                         if streaming:
                             # Streaming quorum aggregation: arrivals fold
                             # online into a running modular sum; straggler
@@ -786,25 +830,28 @@ def run_experiment(
                                 xs_d, ys_d, k_round, dp=dp_cfg,
                                 num_real_clients=num_real, packing=pspec,
                             )
+                        obs_spans.stop(_ENC_STEP + "dispatch")
                         # Stage the next round's arrays while this round
                         # computes (no-op while the dataset stays
                         # resident; see RoundPrefetcher).
-                        prefetcher.prefetch(xs, ys)
-                        jax.block_until_ready((ct_sum.c0, ct_sum.c1, metrics))
+                        with obs_spans.span(_ENC_STEP + "prefetch"):
+                            prefetcher.prefetch(xs, ys)
+                        with obs_spans.span(_ENC_STEP + "device_wait"):
+                            jax.block_until_ready(
+                                (ct_sum.c0, ct_sum.c1, metrics)
+                            )
                         if straggler_s > 0 and not streaming:
                             # The synchronous round waits for its slowest
                             # scheduled straggler (driver-level simulation;
                             # shows up in the phase wall-clock like a real
-                            # straggler would). The TraceAnnotation makes
-                            # the wait a first-class host span in profiler
-                            # traces (obs.trace `host_rows`) instead of an
+                            # straggler would). The span makes the wait
+                            # a first-class host row in profiler traces
+                            # (obs.trace `host_rows`) instead of an
                             # unexplained wall-vs-device gap. The streaming
                             # engine instead CONSUMES the schedule as
                             # per-client arrival times (hefl.quorum_wait
                             # carries any real waiting there).
-                            with jax.profiler.TraceAnnotation(
-                                obs_scopes.STRAGGLER_WAIT
-                            ):
+                            with obs_spans.span(obs_scopes.STRAGGLER_WAIT):
                                 time.sleep(straggler_s)
                     with timer.phase("decrypt"):
                         if meta is not None and meta.surviving == 0:
@@ -838,10 +885,12 @@ def run_experiment(
                                 packing=pspec, base_params=params,
                                 hhe=hhe_on,
                             )
-                            jax.block_until_ready(new_params)
+                            with obs_spans.span("hefl.phase.decrypt.wait"):
+                                jax.block_until_ready(new_params)
                 else:
                     overflow = None
                     with timer.phase("train+aggregate"):
+                        obs_spans.start(_PLAIN_STEP + "dispatch")
                         if robust:
                             new_params, metrics, meta = fedavg_round(
                                 module, train_cfg, mesh, params, xs_d, ys_d,
@@ -853,12 +902,13 @@ def run_experiment(
                                 module, train_cfg, mesh, params, xs_d, ys_d,
                                 k_round, num_real_clients=num_real,
                             )
-                        prefetcher.prefetch(xs, ys)
-                        jax.block_until_ready((new_params, metrics))
+                        obs_spans.stop(_PLAIN_STEP + "dispatch")
+                        with obs_spans.span(_PLAIN_STEP + "prefetch"):
+                            prefetcher.prefetch(xs, ys)
+                        with obs_spans.span(_PLAIN_STEP + "device_wait"):
+                            jax.block_until_ready((new_params, metrics))
                         if straggler_s > 0:
-                            with jax.profiler.TraceAnnotation(
-                                obs_scopes.STRAGGLER_WAIT
-                            ):
+                            with obs_spans.span(obs_scopes.STRAGGLER_WAIT):
                                 time.sleep(straggler_s)
                 params = new_params
                 break
@@ -1019,6 +1069,7 @@ def run_experiment(
             record["robust"] = rob
         history.append(record)
         _record_round_obs(r, phases, dev)
+        obs_spans.stop(obs_spans.ROUND)   # before the stamp that closes it
         obs_events.emit(
             "round_end", round=r,
             accuracy=round(record["accuracy"], 6),
@@ -1071,26 +1122,28 @@ def run_experiment(
     if server is not None:
         server.close()
     span_trace = None
-    if cfg.span_trace_path and span_tracers:
-        from hefl_tpu.obs import spans as obs_spans
-
+    if cfg.span_trace_path:
+        # This call's host spans, with the streaming rounds' trees.
         span_trace = obs_spans.export_chrome_trace(
-            cfg.span_trace_path, span_tracers
+            cfg.span_trace_path, span_tracers,
+            obs_spans.recorded(call=call_id),
         )
         say(
-            f"span trace: {len(span_tracers)} round(s) -> {span_trace} "
+            f"span trace: this call's host spans and {len(span_tracers)} "
+            f"streaming round(s) -> {span_trace} "
             "(Chrome trace-viewer / obs.trace loadable)"
         )
         obs_events.emit(
             "span_trace", path=span_trace, rounds=len(span_tracers)
         )
     obs_record = _finish_run_obs(metrics_base, rounds=len(history))
+    obs_spans.end_call()
     return {
         "history": history,
         "final_metrics": history[-1] if history else None,
         "params": params,
-        # Round-lifecycle span export (ISSUE 20): the written trace path
-        # (None = not requested or no streaming rounds ran).
+        # Span export (obs.spans): the written trace path (None = not
+        # requested).
         "span_trace": span_trace,
         # Durable-aggregation record (None = in-memory engine): journal
         # path, fsync policy, and what recovery found on startup.

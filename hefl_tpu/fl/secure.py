@@ -59,6 +59,7 @@ from hefl_tpu.fl.fedavg import (
 from hefl_tpu.ckks.modular import add_mod as modular_add_mod
 from hefl_tpu.ckks.modular import barrett_mod, barrett_mu
 from hefl_tpu.obs import scopes as obs_scopes
+from hefl_tpu.obs import spans as obs_spans
 from hefl_tpu.parallel import (
     client_axes,
     client_mesh_size,
@@ -66,6 +67,9 @@ from hefl_tpu.parallel import (
     shard_map,
 )
 from hefl_tpu.parallel.collectives import MAX_PSUM_CLIENTS, hierarchical_psum_mod
+
+# decrypt_average's host steps, as spans: kernel, decode, unpack
+_DECRYPT_STEP = obs_spans.PHASE_PREFIX + "decrypt."
 
 
 @partial(jax.jit, static_argnums=0)
@@ -574,36 +578,47 @@ def decrypt_average(
         )
     else:
         surviving = int(num_clients)
+    # The owner's three host steps, each a span (children of the driver's
+    # `hefl.phase.decrypt` when it is open): launching the decrypt kernel,
+    # the op-by-op decode, and the unpack into the parameter pytree.
     with jax.named_scope(obs_scopes.DECRYPT):
-        if mesh is not None:
-            res = decrypt_sharded(ctx, sk, ct_sum, mesh)
-        else:
-            res = ops.decrypt(ctx, sk, _on_one_device(ct_sum))
+        with obs_spans.span(_DECRYPT_STEP + "kernel"):
+            if mesh is not None:
+                res = decrypt_sharded(ctx, sk, ct_sum, mesh)
+            else:
+                res = ops.decrypt(ctx, sk, _on_one_device(ct_sum))
         if packing is not None:
-            v = encoding.decode_int_center(ctx.ntt, res)
-            if hhe:
-                # Transciphered aggregate: the decode carries the cipher's
-                # per-client wrap multiples (-2**62 * Gamma); one shifted
-                # mod-2**62 reduction recovers the exact packed sum —
-                # bitwise the direct path's decode input
-                # (hhe.cipher.hhe_center_mod; window proven by
-                # analysis.certify_transciphering).
-                from hefl_tpu.hhe.cipher import hhe_center_mod
+            with obs_spans.span(_DECRYPT_STEP + "decode"):
+                v = encoding.decode_int_center(ctx.ntt, res)
+                if hhe:
+                    # Transciphered aggregate: the decode carries the
+                    # cipher's per-client wrap multiples (-2**62 * Gamma);
+                    # one shifted mod-2**62 reduction recovers the exact
+                    # packed sum — bitwise the direct path's decode input
+                    # (hhe.cipher.hhe_center_mod; window proven by
+                    # analysis.certify_transciphering).
+                    from hefl_tpu.hhe.cipher import hhe_center_mod
 
-                v = hhe_center_mod(v, packing.guard)
-            delta = unpack_quantized(v, packing, surviving)
-            base_flat, unravel = ravel_pytree(base_params)
-            return unravel(base_flat + jnp.asarray(delta))
-        denom = ct_sum.scale * surviving
-        if exact:
-            blocks = jnp.asarray(
-                encoding.decode_exact(
-                    ctx.ntt, np.asarray(res), denom
-                ).astype(np.float32)
-            )
-        else:
-            blocks = encoding.decode(ctx.ntt, res, denom)
-        return unpack_blocks(blocks, spec)
+                    v = hhe_center_mod(v, packing.guard)
+                delta = unpack_quantized(v, packing, surviving)
+            with obs_spans.span(_DECRYPT_STEP + "unpack"):
+                base_flat, unravel = ravel_pytree(base_params)
+                return unravel(base_flat + jnp.asarray(delta))
+        # (the decode scale is written out twice rather than named: this
+        # frame keeps the size it had, see run_experiment's note on frames)
+        with obs_spans.span(_DECRYPT_STEP + "decode"):
+            if exact:
+                blocks = jnp.asarray(
+                    encoding.decode_exact(
+                        ctx.ntt, np.asarray(res), ct_sum.scale * surviving
+                    ).astype(np.float32)
+                )
+            else:
+                blocks = encoding.decode(
+                    ctx.ntt, res, ct_sum.scale * surviving
+                )
+        with obs_spans.span(_DECRYPT_STEP + "unpack"):
+            return unpack_blocks(blocks, spec)
 
 
 def secure_fedavg_round(

@@ -304,7 +304,8 @@ class AggregationServer:
                 # ignores when a replayed round is compared against its
                 # uninterrupted twin.
                 tracer.add(
-                    "recovery_replay", 0.0, tracer.wall(), clock="wall",
+                    "recovery_replay", tracer.wall0, tracer.wall(),
+                    clock="wall",
                     records=len(replay), refolded=int(sess.replayed_folds),
                 )
         return out

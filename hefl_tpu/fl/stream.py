@@ -1993,7 +1993,7 @@ class StreamEngine:
             # Map simulated waiting onto wall-clock so the wait is a real,
             # attributable host span (obs.trace host_rows), like the
             # synchronous driver's straggler sleep.
-            with jax.profiler.TraceAnnotation(obs_scopes.QUORUM_WAIT):
+            with obs_spans.span(obs_scopes.QUORUM_WAIT):
                 time.sleep(float(commit_s) * s.time_scale)
 
         if session is not None:

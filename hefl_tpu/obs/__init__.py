@@ -1,6 +1,6 @@
 """Trace-native observability: phase scopes, run events, metrics, traces.
 
-Three legs, one subsystem (ISSUE 5):
+Four legs, one subsystem (ISSUE 5):
 
   * `obs.scopes` — the canonical `jax.named_scope` names the round
     program's phases are annotated with (augment / sgd_core / val /
@@ -15,12 +15,27 @@ Three legs, one subsystem (ISSUE 5):
     counter/gauge registry (exclusions by cause, retries, resumes,
     autoselect outcomes, XLA new-executable count, device-memory
     high-water) embedded in every bench/profile/chaos artifact.
-  * `obs.spans` / `obs.trend` (ISSUE 20) — per-round lifecycle span
-    trees on the engine's virtual clock (arrival/fold/ship/commit/
-    recovery, exported as Chrome trace-viewer JSON `obs.trace` can load)
-    and the bench-history trend gate (`python -m hefl_tpu.obs.trend`)
-    that turns the committed BENCH_*.json trajectory into TREND.md and a
-    regression check.
+  * `obs.spans` — the ONE recorder of host spans (ISSUE 24). Every
+    host-side timing goes through `obs.spans.span(name)` (or `start(name)`
+    / `stop(name)` where a `with` would indent too much): PhaseTimer's
+    phases (`hefl.phase.<phase>`), the steps inside them
+    (`hefl.phase.<phase>.<step>`: dispatch / prefetch / device_wait;
+    decrypt's kernel / decode / unpack / wait), `run_experiment`'s start
+    (`hefl.setup` and its `hefl.setup.<step>` children), one `hefl.round`
+    a round, the straggler and quorum waits, and the engine's journal /
+    fsync / transcipher / replay legs. Always on: a span is a row (id,
+    parent, name, call, round, t0_ns, t1_ns) of a process-wide, bounded,
+    in-memory store (`obs.spans.recorded()`), written nowhere at record
+    time, plus a `jax.profiler.TraceAnnotation` over the same interval on
+    the same unix-epoch clock, so a profiler trace carries it beside the
+    device ops. Export: `ExperimentConfig.span_trace_path` /
+    `--span-trace PATH` writes a call's spans as Chrome trace-viewer JSON.
+    The module also holds the streaming engine's per-round lifecycle span
+    TREE on its virtual clock (`SpanTracer`, ISSUE 20:
+    arrival/fold/ship/commit/recovery, in the same export).
+  * `obs.trend` (ISSUE 20) — the bench-history trend gate
+    (`python -m hefl_tpu.obs.trend`) that turns the committed BENCH_*.json
+    trajectory into TREND.md and a regression check.
 """
 
 from hefl_tpu.obs import events, metrics, scopes, spans, trace, trend

@@ -44,10 +44,11 @@ SERVE_ROTATE = "hefl.serve_rotate"    # rotation sweep bodies (ladder/BSGS)
 SERVE_KEYSWITCH = "hefl.serve_keyswitch"  # gadget key-switch (fused kernel)
 SERVE_HOIST = "hefl.serve_hoist"      # hoisted decompose + per-step products
 
-# HOST-side spans (jax.profiler.TraceAnnotation, not named_scope): driver
-# work that owns wall-clock but runs no device ops. The trace parser
-# reports them as `host_rows` so e.g. a straggler wait is a first-class
-# row instead of an unexplained wall-vs-device gap.
+# HOST-side spans (recorded through `obs.spans.span`, which opens a
+# jax.profiler.TraceAnnotation; not named_scope): driver work that owns
+# wall-clock but runs no device ops. The trace parser reports them as
+# `host_rows` so e.g. a straggler wait is a first-class row instead of an
+# unexplained wall-vs-device gap.
 STRAGGLER_WAIT = "hefl.straggler_wait"  # driver-side straggler sleep
 QUORUM_WAIT = "hefl.quorum_wait"        # streaming engine's wait-for-quorum
 

@@ -5,13 +5,16 @@ around every expensive phase (/root/reference/FLPyfhelin.py:203,223-224,235,
 243-248,264-267,305,326-327 and notebook cell 3's `t.append`). `PhaseTimer`
 formalizes exactly that phase schema — train / encrypt / aggregate /
 decrypt / evaluate — as a reusable collector whose dict output is the
-benchmark record (BASELINE.md's table rows).
+benchmark record (BASELINE.md's table rows). It keeps no clock of its own:
+a phase is a span of the program's one recorder (`obs.spans`), named
+`hefl.phase.<name>`, and its seconds are that span's.
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
+
+from hefl_tpu.obs import spans as obs_spans
 
 
 class PhaseTimer:
@@ -28,24 +31,14 @@ class PhaseTimer:
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        # Host-side span (obs): the driver phase also shows up as a
-        # TraceAnnotation in profiler traces, so a --profile trace carries
-        # the wall-clock phase brackets alongside the device-op events.
+        # A host span: a row of the recorder's store and, whenever the
+        # profiler is on, a TraceAnnotation beside the device-op events.
+        timed = obs_spans.span(obs_spans.PHASE_PREFIX + name)
         try:
-            import jax.profiler
-
-            span = jax.profiler.TraceAnnotation(f"hefl.phase.{name}")
-        except ImportError:  # timers stay usable without jax
-            span = contextlib.nullcontext()
-        start = time.perf_counter()
-        try:
-            with span:
+            with timed:
                 yield
         finally:
-            dt = time.perf_counter() - start
-            if name not in self._elapsed:
-                self._order.append(name)
-            self._elapsed[name] = self._elapsed.get(name, 0.0) + dt
+            self.record(name, timed.record.seconds)
 
     def record(self, name: str, seconds: float) -> None:
         """Fold an externally-measured duration into the schema."""

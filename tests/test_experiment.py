@@ -54,6 +54,88 @@ def test_encrypted_experiment_two_rounds():
         assert np.isfinite(leaf)
 
 
+def test_two_rounds_leave_one_of_each_sub_span_a_round():
+    """Every host-side timing of a call is a span of the one recorder:
+    the set-up steps once, each round's phases and their steps once a
+    round, children inside their parents; `history[r]["phases"]` is what it
+    was."""
+    from hefl_tpu.obs import spans as obs_spans
+
+    out = run_experiment(_tiny_cfg(), verbose=False)
+    rows = obs_spans.recorded()
+    call = max(s.call for s in rows if s.call is not None)
+    rows = [s for s in rows if s.call == call]
+    by_id = {s.id: s for s in rows}
+    train = "hefl.phase.train+encrypt+aggregate"
+    in_a_round = {
+        "hefl.round": None,
+        train: "hefl.round",
+        train + ".dispatch": train,
+        train + ".prefetch": train,
+        train + ".device_wait": train,
+        "hefl.phase.decrypt": "hefl.round",
+        "hefl.phase.decrypt.kernel": "hefl.phase.decrypt",
+        "hefl.phase.decrypt.decode": "hefl.phase.decrypt",
+        "hefl.phase.decrypt.unpack": "hefl.phase.decrypt",
+        "hefl.phase.decrypt.wait": "hefl.phase.decrypt",
+        "hefl.phase.evaluate": "hefl.round",
+    }
+    for r in (0, 1):
+        of_round = [s for s in rows if s.round == r]
+        assert sorted(s.name for s in of_round) == sorted(in_a_round)
+        for s in of_round:
+            parent = by_id.get(s.parent)
+            assert (parent.name if parent else None) == in_a_round[s.name]
+            if parent is not None:
+                assert parent.round == r
+                assert parent.t0_ns <= s.t0_ns <= s.t1_ns <= parent.t1_ns
+    setup = [s for s in rows if s.round is None]
+    root = [s for s in setup if s.name == "hefl.setup"]
+    assert len(root) == 1 and root[0].parent is None
+    steps = [s.name for s in setup if s.parent == root[0].id]
+    assert sorted(steps) == sorted([
+        "hefl.setup.data", "hefl.setup.stage", "hefl.setup.stage",
+        "hefl.setup.model", "hefl.setup.context", "hefl.setup.preflight",
+        "hefl.setup.keygen", "hefl.setup.roofline_inputs"])
+    assert len(setup) == 1 + len(steps)
+    first_round = next(s for s in rows if s.name == "hefl.round" and s.round == 0)
+    assert root[0].t1_ns <= first_round.t0_ns
+    # PhaseTimer's record is its spans': same keys, same seconds
+    for r, rec in enumerate(out["history"]):
+        assert list(rec["phases"]) == [
+            "train+encrypt+aggregate", "decrypt", "evaluate", "total"]
+        for s in rows:
+            if s.round == r and s.parent is not None and by_id[
+                    s.parent].name == "hefl.round":
+                key = s.name[len("hefl.phase."):]
+                assert rec["phases"][key] == round(s.seconds, 4)
+
+
+def test_frames_under_the_round_programs_trace_keep_their_size():
+    """CPython 3.12 keeps Python frames in 16 KB chunks and is 40-100x slower
+    on a call that straddles two of them. JAX traces the round program some
+    hundreds of frames above `run_experiment`, so the size of the frames
+    below that trace decides which of its hot calls land on a boundary: on
+    the chip's host, one more frame (or a few more locals) here cost the
+    resnet20 cell 8-15 s of `setup_s`, 10-19% (PERF.md, PR 24). A change
+    that moves these numbers is not wrong, but it owes a chip run of
+    `resnet20.sync_e1` and `medcnn.sync_e10` that shows `setup_s` held."""
+    import sys
+
+    from hefl_tpu.fl import secure
+
+    if sys.version_info[:2] != (3, 12):
+        pytest.skip("frame sizes are the interpreter's: pinned for 3.12")
+    size = lambda f: (  # noqa: E731
+        f.__code__.co_nlocals + f.__code__.co_stacksize
+        + len(f.__code__.co_cellvars) + len(f.__code__.co_freevars))
+    assert size(run_experiment) == 116
+    assert size(secure.secure_fedavg_round) == 53
+    assert size(secure.decrypt_average) == 29
+    # and no frame of the program stands between the two
+    assert "secure_fedavg_round" in run_experiment.__code__.co_names
+
+
 def test_plaintext_experiment_and_label_skew():
     out = run_experiment(
         _tiny_cfg(encrypted=False, partition="label_skew", rounds=1), verbose=False

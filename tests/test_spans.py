@@ -15,6 +15,10 @@
     span events on the JSONL log rebuild the same tree
   * the trend gate (obs.trend): clean history passes, the seeded
     regression fixture fails it, an empty history exits 2
+  * the host-span recorder (ISSUE 24): nesting and `parent`, `call` and
+    `round` ids, the bound, nothing written to events.jsonl, PhaseTimer
+    and SpanTracer.measure as its users, `--span-trace` holding a
+    synchronous run's spans
 """
 
 import json
@@ -327,6 +331,183 @@ def test_chrome_trace_export_roundtrips(tmp_path, monkeypatch):
     ) == obs_spans.tree_signature(
         tracer.root, ignore=(), include_wall=True
     )
+
+
+# ------------------------------------------------- the host-span recorder
+
+
+def test_recorder_nesting_parent_call_and_round_ids():
+    rec = obs_spans.Recorder()
+    with rec.span("outside") as outside:
+        pass
+    with rec.call() as first:
+        with rec.span("hefl.round", round=7) as rnd:
+            with rec.span("hefl.phase.decrypt") as phase:
+                with rec.span("hefl.phase.decrypt.decode") as step:
+                    pass
+            with rec.span("hefl.phase.evaluate", round=8) as other:
+                pass
+    with rec.call() as second:
+        with rec.span("hefl.setup") as setup:
+            pass
+    rows = rec.recorded()
+    assert [s.name for s in rows] == [   # in the order they ended
+        "outside", "hefl.phase.decrypt.decode", "hefl.phase.decrypt",
+        "hefl.phase.evaluate", "hefl.round", "hefl.setup"]
+    assert len({s.id for s in rows}) == len(rows)
+    assert (outside.parent, outside.call, outside.round) == (None, None, None)
+    assert (rnd.parent, phase.parent, step.parent) == (None, rnd.id, phase.id)
+    assert other.parent == rnd.id
+    # `round` is the parent's unless given; `call` counts calls from 0
+    assert (rnd.round, phase.round, step.round, other.round) == (7, 7, 7, 8)
+    assert (first, second) == (0, 1)
+    assert {s.call for s in (rnd, phase, step, other)} == {0}
+    assert (setup.call, setup.round) == (1, None)
+    for child, parent in ((step, phase), (phase, rnd), (other, rnd)):
+        assert parent.t0_ns <= child.t0_ns <= child.t1_ns <= parent.t1_ns
+    assert step.seconds == (step.t1_ns - step.t0_ns) * 1e-9
+
+
+def test_recorder_is_bounded_and_closes_what_an_error_leaves_open():
+    rec = obs_spans.Recorder(bound=4)
+    for i in range(10):
+        with rec.span(f"s{i}"):
+            pass
+    assert [s.name for s in rec.recorded()] == ["s6", "s7", "s8", "s9"]
+    # start()/stop() with no `with` and no handle: an error between them
+    # leaves the span and its children open; the call closes them on its
+    # way out
+    rec = obs_spans.Recorder()
+    rec.start("hefl.setup")
+    rec.start("hefl.setup.model")
+    rec.stop("hefl.setup")          # closes what is open inside it too
+    rec.stop("hefl.setup")          # nothing of that name is open: no-op
+    assert [s.name for s in rec.recorded()] == ["hefl.setup.model", "hefl.setup"]
+    assert rec._stack() == []
+    rec = obs_spans.Recorder()
+    with pytest.raises(KeyError):
+        with rec.call():
+            rec.start("hefl.round", round=0)
+            rec.start("hefl.phase.decrypt")
+            raise KeyError("lost")
+    rows = rec.recorded()
+    assert [s.name for s in rows] == ["hefl.phase.decrypt", "hefl.round"]
+    assert all(s.t1_ns is not None and s.t1_ns >= s.t0_ns for s in rows)
+    assert rec.call_id is None and rec._stack() == []
+    with rec.span("after") as after:   # the stack is clean again
+        pass
+    assert after.parent is None
+    # run_experiment begins and ends its call by hand, so an error can
+    # pass without ending it: the next call ends it first
+    assert rec.begin_call() == 1
+    rec.start("hefl.round", round=3)
+    assert rec.call_id == 1 and len(rec._stack()) == 1
+    with rec.call() as third:
+        assert third == 2 and rec._stack() == []
+        assert rec.recorded()[-1].name == "hefl.round"
+        assert rec.recorded(call=1) == rec.recorded()[-1:]
+    assert rec.call_id is None
+    rec.end_call()   # with no call open: harmless
+    assert rec.call_id is None and rec.recorded(call=2) == []
+    assert obs_spans.MAX_SPANS >= 1 << 14
+    assert obs_spans._RECORDER._spans.maxlen == obs_spans.MAX_SPANS
+
+
+def test_recording_writes_nothing_to_the_event_log(tmp_path, monkeypatch):
+    monkeypatch.setenv("HEFL_EVENTS", "1")
+    ev_path = str(tmp_path / "events.jsonl")
+    obs_events.configure(ev_path)
+    try:
+        obs_events.emit("marker")
+        size = os.path.getsize(ev_path)
+        before = len(obs_spans.recorded())
+        for _ in range(100):
+            with obs_spans.span("hefl.phase.decrypt"):
+                with obs_spans.span("hefl.phase.decrypt.decode"):
+                    pass
+        assert len(obs_spans.recorded()) == min(before + 200,
+                                                obs_spans.MAX_SPANS)
+        assert os.path.getsize(ev_path) == size
+    finally:
+        obs_events.configure(None)
+
+
+def test_phase_timer_summary_is_its_spans_durations():
+    from hefl_tpu.utils import PhaseTimer
+
+    timer = PhaseTimer()
+    before = len(obs_spans.recorded())
+    with timer.phase("train+encrypt+aggregate"):
+        pass
+    with timer.phase("decrypt"):
+        with obs_spans.span("hefl.phase.decrypt.decode"):
+            pass
+    with pytest.raises(ValueError):
+        with timer.phase("decrypt"):   # re-entered, and left by an error
+            raise ValueError
+    rows = obs_spans.recorded()[before:]
+    assert [s.name for s in rows] == [
+        "hefl.phase.train+encrypt+aggregate", "hefl.phase.decrypt.decode",
+        "hefl.phase.decrypt", "hefl.phase.decrypt"]
+    want = {}
+    for s in rows:
+        if s.name.count(".") == 2:
+            key = s.name[len(obs_spans.PHASE_PREFIX):]
+            want[key] = want.get(key, 0.0) + s.seconds
+    summary = timer.summary()
+    assert list(summary) == ["train+encrypt+aggregate", "decrypt", "total"]
+    for key, seconds in want.items():
+        assert summary[key] == round(seconds, 4)
+    assert summary["total"] == round(sum(want.values()), 4)
+
+
+def test_span_tracer_measure_is_on_the_recorders_clock():
+    tracer = obs_spans.SpanTracer(5)
+    t_lo = obs_spans.now_ns()
+    with tracer.measure("fsync", frames=3) as sp:
+        pass
+    t_hi = obs_spans.now_ns()
+    row = obs_spans.recorded()[-1]
+    assert (row.name, row.round) == ("hefl.span.fsync", 5)
+    assert t_lo <= row.t0_ns <= row.t1_ns <= t_hi
+    # the tree's wall-clock span is the same interval, in unix seconds
+    assert sp.clock == "wall" and sp.args == {"frames": 3}
+    assert sp.t0 == row.t0_ns * 1e-9 and sp.t1 == row.t1_ns * 1e-9
+    assert t_lo * 1e-9 - 1.0 <= tracer.wall0 <= sp.t0
+    # exported from the tracer's opening by default, from a shared base
+    # when it is laid beside the recorder's rows
+    own = [e for e in tracer.to_trace_events()
+           if e["name"] == "hefl.span.fsync"]
+    assert 0 <= own[0]["ts"] <= (t_hi - t_lo) / 1e3 + 1e6
+    host = obs_spans.host_trace_events([row], base_ns=row.t0_ns)
+    assert host[0]["ts"] == 0 and host[0]["args"]["t0_ns"] == row.t0_ns
+    assert host[0]["dur"] == (row.t1_ns - row.t0_ns) / 1e3
+
+
+def test_span_trace_holds_a_synchronous_runs_spans(tmp_path):
+    from hefl_tpu.experiment import ExperimentConfig, HEConfig, run_experiment
+
+    out_path = str(tmp_path / "spans.trace.json")
+    out = run_experiment(ExperimentConfig(
+        model="smallcnn", dataset="mnist", num_clients=2, rounds=2,
+        train=CFG, he=HEConfig(n=256), n_train=32, n_test=16, seed=3,
+        span_trace_path=out_path,
+    ), verbose=False)
+    assert out["span_trace"] == out_path
+    with open(out_path) as f:
+        doc = json.load(f)
+    events = obs_trace.load_trace_events(out_path)
+    names = [e["name"] for e in events]
+    assert names.count("hefl.setup") == 1 and names.count("hefl.round") == 2
+    for name in ("hefl.phase.train+encrypt+aggregate.dispatch",
+                 "hefl.phase.decrypt.decode", "hefl.phase.evaluate"):
+        assert names.count(name) == 2
+    calls = {e["args"]["call"] for e in events}
+    assert len(calls) == 1 and None not in calls   # this call's spans only
+    assert min(e["ts"] for e in events) == 0
+    assert doc["wall_base_ns"] == min(e["args"]["t0_ns"] for e in events)
+    assert {e["args"]["round"] for e in events
+            if e["name"] == "hefl.round"} == {0, 1}
 
 
 # ------------------------------------------------------- trend gate
