@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from hefl_tpu.models.cnn import LogReg, MedCNN, SmallCNN, count_params
 from hefl_tpu.models.resnet import ResNet20
+from hefl_tpu.obs import metrics as obs_metrics
 
 # name -> (module class, default num_classes, default input shape): each
 # model's defaults are the dataset it was designed for, so
@@ -46,6 +47,8 @@ def create_model(
     module = cls(num_classes=num_classes if num_classes is not None else default_classes)
     if rng is None:
         rng = jax.random.key(0)
+    # MedCNN's stages set it as they are traced; no other model has any.
+    obs_metrics.gauge("model.polyphase_stages").set(0)
     dummy = jnp.zeros(
         (1, *(input_shape if input_shape is not None else default_shape)), jnp.float32
     )

@@ -9,22 +9,214 @@ and the parameter count is exactly 222,722 in 18 weight tensors
 (SURVEY.md §2.3) — the HE sizing contract for the encrypted FedAvg path.
 
 TPU notes: convolutions and matmuls run in bfloat16 (MXU-native) with
-float32 params and float32 accumulation; shapes are static so XLA tiles
-everything onto the systolic array. The softmax is NOT part of the model by
-default (we return logits and fold it into the loss, the numerically-stable
-JAX idiom); `apply_softmax=True` recovers the Keras probs-output behavior
-for prediction parity.
+float32 params and float32 accumulation. The softmax is NOT part of the
+model by default (we return logits and fold it into the loss, the
+numerically-stable JAX idiom); `apply_softmax=True` recovers the Keras
+probs-output behavior for prediction parity.
+
+What the chip's trace showed (PERF.md sections 5 and 6, PR 25): the training
+step is bound by HBM bytes, not by the MXU (1 ms of multiply-adds in a 42 ms
+step). XLA lays an activation out with its channels in a tile's 128 lanes,
+so a 32-channel map fills a quarter of every tile, occupies and moves four
+times its bytes, and the max-pool's backward (`select-and-scatter`) was the
+longest op of the step. So the large early stages run in POLYPHASE form
+(`_conv_stages`, `_polyphase_block` for the rule): the stage is computed on
+a space-to-depth form of its input, with a kernel built from the 3x3
+parameter by a constant 0/1 selection, so that the conv outputs of one
+block sit side by side in the lanes and the pool is an elementwise maximum
+of four lane groups. Same parameters, same products summed, the same
+first-maximum pool gradient; at 256x256x3 the first stage takes 4x4 blocks
+and hands its pooled map, still in 2x2 form, to the second (2x2 blocks),
+and the step went 47.1 -> 14.4 ms on a TPU v5e.
 """
 
 from __future__ import annotations
 
+import itertools
+from functools import partial
 from typing import Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from hefl_tpu.models.folded import folded_conv, folded_dense
+from hefl_tpu.obs import metrics as obs_metrics
+
+
+_LANES = 128  # the minor dimension of a TPU tile
+# The smallest pooled map at which the form paid (PERF.md section 5: MedCNN's
+# 127- and 62-wide stages gained on the chip, its 30-wide one lost).
+_MIN_POLYPHASE_MAP = 48
+
+
+def _polyphase_block(hw, ci: int, co: int) -> int:
+    """THE rule for which [3x3 VALID conv -> ReLU -> 2x2/2 max-pool] stages
+    run in polyphase form, read from the stage's own shapes (PERF.md section
+    5 has what each stage read on the chip). -> 0 for the plain stage, else
+    the block `s`: the stage takes its input in s x s space-to-depth form.
+
+    A plain stage leaves `co` of a tile's 128 lanes in use and pays for a
+    padded activation at every pass over it; the form pays where that map is
+    large and its four pool phases fit the lanes (`4 * co <= 128`). Block 4
+    makes 7.1x the stage's multiply-adds (block 2: 1.8x) on an MXU that the
+    plain stage leaves idle, so it is taken where they are few: where the
+    4 x 4 block of the input fits one tile of lanes (`16 * ci <= 128`: an
+    image's 1 or 3 channels). Its pool groups are then whole tiles and its
+    output is already the space-to-depth form a block-2 stage takes."""
+    hp, wp = (hw[0] - 2) // 2, (hw[1] - 2) // 2
+    if 4 * co > _LANES or min(hp, wp) < _MIN_POLYPHASE_MAP:
+        return 0
+    return 4 if 16 * ci <= _LANES else 2
+
+
+def _phase_selection(s: int) -> np.ndarray:
+    """sel[u, d, q, p, a] = 1 where a == s*u + d - (2q + p): tap `a` of a
+    3-tap kernel, applied at position 2q + p of an output block (pool phase
+    `p` of pooled position `q`), reads position `d` of input block `u`."""
+    sel = np.zeros((2, s, s, 3), np.float32)
+    for u, d, r in itertools.product(range(2), range(s), range(s)):
+        if 0 <= s * u + d - r <= 2:
+            sel[u, d, r, s * u + d - r] = 1.0
+    return sel.reshape(2, s, s // 2, 2, 3)
+
+
+_SEL = {s: _phase_selection(s) for s in (2, 4)}
+
+
+def _to_depth(x, depth: int, hw, s: int, nb: int, mb: int):
+    """Bring a map from d x d to s x s space-to-depth form with nb x mb
+    blocks, zero-padded past the map (s = 1, nb x mb = hw: the plain map).
+
+    x: [B, n, m, depth*depth*C], the `depth` form of a plain [B, *hw, C] map,
+    channel order (dy, dx, c): x[b, i, j, (dy, dx, c)] = plain[b, depth*i +
+    dy, depth*j + dx, c]. -> [B, nb, mb, s*s*C]."""
+    if depth == s:
+        return x[:, :nb, :mb]
+    b, n, m, c = x.shape
+    c //= depth * depth
+    x = x.reshape(b, n, m, depth, depth, c).transpose(0, 1, 3, 2, 4, 5)
+    x = x.reshape(b, n * depth, m * depth, c)[:, : min(hw[0], s * nb), : min(hw[1], s * mb)]
+    x = jnp.pad(x, ((0, 0), (0, s * nb - x.shape[1]), (0, s * mb - x.shape[2]), (0, 0)))
+    x = x.reshape(b, nb, s, mb, s, c).transpose(0, 1, 3, 2, 4, 5)
+    return x.reshape(b, nb, mb, s * s * c)
+
+
+@jax.custom_vjp
+def _relu_pool4(y):
+    """ReLU of the maximum over the four pool phases, y[..., (p, g)] ->
+    [..., g], as lane slices (whole tiles at block 4), with the gradient of
+    `relu` then `reduce_window` max: a window's cotangent goes whole to its
+    FIRST maximum in window order (0,0), (0,1), (1,0), (1,1), and nowhere
+    if that maximum is not positive. `jnp.max` would split it between ties,
+    which bf16 activations make common, and the training step would no
+    longer be the reference's. The backward needs the winning phase alone
+    (int8), not the activation."""
+    return _relu_pool4_fwd(y)[0]
+
+
+def _relu_pool4_fwd(y):
+    g = y.shape[-1] // 4
+    phases = [y[..., p * g : (p + 1) * g] for p in range(4)]
+    top = jnp.maximum(
+        jnp.maximum(phases[0], phases[1]), jnp.maximum(phases[2], phases[3])
+    )
+    first = jnp.full(top.shape, 4, jnp.int8)
+    for p in (3, 2, 1, 0):
+        first = jnp.where(phases[p] == top, jnp.int8(p), first)
+    return nn.relu(top), jnp.where(top > 0, first, jnp.int8(4))
+
+
+def _relu_pool4_bwd(first, g):
+    return (jnp.concatenate([jnp.where(first == p, g, 0) for p in range(4)], axis=-1),)
+
+
+_relu_pool4.defvjp(_relu_pool4_fwd, _relu_pool4_bwd)
+
+
+def _polyphase_stage(conv, xs, kernel, bias, s: int):
+    """One [3x3 VALID conv -> ReLU -> 2x2/2 max-pool] stage on the s x s
+    space-to-depth form of its input (s = 2 or 4): the s*s conv outputs of a
+    block sit side by side in the channel (lane) dimension, pool phase
+    major, so the pool is an elementwise maximum of four lane groups. The
+    same products summed as the plain stage (the zeros of `k2` aside).
+
+    xs: [B, nb+1, mb+1, s*s*Ci] (`_to_depth`); kernel: [..., 3, 3, Ci, Co]
+    and bias: [..., Co], with whatever leading axes `conv(x, kernel, bias)`
+    takes. -> the pooled map in s/2 form, [B, nb, mb, (s/2)**2 * Co]: plain
+    at s = 2, and at s = 4 the very form a following block-2 stage takes.
+    """
+    ci, co = kernel.shape[-2:]
+    # k2[u, v, (dy, dx, ci), (py, px, qy, qx, co)]
+    #   = kernel[s*u + dy - (2*qy + py), s*v + dx - (2*qx + px), ci, co] or 0:
+    # a constant 0/1 selection, so `kernel` stays the parameter and its
+    # gradient comes back through the selection.
+    k2 = jnp.einsum(
+        "udqpa,vewzb,...abio->...uvdeipzqwo", _SEL[s], _SEL[s], kernel
+    )
+    k2 = k2.reshape(*kernel.shape[:-4], 2, 2, s * s * ci, s * s * co)
+    # y[b, i, j, (py, px, qy, qx, co)] = the plain conv's output at
+    # (s*i + 2*qy + py, s*j + 2*qx + px)
+    return _relu_pool4(conv(xs, k2, jnp.tile(bias, s * s)))
+
+
+def _conv_stages(conv, x, layers):
+    """The model's [conv -> ReLU -> max-pool] stack over `layers`' (kernel,
+    bias) pairs through the lowering `conv(x, kernel, bias)`, each stage in
+    the form `_polyphase_block` gives it. Between two stages the map stays
+    in whatever space-to-depth form it has; rows and columns that the next
+    stage's VALID pool would drop are not computed. Records how many stages
+    took the polyphase form as the gauge `model.polyphase_stages` (set when
+    the model is traced)."""
+    depth, hw, taken = 1, x.shape[1:3], 0
+    for i, (kernel, bias) in enumerate(layers):
+        s = _polyphase_block(hw, *kernel.shape[-2:])
+        pooled = [(n - 2) // 2 for n in hw]
+        if not s:
+            x = _to_depth(x, depth, hw, 1, *hw)
+            x = nn.max_pool(nn.relu(conv(x, kernel, bias)), (2, 2), strides=(2, 2))
+            depth, hw = 1, pooled
+            continue
+        if i + 1 < len(layers):  # all the next stage reads of this one
+            pooled = [min(n, 2 * ((n - 2) // 2) + 2) for n in pooled]
+        nb, mb = (-(-n // (s // 2)) for n in pooled)
+        x = _to_depth(x, depth, hw, s, nb + 1, mb + 1)
+        x = _polyphase_stage(conv, x, kernel, bias, s)
+        depth, hw, taken = s // 2, pooled, taken + 1
+    obs_metrics.gauge("model.polyphase_stages").set(taken)
+    return _to_depth(x, depth, hw, 1, *hw)
+
+
+def _conv_bf16(x, kernel, bias):
+    """flax.linen.Conv(dtype=bfloat16)'s computation: operands and bias
+    rounded to bf16, one 3x3 VALID NHWC convolution, bias added in bf16."""
+    x, kernel, bias = (a.astype(jnp.bfloat16) for a in (x, kernel, bias))
+    y = lax.conv_general_dilated(
+        x, kernel, (1, 1), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC")
+    )
+    return y + bias
+
+
+class _ConvParams(nn.Module):
+    """The parameters of one 3x3 conv under flax.linen.Conv's names, shapes,
+    dtypes and initialisers (the HE packing contract and the checkpoint
+    format), declared apart from the computation so that the stage can
+    choose its form."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, in_features: int):
+        kernel = self.param(
+            "kernel", nn.linear.default_kernel_init,
+            (3, 3, in_features, self.features), jnp.float32,
+        )
+        bias = self.param(
+            "bias", nn.initializers.zeros_init(), (self.features,), jnp.float32
+        )
+        return kernel, bias
 
 
 class MedCNN(nn.Module):
@@ -43,16 +235,11 @@ class MedCNN(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        for f in self.features:
-            x = nn.Conv(
-                f,
-                (3, 3),
-                padding="VALID",
-                dtype=jnp.bfloat16,
-                param_dtype=jnp.float32,
-            )(x)
-            x = nn.relu(x)
-            x = nn.max_pool(x, (2, 2), strides=(2, 2))
+        widths = (x.shape[-1], *self.features)
+        x = _conv_stages(_conv_bf16, x, [
+            _ConvParams(f, name=f"Conv_{i}")(widths[i])
+            for i, f in enumerate(self.features)
+        ])
         x = x.reshape((x.shape[0], -1))
         for d in self.dense:
             x = nn.Dense(d, dtype=jnp.bfloat16, param_dtype=jnp.float32)(x)
@@ -75,11 +262,10 @@ class MedCNN(nn.Module):
         layer. -> logits (or probs) [C*B, num_classes] float32.
         """
         c = num_clients
-        for i in range(len(self.features)):
-            lyr = stacked_params[f"Conv_{i}"]
-            x = folded_conv(x, lyr["kernel"], lyr["bias"], num_clients=c)
-            x = nn.relu(x)
-            x = nn.max_pool(x, (2, 2), strides=(2, 2))
+        x = _conv_stages(partial(folded_conv, num_clients=c), x, [
+            (stacked_params[f"Conv_{i}"]["kernel"], stacked_params[f"Conv_{i}"]["bias"])
+            for i in range(len(self.features))
+        ])
         b = x.shape[0] // c
         x = x.reshape(c, b, -1)
         for j in range(len(self.dense)):

@@ -43,6 +43,9 @@ def _stacked(model, shape, c, seed=0):
     "model,shape,atol",
     [
         (SmallCNN(num_classes=10), (28, 28, 1), 1e-4),
+        # 100x100: SmallCNN's first stage takes the polyphase form (block 4)
+        # in both lowerings, through the one helper (models.cnn._conv_stages)
+        (SmallCNN(num_classes=10), (100, 100, 1), 2e-3),
         (LogReg(num_classes=10), (28, 28, 1), 1e-6),
         # 20 bf16 layers accumulate reduction-order drift; tolerance, not
         # approximation (every layer is exact math — see models.folded).
@@ -61,6 +64,28 @@ def test_folded_apply_matches_vmap_forward(model, shape, atol):
         c,
     )
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got), atol=atol)
+
+
+def test_polyphase_stage_count_is_recorded():
+    # `model.polyphase_stages`: how many conv stages took the polyphase form
+    # when the model was last traced, in every run's record (obs.metrics).
+    from hefl_tpu.models import create_model
+    from hefl_tpu.obs import metrics as obs_metrics
+
+    def stages():
+        return obs_metrics.snapshot()["model.polyphase_stages"]
+
+    create_model("medcnn", input_shape=(256, 256, 3))
+    assert stages() == 2
+    module, params = create_model("smallcnn", input_shape=(100, 100, 1))
+    assert stages() == 1
+    create_model("resnet20")
+    assert stages() == 0
+    jax.eval_shape(
+        lambda p, x: module.folded_apply(p, x, num_clients=2),
+        stack_params(params, 2), jnp.zeros((4, 100, 100, 1)),
+    )
+    assert stages() == 1
 
 
 def test_folded_apply_matches_vmap_forward_medcnn():

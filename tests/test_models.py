@@ -1,10 +1,16 @@
 """Model zoo contract tests (SURVEY.md §2.3 sizing is the HE contract)."""
 
+import hashlib
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax import lax
 
-from hefl_tpu.models import MedCNN, ResNet20, SmallCNN, count_params, create_model
+from hefl_tpu.models import MedCNN, ResNet20, SmallCNN, cnn, count_params, create_model
+from hefl_tpu.obs import metrics as obs_metrics
 
 
 def test_medcnn_parameter_count_matches_reference():
@@ -80,3 +86,162 @@ def test_models_are_deterministic_pure_functions():
     a = model.apply({"params": params}, x)
     b = model.apply({"params": params}, x)
     assert jnp.array_equal(a, b)
+
+
+# --- the polyphase form of a conv + ReLU + 2x2 max-pool stage (PR 25) ---
+
+
+def _conv_f32(x, kernel, bias):
+    return lax.conv_general_dilated(
+        x, kernel, (1, 1), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision="highest",
+    ) + bias
+
+
+def _plain_stage(x, kernel, bias):
+    """What the stage was before PR 25: nn.Conv + relu + nn.max_pool."""
+    y = nn.Conv(kernel.shape[-1], (3, 3), padding="VALID", precision="highest").apply(
+        {"params": {"kernel": kernel, "bias": bias}}, x
+    )
+    return nn.max_pool(nn.relu(y), (2, 2), strides=(2, 2))
+
+
+def _polyphase(x, kernel, bias, block):
+    """One stage in block x block polyphase form, plain map in and out."""
+    hw = x.shape[1:3]
+    hp, wp = (hw[0] - 2) // 2, (hw[1] - 2) // 2
+    nb, mb = -(-hp // (block // 2)), -(-wp // (block // 2))
+    xs = cnn._to_depth(x, 1, hw, block, nb + 1, mb + 1)
+    out = cnn._polyphase_stage(_conv_f32, xs, kernel, bias, block)
+    return cnn._to_depth(out, block // 2, (hp, wp), 1, hp, wp)
+
+
+def _rel_err(got, want):
+    return float(jnp.max(jnp.abs(got - want)) / (jnp.max(jnp.abs(want)) + 1e-12))
+
+
+@pytest.mark.parametrize("block", [2, 4])
+@pytest.mark.parametrize("ci", [1, 3, 32])
+@pytest.mark.parametrize("width", [254, 125, 60, 26, 11])
+def test_polyphase_stage_matches_conv_relu_maxpool(width, ci, block):
+    # `width` is the conv's output map: even maps pool whole, odd ones
+    # (125 -> 62, 11 -> 5) drop their last row and column, which the
+    # polyphase form never computes. The height is the other parity; block
+    # 4 pads whatever is not a whole block with zeros and crops it again.
+    co = 8
+    ks = jax.random.split(jax.random.key(1000 * width + ci), 4)
+    x = jax.random.normal(ks[0], (2, 9 + width % 2, width + 2, ci))
+    kernel = jax.random.normal(ks[1], (3, 3, ci, co)) / np.sqrt(9 * ci)
+    bias = 0.1 * jax.random.normal(ks[2], (co,))
+    want = _plain_stage(x, kernel, bias)
+    got = _polyphase(x, kernel, bias, block)
+    assert got.shape == want.shape == (2, (7 + width % 2) // 2, width // 2, co)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    ct = jax.random.normal(ks[3], want.shape)
+    g_want = jax.grad(lambda *a: jnp.sum(_plain_stage(*a) * ct), argnums=(0, 1, 2))(
+        x, kernel, bias
+    )
+    g_got = jax.grad(
+        lambda *a: jnp.sum(_polyphase(*a, block) * ct), argnums=(0, 1, 2)
+    )(x, kernel, bias)
+    for name, a, b in zip(("input", "kernel", "bias"), g_got, g_want):
+        assert _rel_err(a, b) < 1e-4, (name, _rel_err(a, b))
+
+
+@pytest.mark.parametrize("block", [2, 4])
+@pytest.mark.parametrize("level", [2.0, -1.0])
+def test_polyphase_pool_gradient_goes_to_the_first_maximum(level, block):
+    # Planted ties: whole-number inputs and weights make every sum exact in
+    # float32, and a constant image makes all four values of every window
+    # equal. reduce_window's backward gives the window's gradient to its
+    # first maximum in window order; an even split between ties (jnp.max's
+    # rule) would differ. At level -1 every window but a few is ReLU's zero,
+    # which passes no gradient on.
+    x = jnp.full((1, 10, 12, 2), level).at[0, 4:6, 5:8, 0].set(3.0)
+    kernel = jnp.ones((3, 3, 2, 4)).at[1, 1, 0, 2].set(2.0)
+    bias = jnp.zeros((4,))
+    ct = jnp.arange(1.0, 1.0 + 4 * 5 * 4).reshape(1, 4, 5, 4)
+    want = jax.grad(lambda x: jnp.sum(_plain_stage(x, kernel, bias) * ct))(x)
+    got = jax.grad(lambda x: jnp.sum(_polyphase(x, kernel, bias, block) * ct))(x)
+    assert float(jnp.max(jnp.abs(want))) > 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize(
+    "hw,widths,taken",
+    [
+        ((256, 200), (3, 32, 32, 32), 2),  # MedCNN's head: block 4, block 2, plain
+        ((255, 203), (3, 32, 32), 2),      # odd maps, the last stage untrimmed
+        ((128, 130), (1, 32, 32), 1),      # block 4 handed on to a plain stage
+        ((202, 110), (3, 8), 1),           # a single stage, block 4, back to plain
+        ((110, 111), (32, 16, 4), 1),      # 32 channels in: block 2
+        ((28, 28), (1, 32, 64), 0),        # SmallCNN: maps too small, untouched
+    ],
+)
+def test_conv_stages_match_the_plain_stack(hw, widths, taken):
+    # The rule reads the form from each stage's shapes; whatever it chooses,
+    # the stack computes the plain stack's function and gradients.
+    ks = jax.random.split(jax.random.key(hw[0]), 2 * len(widths))
+    x = jax.random.normal(ks[0], (1, *hw, widths[0]))
+    layers = [
+        (jax.random.normal(ks[2 * i + 1], (3, 3, ci, co)) / np.sqrt(9 * ci),
+         0.1 * jax.random.normal(ks[2 * i + 2], (co,)))
+        for i, (ci, co) in enumerate(zip(widths[:-1], widths[1:]))
+    ]
+
+    def plain(x, layers):
+        for kernel, bias in layers:
+            x = _plain_stage(x, kernel, bias)
+        return x
+
+    want = plain(x, layers)
+    got = cnn._conv_stages(_conv_f32, x, layers)
+    assert obs_metrics.snapshot()["model.polyphase_stages"] == taken
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    ct = jax.random.normal(ks[-1], want.shape)
+    g_want = jax.grad(lambda *a: jnp.sum(plain(*a) * ct), argnums=(0, 1))(x, layers)
+    g_got = jax.grad(
+        lambda *a: jnp.sum(cnn._conv_stages(_conv_f32, *a) * ct), argnums=(0, 1)
+    )(x, layers)
+    for a, b in zip(jax.tree_util.tree_leaves(g_got), jax.tree_util.tree_leaves(g_want)):
+        assert _rel_err(a, b) < 1e-4
+
+
+def _tree_digest(params):
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize(
+    "name,shape,leaves,count,digest",
+    [
+        ("medcnn", (256, 256, 3), 18, 222_722,
+         "d79b997b1c1dd763383eea71307dae2d26cf388f0249474b4a7ff7b53967038f"),
+        ("smallcnn", (28, 28, 1), 8, None,
+         "8bf11747d31b04a95850006076ae107e40ee57817694f8a2b4284c6787134e5e"),
+    ],
+)
+def test_parameter_tree_is_the_parents(name, shape, leaves, count, digest):
+    # The tree is the HE packing contract and the checkpoint format: names,
+    # shapes and dtypes as flax.linen.Conv / Dense declare them, and `init`
+    # bit-equal to the tree of the commit before PR 25 (digests read there,
+    # key 7).
+    module, params = create_model(name, input_shape=shape, rng=jax.random.key(7))
+    convs = len(module.features)
+    assert sorted(params) == sorted(
+        [f"Conv_{i}" for i in range(convs)]
+        + [f"Dense_{j}" for j in range(len(module.dense) + 1)]
+    )
+    widths = (shape[-1], *module.features)
+    for i in range(convs):
+        assert set(params[f"Conv_{i}"]) == {"kernel", "bias"}
+        assert params[f"Conv_{i}"]["kernel"].shape == (3, 3, widths[i], widths[i + 1])
+        assert params[f"Conv_{i}"]["bias"].shape == (widths[i + 1],)
+    flat = jax.tree_util.tree_leaves(params)
+    assert len(flat) == leaves and all(t.dtype == jnp.float32 for t in flat)
+    assert count is None or count_params(params) == count
+    assert _tree_digest(params) == digest
