@@ -6,15 +6,21 @@ A cell is an entry of `workloads` in BENCHMARK.json: a configuration file
 (`<path>/configs/<name>.json`, named by the entry's `file`) and a traffic
 mix (`<path>/traffic/<mix>.json`), merged field by field into the program's
 `ExperimentConfig`. Per-layer metrics are read by `<path>/layer_metrics/
-<metric>.py`, the plain references live in `<path>/reference/<model>.py`;
-`<path>` is any directory of BENCHMARK.json's `paths`. Adding a cell, a mix,
-a configuration or a per-layer metric adds files and entries and edits none.
+<metric>.py`; what a round's work counts and the numbers that decide `correct`
+come from `<path>/checks/<check>.py`, which the configuration names with its
+key `check` (`image_classifier` without it) and which knows the kind of
+model and round, with the plain references in `<path>/reference/`; `<path>`
+is any directory of BENCHMARK.json's `paths`. Adding a cell, a mix, a
+configuration, a check or a per-layer metric adds files and entries and
+edits none.
 
 The run: set-up (imports, data from the seed, keys, compile or cache load,
 a two-round warm-up call of `run_experiment`), then a measured call whose
 first round is a lead-in and whose remaining whole rounds are the window,
 then the checks that decide `correct`, outside the window. The last line of
-standard output is the result object; earlier lines say what was run.
+standard output is the result object, its last key `checks` each number
+compared beside its limit (also the last lines of standard error); earlier
+lines say what was run.
 """
 
 from __future__ import annotations
@@ -49,7 +55,7 @@ import reduce as red  # noqa: E402  (benchmarks/reduce.py)
 PHASES = ("train+encrypt+aggregate", "decrypt", "evaluate")
 MIN_WINDOW_ROUNDS = 3
 TRACED_ROUNDS = 2
-CHECK_STEPS = 3   # optimizer steps the plain reference follows
+DEFAULT_CHECK = "image_classifier"  # of a configuration without the key `check`
 
 
 def say(**fields) -> None:
@@ -86,9 +92,14 @@ def load_cell(bench_path: str, workload: str) -> dict:
     def here(metric):
         return "workloads" not in metric or workload in metric["workloads"]
 
+    config = _read_json(os.path.join(ROOT, conf["file"]))
     return {
-        "cell": cell, "paths": paths,
-        "config": _read_json(os.path.join(ROOT, conf["file"])),
+        "cell": cell, "paths": paths, "config": config,
+        # a file of the cell found by name: module("reference", "adam")
+        "module": lambda kind, name: _module_at(
+            _find(paths, kind, name + ".py")),
+        "check": _find(paths, "checks",
+                       config.get("check", DEFAULT_CHECK) + ".py"),
         "traffic": _read_json(_find(paths, "traffic", cell["traffic"] + ".json")),
         "end_to_end": [m for m in bench["end_to_end"] if here(m)],
         "per_layer": [m for m in bench["per_layer"] if here(m)],
@@ -138,6 +149,7 @@ def build_config(cell: dict, seed: int, **over):
                                seed=int(seed), **over)
 
 
+@functools.lru_cache(maxsize=None)  # one instance a file, whoever asks
 def _module_at(path: str) -> types.ModuleType:
     name = "_bench_" + hashlib.sha1(path.encode()).hexdigest()[:12]
     spec = importlib.util.spec_from_file_location(name, path)
@@ -199,218 +211,9 @@ def data_digest(arrays) -> str:
 
 
 # --------------------------------------------------------------------------
-# the checks that decide `correct`
+# the checks that decide `correct`: the configuration's check module gives the
+# numbers (`<path>/checks/<name>.py`), the harness judges them
 # --------------------------------------------------------------------------
-
-
-def _norms(tree):
-    import jax
-    import numpy as np
-
-    return [float(np.linalg.norm(np.asarray(leaf, np.float64)))
-            for leaf in jax.tree_util.tree_leaves(tree)]
-
-
-def norm_gap(got, want) -> float:
-    """Worst leaf: |norm(got) - norm(want)| against the reference's norm of
-    that leaf or of the median leaf, whichever is larger."""
-    ref = _norms(want)
-    floor = sorted(ref)[len(ref) // 2]
-    return max(abs(g - r) / max(r, floor) for g, r in zip(_norms(got), ref))
-
-
-def whole_norm_gap(got, want) -> float:
-    """|norm(got) - norm(want)| / norm(want) over all leaves as one vector."""
-    whole = lambda t: math.sqrt(sum(n * n for n in _norms(t)))  # noqa: E731
-    return abs(whole(got) - whole(want)) / whole(want)
-
-
-@functools.lru_cache(maxsize=None)
-def _grad_fns(module, ref, quant):
-    """(reference, system) jitted value-and-grad of the loss with the logits,
-    over (params, batch, onehot): compiled once for a cell, whatever the
-    seed, so the persistent cache serves every later run."""
-    import jax
-
-    from hefl_tpu.fl.loss import loss_fn
-
-    def system(p, x, onehot):  # at the program's own precision
-        if quant is not None:
-            return ref.loss(p, x, onehot, quant)
-        return (loss_fn(module, p, x, onehot)[0],
-                module.apply({"params": p}, x))
-
-    def reference(p, x, onehot):
-        with jax.default_matmul_precision("highest"):
-            return ref.loss(p, x, onehot)
-
-    return (jax.jit(jax.value_and_grad(reference, has_aux=True)),
-            jax.jit(jax.value_and_grad(system, has_aux=True)))
-
-
-def model_numbers(module, ref, x, onehot, seed: int, quant=None) -> dict:
-    """The system's own loss and gradient (`fl.loss.loss_fn`, what the SGD
-    step differentiates) against the plain float32 reference, on one timed
-    batch of the seed's images and the reference's seeded weights. The
-    logits' widest error is given in units of the widest error that the
-    reference makes when it is computed in float8, on the same weights and
-    batch: how far a precision moves the logits swings sixfold with the
-    seed, the ratio of two precisions far less. The loss is held to the
-    cross-entropy of the system's own logits, the gradient to the
-    reference's, leaf by leaf. With `quant` the reference computed in that
-    precision stands in the system's place: the control."""
-    import numpy as np
-
-    params = ref.init(seed, x.shape[1:], onehot.shape[-1])
-    ref_fn, sys_fn = _grad_fns(module, ref, quant)
-    (_, z_ref), g_ref = ref_fn(params, x, onehot)
-    (l_sys, z_sys), g_sys = sys_fn(params, x, onehot)
-    (_, z_fp8), _ = _grad_fns(module, ref, fp8_quant)[1](params, x, onehot)
-    z_ref = np.asarray(z_ref, np.float64)
-    err = lambda z: float(np.max(np.abs(  # noqa: E731
-        np.asarray(z, np.float64) - z_ref)))
-    # The loss arithmetic apart from the forward's precision: the system's
-    # loss against the cross-entropy of its own logits in float64.
-    z = np.asarray(z_sys, np.float64)
-    z = z - z.max(-1, keepdims=True)
-    ce = float(np.mean(np.log(np.exp(z).sum(-1)) - (z * onehot).sum(-1)))
-    return {
-        "loss_gap": abs(float(l_sys) - ce) / ce,
-        "logit_err_vs_fp8": err(z_sys) / err(z_fp8),
-        "grad_norm_gap": norm_gap(g_sys, g_ref),
-        "logit_err_max": err(z_sys),
-    }
-
-
-def fp8_quant(a):
-    """The control's precision: float8 (e4m3) values forward, the identity
-    backward, where the configuration states bfloat16."""
-    import jax
-    import jax.numpy as jnp
-
-    return a + jax.lax.stop_gradient(
-        a.astype(jnp.float8_e4m3fn).astype(jnp.float32) - a)
-
-
-@functools.lru_cache(maxsize=None)
-def _ref_loss(ref):
-    import jax
-
-    def loss(p, x, onehot):
-        with jax.default_matmul_precision("highest"):
-            return ref.loss(p, x, onehot)[0]
-
-    return jax.jit(loss)
-
-
-def train_numbers(cfg, module, ref, adam, x, y, steps: int = CHECK_STEPS) -> dict:
-    """One round through the timed entry points (`secure_fedavg_round`, then
-    `decrypt_average`) with the cell's clients, batch, client lowering and HE
-    parameters, from the reference's seeded weights. Its local scan is cut to
-    `steps` optimizer steps of one epoch on the head of each client's shard,
-    and its random warp is off: the plain reference cannot follow the
-    program's augmentation. Compared: the in-program plain mean against a
-    plain float32 Adam run over the same batches (the norm of the
-    parameters' change, as one vector and by the worst leaf), each client's
-    validation loss at its trained weights against the reference's, and the
-    decrypted average against the plain mean."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from hefl_tpu.ckks.keys import keygen
-    from hefl_tpu.ckks.packing import PackSpec
-    from hefl_tpu.data import iid_contiguous, stack_federated
-    from hefl_tpu.fl import decrypt_average, secure_fedavg_round
-    from hefl_tpu.fl.client import epoch_index_streams, train_batch_geometry
-    from hefl_tpu.fl.fedavg import pad_federated
-    from hefl_tpu.parallel import client_mesh_size, client_sharding, make_mesh
-
-    if (cfg.partition != "iid" or cfg.mesh_ct > 1 or cfg.stream is not None
-            or (cfg.packing is not None and cfg.packing.enabled)):
-        raise NotImplementedError(
-            "the training check follows a synchronous, unpacked, IID round on "
-            "a 1-D mesh; the benchmark PR that adds another kind of cell "
-            "proves its check on the chip with it")
-    n_cl, classes = cfg.num_clients, cfg.train.num_classes
-    tc = dataclasses.replace(cfg.train, epochs=1, augment=False)
-    m = next(k for k in range(steps * tc.batch_size, len(y) // n_cl + 1)
-             if train_batch_geometry(tc, k)[2] == steps)
-    n_tr, grp, _ = train_batch_geometry(tc, m)
-    xs, ys = stack_federated(x, y, iid_contiguous(len(y), n_cl))
-    xs, ys = np.asarray(xs[:, :m]), np.asarray(ys[:, :m])
-    shape = tuple(int(d) for d in xs.shape[2:])
-    params0 = ref.init(cfg.seed, shape, classes)
-
-    # ---- the system: one round of the timed entry, then the owner's decrypt
-    mesh = make_mesh(n_cl)
-    xs_p, ys_p, num_real = pad_federated(xs, ys, client_mesh_size(mesh))
-    place = client_sharding(mesh)
-    ctx = cfg.he.build()
-    _, k_he = jax.random.split(jax.random.key(cfg.seed))
-    sk, pk = keygen(ctx, k_he)
-    key = jax.random.fold_in(jax.random.key(cfg.seed), 1000)
-    gp = jax.tree_util.tree_map(jnp.asarray, params0)
-    outs = secure_fedavg_round(
-        module, tc, mesh, ctx, pk, gp, jax.device_put(xs_p, place),
-        jax.device_put(ys_p, place), key, with_plain_reference=True,
-        num_real_clients=num_real)
-    ct, mets, overflow, plain = outs[0], outs[1], outs[2], outs[-1]
-    avg = decrypt_average(ctx, sk, ct, n_cl, PackSpec.for_params(gp, ctx.n),
-                          meta=outs[3] if len(outs) == 5 else None,
-                          base_params=gp)
-    val_sys = np.asarray(mets, np.float64)[:n_cl, 0, 0]
-
-    # ---- the reference: the same batches, client after client. The batches
-    # are the program's own shuffle of the round key (secure_fedavg_round
-    # splits it into a training and an encryption key, then per client).
-    train_keys = jax.random.split(jax.random.split(key)[0], n_cl)
-    perms = np.asarray(epoch_index_streams(tc, train_keys, m)[0])
-    eye = np.eye(classes, dtype=np.float32)
-    scaled = lambda a: np.asarray(a, np.float32) / 255.0  # noqa: E731
-    ref_vg = _grad_fns(module, ref, None)[0]
-    total, short, val_gaps, untrained, first_losses = None, None, [], [], []
-    as_f32 = lambda t: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: np.asarray(a, np.float32), t)
-    add = lambda acc, t: t if acc is None else jax.tree_util.tree_map(  # noqa: E731
-        np.add, acc, t)
-    for c in range(n_cl):
-        if len(set(perms[c].ravel().tolist())) != steps * grp:
-            raise RuntimeError("the check's batches repeat a row")
-        x_tr, y_tr = xs[c, m - n_tr:], ys[c, m - n_tr:]
-        trail, losses = adam.steps(
-            ref_vg, params0, [(scaled(x_tr[i]), eye[y_tr[i]]) for i in perms[c]],
-            tc.lr, tc.lr_decay, tc.warmup_steps)
-        total, short = add(total, trail[-1]), add(short, trail[-2])
-        first_losses.append(losses[0])
-        val = scaled(xs[c, :m - n_tr]), eye[ys[c, :m - n_tr]]
-        want = float(_ref_loss(ref)(as_f32(trail[-1]), *val))
-        val_gaps.append(abs(val_sys[c] - want) / want)
-        untrained.append(abs(float(_ref_loss(ref)(as_f32(params0), *val))
-                             - want) / want)
-    mean = lambda t: jax.tree_util.tree_map(lambda a: a / n_cl, t)  # noqa: E731
-    moved = lambda t: jax.tree_util.tree_map(  # noqa: E731
-        lambda a, b: np.asarray(a, np.float64) - b, t, params0)
-    leaves = lambda t: [np.asarray(v, np.float64)  # noqa: E731
-                        for v in jax.tree_util.tree_leaves(t)]
-    err = max(float(np.max(np.abs(a - b)))
-              for a, b in zip(leaves(avg), leaves(plain)))
-    say(check_round={"clients": n_cl, "images_a_client": m, "steps": steps,
-                     "batch": grp, "validation_rows": m - n_tr},
-        reference_first_step_loss=first_losses)
-    d_sys, d_ref = moved(plain), moved(mean(total))
-    return {
-        "step_norm_gap": whole_norm_gap(d_sys, d_ref),
-        "leaf_step_gap": norm_gap(d_sys, d_ref),
-        "val_loss_gap": float(max(val_gaps)),
-        "he_avg_err": err if math.isfinite(err) else float("inf"),
-        "check_round_overflow": int(np.sum(np.asarray(overflow))),
-        # for the record, what two faults read on the reference's side: an
-        # optimizer step that returns its state unchanged (`step_norm_gap`),
-        # a validation loss taken at the round's input weights
-        "skipped_step_reads": whole_norm_gap(moved(mean(short)), d_ref),
-        "untrained_val_reads": float(max(untrained)),
-    }
 
 
 def judge(numbers: dict, limits: dict) -> list[dict]:
@@ -482,6 +285,15 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
              trace: bool, require_tpu: bool = True, workdir: str | None = None,
              keep_trace: str | None = None) -> dict:
     """-> the result object (the caller prints it as the last line)."""
+    # This frame keeps the 97 slots it had when the checks' locals lived in it
+    # (PR 26 took 15 out). JAX traces the round program hundreds of frames
+    # above, CPython 3.12 is 40-100x slower on a call that straddles a 16 KB
+    # chunk of its frame stack, and the bytes below decide which hot calls do:
+    # at 82 slots resnet20.sync_e1's warm `setup_s` read 74-75 s against the
+    # parent's 79-80 (PERF.md, PR 24 and PR 26). A change of this frame's
+    # size is not wrong, but it re-rolls that and owes both cells a chip run.
+    _0 = _1 = _2 = _3 = _4 = _5 = _6 = _7 = _8 = _9 = _10 = _11 = _12 = _13 = \
+        _14 = None
     cell = load_cell(bench_path, workload)
     for key, val in cell["config"].get("env", {}).items():
         os.environ[key] = str(val)  # backend pins, before hefl_tpu is imported
@@ -496,8 +308,6 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
             f"benchmarks/run.py: {workload} needs {chips} TPU chip(s); found "
             f"{jax.device_count()} x {jax.default_backend()}")
     from hefl_tpu import experiment
-    from hefl_tpu.fl.client import train_batch_geometry
-    from hefl_tpu.models import create_model
     from hefl_tpu.obs import metrics as obs_metrics
     from hefl_tpu.utils.device import setup_compile_cache
 
@@ -524,9 +334,9 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
         make = experiment.make_dataset
         make_once = experiment.make_dataset = functools.lru_cache(None)(make)
         cfg = build_config(cell, seed, events_path=events_path)
-        (x, y), (xt, yt), _ = make_once(cfg.dataset, seed=cfg.seed,
-                                        n_train=cfg.n_train, n_test=cfg.n_test)
-        digest = data_digest((x, y, xt, yt))
+        data = make_once(cfg.dataset, seed=cfg.seed, n_train=cfg.n_train,
+                         n_test=cfg.n_test)
+        digest = data_digest((*data[0], *data[1]))
 
         warm = experiment.run_experiment(
             dataclasses.replace(cfg, rounds=2), verbose=False)
@@ -550,19 +360,15 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
             jax.extend.backend.get_backend().live_executables())
         history = out["history"]
 
-        _, grp, steps = train_batch_geometry(
-            cfg.train, len(y) // cfg.num_clients)
-        samples_round = cfg.num_clients * cfg.train.epochs * steps * grp
-        ref = _module_at(_find(cell["paths"], "reference",
-                               cell["config"]["reference"] + ".py"))
-        shape = tuple(int(d) for d in x.shape[1:])
+        check = _module_at(cell["check"])
+        work = check.round_work(cell, cfg, data)
+        samples_round = work["samples_per_round"]
         record = {
             "rounds": win["rounds"], "window_s": win["window_s"],
             "samples_per_round": samples_round, "chips": chips,
             "memory_peak_bytes": memory["peak_bytes"],
             "device_kind": devices[0].device_kind,
-            "train_flops_per_round": 3 * samples_round * ref.forward_flops(
-                shape, cfg.train.num_classes),
+            "train_flops_per_round": work["train_flops_per_round"],
         }
         walls = [r["wall_s"] for r in win["rounds"]]
         values = {  # all the time of the window over all of its rounds
@@ -588,9 +394,7 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
             breakdown = tr["breakdown"]
             values = {}
             for m in cell["per_layer"]:
-                reader = _module_at(_find(cell["paths"], "layer_metrics",
-                                          m["name"] + ".py"))
-                got = reader.read(record, tr)
+                got = cell["module"]("layer_metrics", m["name"]).read(record, tr)
                 if got is not None:
                     values[m["name"]] = got
         units = {m["name"]: m["unit"]
@@ -599,20 +403,10 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
                    for k, v in values.items() if k in units}
 
         # ---- the checks, outside the window --------------------------------
-        module, _ = create_model(cfg.model, num_classes=cfg.train.num_classes,
-                                 input_shape=shape)
-        bs = cfg.train.batch_size
-        xb = np.asarray(x[:bs], np.float32) / 255.0
-        onehot = np.eye(cfg.train.num_classes, dtype=np.float32)[y[:bs]]
-        numbers = model_numbers(module, ref, xb, onehot, cfg.seed)
-        say(logit_err_max=numbers.pop("logit_err_max"))  # for the record
-        adam = _module_at(_find(cell["paths"], "reference", "adam.py"))
-        he = train_numbers(cfg, module, ref, adam, x, y)
+        numbers = check.numbers(cell, cfg, data)
         overflow = sum(int(np.sum(rec.get("encode_overflow", 0)))
-                       for rec in history) + he.pop("check_round_overflow")
-        say(skipped_step_would_read=he.pop("skipped_step_reads"),
-            untrained_val_would_read=he.pop("untrained_val_reads"))
-        numbers.update(he, encode_overflow=overflow,
+                       for rec in history) + numbers.pop("encode_overflow", 0)
+        numbers.update(encode_overflow=overflow,
                        executables_in_window=win["executables_in_window"],
                        failed_rounds=win["failed"])
         limits = dict(cell["config"]["limits"],
@@ -658,6 +452,9 @@ def run_cell(bench_path: str, workload: str, seed: int, seconds: float,
         }
         if breakdown is not None:
             result["breakdown"] = breakdown
+        # last in the line: each number compared, beside its limit
+        result["checks"] = {r["check"]: {k: v for k, v in r.items() if k != "check"}
+                            for r in rows}
         return result
     finally:
         gc.callbacks.remove(gc_watch)
@@ -679,6 +476,8 @@ def main(argv=None) -> int:
     result = run_cell(args.benchmark, args.workload, args.seed, args.seconds,
                       bool(args.trace), keep_trace=args.keep_trace)
     print(json.dumps(result), flush=True)
+    for name, row in result["checks"].items():  # and as standard error's last lines
+        print(json.dumps({"check": name, **row}), file=sys.stderr, flush=True)
     return 0
 
 

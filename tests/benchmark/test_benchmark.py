@@ -40,13 +40,21 @@ def run():
 
 
 @pytest.fixture(scope="module")
+def check(run):
+    """`benchmarks/checks/image_classifier.py`, the instance the harness runs:
+    the check of every configuration that names none (PR 26)."""
+    return run._module_at(run.load_cell(TINY, "tiny.sync_tiny")["check"])
+
+
+@pytest.fixture(scope="module")
 def red():
     return _load("_hefl_bench_reduce", os.path.join(BENCH, "reduce.py"))
 
 
-def _run_tiny(run, monkeypatch, tmp_path, seed=3000000001, **kw):
+def _run_tiny(run, monkeypatch, tmp_path, seed=3000000001,
+              workload="tiny.sync_tiny", **kw):
     monkeypatch.setenv("HEFL_EVENTS", "1")
-    return run.run_cell(TINY, "tiny.sync_tiny", seed, 1.0, False,
+    return run.run_cell(TINY, workload, seed, 1.0, False,
                         require_tpu=False, workdir=str(tmp_path), **kw)
 
 
@@ -55,7 +63,8 @@ def test_tiny_cell_end_to_end(run, monkeypatch, tmp_path, capsys):
     files under tests/benchmark/tiny and entries of its BENCHMARK.json; the
     harness runs it with no edit and prints the contract's result."""
     result = _run_tiny(run, monkeypatch, tmp_path)
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device",
+                            "checks"]          # the numbers compared come last
     assert set(result["device"]) == {"platform", "kind", "count",
                                      "memory_peak_bytes"}
     assert result["correct"] is True and result["failed"] == 0
@@ -65,6 +74,9 @@ def test_tiny_cell_end_to_end(run, monkeypatch, tmp_path, capsys):
         assert set(m) == {"value", "unit"} and m["value"] > 0
     lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
     checks = {ln["check"] for ln in lines if "check" in ln}
+    assert set(result["checks"]) == checks and all(
+        row["ok"] and ("max" in row or "min" in row) and "value" in row
+        for row in result["checks"].values())
     assert {"he_avg_err", "step_norm_gap", "leaf_step_gap", "val_loss_gap",
             "logit_err_vs_fp8", "loss_gap", "grad_norm_gap", "encode_overflow",
             "executables_in_window"} <= checks
@@ -92,7 +104,102 @@ def test_broken_timed_path_is_not_correct(run, monkeypatch, tmp_path):
     assert result["correct"] is False and result["attempted"] >= 3
 
 
-def test_a_skipped_step_and_half_a_batch_are_not_correct(run, monkeypatch):
+@pytest.mark.parametrize("seed,correct", [
+    (3000000049, True),     # the planted number, seed % 100, under its limit of 50
+    (3000000051, False),    # and over it
+])
+def test_a_configuration_names_its_check(run, monkeypatch, tmp_path, capsys,
+                                         seed, correct):
+    """The seam (PR 26): `tiny-planted.json` names `checks/planted.py`, a file
+    of the tests' own, and the harness runs the cell end to end with no edit.
+    The module's count of a round's work is what `samples_per_s` is taken
+    from, its one number is judged against the configuration's limit, and
+    none of `image_classifier`'s numbers is read."""
+    result = _run_tiny(run, monkeypatch, tmp_path, seed=seed,
+                       workload="planted.sync_tiny")
+    assert result["correct"] is correct and result["failed"] == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    rows = {ln["check"]: ln for ln in lines if "check" in ln}
+    assert set(rows) == {"planted", "encode_overflow", "executables_in_window",
+                         "failed_rounds"}
+    assert rows["planted"]["value"] == seed % 100 and rows["planted"]["max"] == 50.0
+    assert rows["planted"]["ok"] is correct
+    info = lines[-1]
+    assert info["samples_per_round"] == 1234
+    rate = result["metrics"]["samples_per_s"]["value"]
+    assert rate == pytest.approx(
+        1234 * info["rounds_in_window"] / info["window_s"], rel=1e-9)
+
+
+def test_a_number_without_a_limit_is_a_fault_of_the_cells_files(
+        run, monkeypatch, tmp_path):
+    with pytest.raises(KeyError, match="unlimited"):
+        _run_tiny(run, monkeypatch, tmp_path, seed=3000000099,
+                  workload="planted.sync_tiny")
+
+
+def test_the_default_check_and_an_unknown_one(run, tmp_path):
+    """A configuration without `check` runs `image_classifier`: that default
+    is what carries the three configurations that were there before the key
+    (their files have none). A name with no file is refused when the cell is
+    loaded, before any set-up."""
+    for bench, workload in [(TINY, "tiny.sync_tiny"),
+                            (os.path.join(ROOT, "BENCHMARK.json"), "medcnn.sync_e10"),
+                            (os.path.join(ROOT, "BENCHMARK.json"), "resnet20.sync_e1")]:
+        cell = run.load_cell(bench, workload)
+        assert "check" not in cell["config"]
+        assert cell["check"] == os.path.join(BENCH, "checks", "image_classifier.py")
+    assert run.load_cell(TINY, "planted.sync_tiny")["check"] == os.path.join(
+        HERE, "tiny", "checks", "planted.py")
+    with open(TINY) as f:
+        bench = json.load(f)
+    with open(os.path.join(HERE, "tiny", "configs", "tiny-planted.json")) as f:
+        conf = dict(json.load(f), check="no_such_check")
+    conf_path = tmp_path / "conf.json"
+    conf_path.write_text(json.dumps(conf))
+    bench["configs"][1]["file"] = str(conf_path)
+    bench_path = tmp_path / "BENCHMARK.json"
+    bench_path.write_text(json.dumps(bench))
+    with pytest.raises(FileNotFoundError, match=r"checks/no_such_check\.py"):
+        run.load_cell(str(bench_path), "planted.sync_tiny")
+
+
+def test_the_harness_knows_no_model():
+    """`run.py` keeps what every cell shares; what knows the kind of model
+    and round is the check module. Under `benchmarks/` only
+    `checks/image_classifier.py` builds a model, a one-hot or a `PackSpec`."""
+    with open(os.path.join(BENCH, "run.py")) as f:
+        src = f.read()
+    for gone in ("hefl_tpu.models", "hefl_tpu.fl.loss", "hefl_tpu.ckks.packing",
+                 "hefl_tpu.data", "iid_contiguous", "stack_federated",
+                 "num_classes", "255", "create_model", "PackSpec", "onehot"):
+        assert gone not in src, gone
+    builds = re.compile(r"create_model\(|np\.eye\(|PackSpec\b")
+    for top, _, files in os.walk(BENCH):
+        for name in files:
+            path = os.path.join(top, name)
+            if name.endswith(".py") and path != os.path.join(
+                    BENCH, "checks", "image_classifier.py"):
+                with open(path) as f:
+                    assert not builds.search(f.read()), path
+
+
+def test_run_cells_frame_keeps_its_size(run):
+    """`run_cell` is a frame under JAX's trace of the round program, like the
+    three that `tests/test_experiment.py` pins: its size decides which hot
+    calls straddle a 16 KB chunk of CPython 3.12's frame stack. PR 26 took 15
+    locals out of it, read resnet20.sync_e1's warm `setup_s` 6% off the
+    parent's on the chip, and gave the frame its 97 slots back. A change that
+    moves this number owes both cells a chip run of `setup_s`."""
+    if sys.version_info[:2] != (3, 12):
+        pytest.skip("frame sizes are the interpreter's: pinned for 3.12")
+    code = run.run_cell.__code__
+    assert (code.co_nlocals + code.co_stacksize + len(code.co_cellvars)
+            + len(code.co_freevars)) == 97
+
+
+def test_a_skipped_step_and_half_a_batch_are_not_correct(run, check,
+                                                         monkeypatch):
     """The timed path broken underneath the checks: an optimizer step that
     returns its weights unchanged leaves the limit of `step_norm_gap` (the
     check round against the plain Adam reference), and a loss over half the
@@ -113,16 +220,16 @@ def test_a_skipped_step_and_half_a_batch_are_not_correct(run, monkeypatch):
                                 n_test=2)
     shape = tuple(int(d) for d in x.shape[1:])
     module, _ = create_model(cfg.model, num_classes=10, input_shape=shape)
-    ref = run._module_at(run._find(cell["paths"], "reference", "smallcnn.py"))
-    adam = run._module_at(run._find(cell["paths"], "reference", "adam.py"))
+    ref, adam = cell["module"]("reference", "smallcnn"), cell["module"](
+        "reference", "adam")
     xb = np.asarray(x[:8], np.float32) / 255.0
     onehot = np.eye(10, dtype=np.float32)[y[:8]]
 
     def numbers():
         secure._build_secure_round_fn.cache_clear()
-        run._grad_fns.cache_clear()
-        return {**run.model_numbers(module, ref, xb, onehot, cfg.seed),
-                **run.train_numbers(cfg, module, ref, adam, x, y)}
+        check._grad_fns.cache_clear()
+        return {**check.model_numbers(module, ref, xb, onehot, cfg.seed),
+                **check.train_numbers(cfg, module, ref, adam, x, y)}
 
     sound = numbers()
     for name in ("step_norm_gap", "leaf_step_gap", "val_loss_gap", "loss_gap",
@@ -179,7 +286,7 @@ def test_reference_adam_on_a_constant_gradient(grad, decay, warmup, want):
     assert trail[-1]["w"] - 1.0 == pytest.approx(np.full(3, want), rel=1e-6, abs=1e-12)
 
 
-def test_rate_and_flop_arithmetic(red, run):
+def test_rate_and_flop_arithmetic(red, check):
     # 8 rounds of 14,080 samples in 44 s on one chip, and on four
     assert red.samples_per_s(8, 14080, 44.0, 1) == pytest.approx(2560.0)
     assert red.samples_per_s(8, 14080, 44.0, 4) == pytest.approx(640.0)
@@ -214,7 +321,7 @@ def test_rate_and_flop_arithmetic(red, run):
     # the norm gap is the worst leaf, floored by the median leaf's norm
     got = {"a": np.array([3.0, 4.0]), "b": np.array([1e-9]), "c": np.array([2.0])}
     ref = {"a": np.array([6.0, 8.0]), "b": np.array([0.0]), "c": np.array([2.0])}
-    assert run.norm_gap(got, ref) == pytest.approx(0.5)
+    assert check.norm_gap(got, ref) == pytest.approx(0.5)
 
 
 def test_trace_reduction_on_a_recorded_chip_trace(red):
@@ -276,10 +383,19 @@ def test_benchmark_json_keeps_the_contract(run, path):
         cell = run.load_cell(path, w["name"])     # every file is found by name
         assert {m["name"] for m in cell["end_to_end"]} >= {"setup_s", "round_s"}
         for m in cell["per_layer"]:
-            reader = run._module_at(run._find(cell["paths"], "layer_metrics",
-                                              m["name"] + ".py"))
-            assert callable(reader.read)
+            assert callable(cell["module"]("layer_metrics", m["name"]).read)
+        # the optional key: the configuration's file names its check, a
+        # module of `checks/`; without the key it is `image_classifier`
+        named = cell["config"].get("check", run.DEFAULT_CHECK)
+        assert _NAME.match(named) and cell["check"].endswith(
+            os.path.join("checks", named + ".py"))
+        module = run._module_at(cell["check"])
+        assert callable(module.round_work) and callable(module.numbers)
         limits = cell["config"]["limits"]
+        if named != run.DEFAULT_CHECK:
+            assert limits and all(v is None or set(v) <= {"max", "min"}
+                                  for v in limits.values())
+            continue
         assert set(limits) == {
             "he_avg_err", "step_norm_gap", "leaf_step_gap", "val_loss_gap",
             "logit_err_vs_fp8", "loss_gap", "grad_norm_gap"}
@@ -322,7 +438,7 @@ def test_a_mix_may_set_any_experiment_field(run):
     ("medcnn", (190, 190, 3), 2, (0.6, 1e-5, 0.3)),
     ("resnet20", (32, 32, 3), 10, (0.6, 1e-5, 0.6)),
 ])
-def test_reference_against_the_system_and_control_fails(run, model, shape,
+def test_reference_against_the_system_and_control_fails(check, model, shape,
                                                         classes, limits):
     """At a small size on the CPU: the system's bfloat16 loss, logits and
     gradient stay near the plain float32 reference (bfloat16 keeps 8 bits of
@@ -344,9 +460,9 @@ def test_reference_against_the_system_and_control_fails(run, model, shape,
         jax.tree_util.tree_structure(jax.tree_util.tree_map(np.asarray, dict(proto))))
     xb = np.asarray(x, np.float32) / 255.0
     onehot = np.eye(classes, dtype=np.float32)[y]
-    sound = run.model_numbers(module, ref, xb, onehot, seed=5)
-    control = run.model_numbers(module, ref, xb, onehot, seed=5,
-                                quant=run.fp8_quant)
+    sound = check.model_numbers(module, ref, xb, onehot, seed=5)
+    control = check.model_numbers(module, ref, xb, onehot, seed=5,
+                                  quant=check.fp8_quant)
     logit_max, loss_max, grad_max = limits
     assert sound["logit_err_vs_fp8"] < logit_max / 2
     assert sound["loss_gap"] < loss_max and sound["grad_norm_gap"] < grad_max
