@@ -77,6 +77,8 @@ def create_model(
         rng = jax.random.key(0)
     # MedCNN's stages set it as they are traced; no other model has any.
     obs_metrics.gauge("model.polyphase_stages").set(0)
+    # a token model's forward sets it as it is traced (models/lm.py)
+    obs_metrics.gauge("model.fused_attention_layers").set(0)
     dummy = jnp.zeros(
         (1, *(input_shape if input_shape is not None else default_shape)), jnp.float32
     )

@@ -27,8 +27,12 @@ held experts' terms are computed by a grouped matrix product
 imbalance: no capacity, nothing dropped) and summed with the shared expert;
 what absent experts would add is left out. Compute is bfloat16 with float32
 accumulation; the residual stream, the norms, the router and the softmax are
-float32. Attention is causal, blocked over queries with each block seeing
-only the keys up to its own end, so memory is linear in the sequence.
+float32. Attention is causal and one fused Pallas kernel a layer
+(`causal_attention`: splash attention, forward and gradient): scores and
+probabilities live a block at a time in the chip's fast memory and never in
+HBM, the blocks above the diagonal are skipped, and memory is linear in the
+sequence. Every block is made again for the gradient (a `jax.checkpoint` a
+layer) but for attention's output and log-sum-exp, which are kept.
 
 A module here is a frozen dataclass, hashable like a flax module, with the
 same `apply({"params": ...}, x)`; `bind(base)` gives the module that the
@@ -75,7 +79,7 @@ class LMArch:
     held_experts: int = 128           # of n_experts
     mtp_weight: float = 0.1           # assumed: V3's final value
     init_std: float = 0.02            # assumed: V3's initializer_range
-    q_block: int = 512                # attention's query block
+    q_block: int = 1024               # attention's query and key block
     loss_chunk: int = 2048            # tokens a slice of the head's logits
 
 
@@ -88,7 +92,7 @@ PRESETS = {
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         intermediate=128, moe_intermediate=32, n_experts=8,
         experts_per_tok=2, expert_layers=2, held_start=0, held_experts=4,
-        q_block=8, loss_chunk=16),
+        q_block=128, loss_chunk=16),
 }
 
 
@@ -126,32 +130,70 @@ def rope(x, theta: float):
         *x.shape[:-2], d)
 
 
+def _interpret() -> bool:
+    """Pallas runs interpreted wherever the backend is no TPU (the tests)."""
+    return jax.default_backend() != "tpu"
+
+
+ATTN_SAVED = "mla_saved"   # what a layer's checkpoint keeps of attention
+
+
+@functools.lru_cache(maxsize=None)
+def _attention_kernel(seq: int, heads: int, block: int, interpret: bool):
+    """The fused causal attention over `seq` positions (a multiple of
+    `block`) of `heads` heads: splash attention of
+    `jax.experimental.pallas.ops.tpu`, q and kv blocks of `block` (scores
+    made 512 keys at a time), its gradient one kernel more (`dkv`, which
+    also forms `dq`, a key block's part at a time). Its output and
+    log-sum-exp carry the name `ATTN_SAVED`, so a `jax.checkpoint` whose
+    policy saves that name does not run the forward kernel again. The mask
+    is processed in NumPy here on the host, once a shape: every layer and
+    every trace reuses the object, which holds NumPy arrays only (constants
+    of whatever program calls it)."""
+    import importlib
+
+    import numpy as np
+
+    splash = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.splash_attention")
+    step = min(block, 512)
+    sizes = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=step,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=step,
+        use_fused_bwd_kernel=True)
+    mask = splash.MultiHeadMask([splash.CausalMask((seq, seq))] * heads)
+    with jax.ensure_compile_time_eval():
+        kernel = splash.make_splash_mha(
+            mask, block_sizes=sizes, head_shards=1, q_seq_shards=1,
+            residual_checkpoint_name=ATTN_SAVED, interpret=interpret)
+    return jax.tree_util.tree_map(np.asarray, kernel)
+
+
 def causal_attention(q, k, v, q_block: int):
-    """softmax(q k^T / sqrt(d)) v, causal, float32 softmax. q, k: [B, S, H,
-    dq]; v: [B, S, H, dv]. Blocked over queries: block i sees keys
-    [0, (i+1) * q_block), so no S x S array exists and the blocks above the
-    diagonal are never computed. Each block's scores are made again for its
-    gradient (a checkpoint a block), so only q, k and v outlive a block."""
-    b, s, h, dq = q.shape
-    blk = min(q_block, s)
-    scale = 1.0 / math.sqrt(dq)
-    q, k, v = q.astype(BF16), k.astype(BF16), v.astype(BF16)
-
-    @functools.partial(jax.checkpoint, static_argnums=(3,))
-    def one(qb, kb, vb, lo):
-        sc = jnp.einsum("bqhd,bkhd->bhqk", qb, kb,
-                        preferred_element_type=F32) * scale
-        keep = (lo + jnp.arange(qb.shape[1])[:, None]
-                >= jnp.arange(kb.shape[1])[None, :])
-        p = jax.nn.softmax(jnp.where(keep[None, None], sc, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(BF16), vb,
-                          preferred_element_type=F32)
-
-    outs = []
-    for lo in range(0, s, blk):
-        hi = min(lo + blk, s)
-        outs.append(one(q[:, lo:hi], k[:, :hi], v[:, :hi], lo))
-    return jnp.concatenate(outs, axis=1)
+    """softmax(q k^T / sqrt(d)) v, causal. q, k: [B, S, H, dq]; v: [B, S, H,
+    dv] -> f32[B, S, H, dv]. One fused Pallas kernel (`_attention_kernel`)
+    and one more for its gradient: bfloat16 operands, float32 scores, a
+    float32 running maximum, sum and accumulator over the keys, the
+    probabilities narrowed to bfloat16 for the product with v, the division
+    at the end, the output narrowed to bfloat16 (as the product that takes
+    it would). No score or probability block reaches HBM, forward or
+    backward; the blocks above the diagonal are skipped; every key up to the
+    query's own position counts. q is scaled in float32 before it is
+    narrowed (the kernel does not scale). The kernel wants blocks that are
+    multiples of 128: `q_block` is rounded up to one, and a sequence that is
+    no multiple of the block is padded at its end. Padded keys lie behind
+    every real query, so the causal mask removes them; padded query rows are
+    cut off."""
+    _, s, h, dq = q.shape
+    up = lambda n, m: -(-n // m) * m  # noqa: E731
+    blk = min(up(q_block, 128), up(s, 128))
+    pad = up(s, blk) - s
+    heads_first = lambda x: jnp.pad(  # noqa: E731
+        x.astype(BF16), ((0, 0), (0, pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    q = q.astype(F32) * (1.0 / math.sqrt(dq))
+    kernel = _attention_kernel(s + pad, h, blk, _interpret())
+    o = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
+    return o.transpose(0, 2, 1, 3)[:, :s].astype(F32)
 
 
 def latent_attention(arch: LMArch, w, g, x):
@@ -211,11 +253,6 @@ def _gmm_tiles(k: int, n: int) -> tuple[int, int]:
     fit = lambda d: d if d <= 1024 else next(  # noqa: E731
         t for t in (1024, 768, 512, 384, 256, 128) if d % t == 0)
     return fit(k), fit(n)
-
-
-def _interpret() -> bool:
-    """Pallas runs interpreted wherever the backend is no TPU (the tests)."""
-    return jax.default_backend() != "tpu"
 
 
 def _gmm_call(x, w, sizes, transpose: bool):
@@ -404,7 +441,9 @@ class JoyAIFlash:
         arch, p, base = self.arch, variables["params"], variables["base"]
         s = tokens.shape[1] - 2
         emb = lambda t: base["embed"][t].astype(F32)  # noqa: E731
-        blk = jax.checkpoint(lambda w, g, h: block(arch, w, g, h))
+        blk = jax.checkpoint(
+            lambda w, g, h: block(arch, w, g, h),
+            policy=jax.checkpoint_policies.save_only_these_names(ATTN_SAVED))
         h, routed = emb(tokens[:, :s]), []
         for w, g in zip(base["blocks"], p["blocks"]):
             h, seen = blk(w, g, h)
@@ -419,6 +458,10 @@ class JoyAIFlash:
             h2, seen = blk(mb["block"], m["block"], _mm(both, mb["eh"]))
             h_mtp = rms_norm(h2, m["norm"], arch.eps)
         routed.append(seen)
+        # every block's attention is the fused kernel: `causal_attention`
+        # has one path
+        obs_metrics.gauge("model.fused_attention_layers").set(
+            len(base["blocks"]) + 1)
         return h_main, h_mtp, (jnp.stack([r[0] for r in routed]),
                                jnp.stack([r[1] for r in routed]))
 

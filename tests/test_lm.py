@@ -97,6 +97,77 @@ def test_latent_attention_block_matches_reference(ref, seeded):
                            got[:, :-1])
 
 
+def _plain_attention(q, k, v):
+    """The reference's attention (`ref._attention`'s `attend`) on q, k, v:
+    float32 scores, mask, softmax and products, one block."""
+    s = q.shape[1]
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    ok = np.arange(s)[:, None] >= np.arange(s)[None, :]
+    p = jax.nn.softmax(jnp.where(ok[None, None], sc, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def _qkv(s, seed=11, b=2, h=2, dq=24, dv=16):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (b, s, h, dq)),
+            jax.random.normal(ks[1], (b, s, h, dq)),
+            jax.random.normal(ks[2], (b, s, h, dv)),
+            jax.random.normal(ks[3], (b, s, h, dv)))
+
+
+def test_fused_attention_and_its_gradients_match_plain_attention():
+    # three key blocks of 128, dq != dv, 300 positions padded to 384
+    q, k, v, ct = _qkv(300)
+    want = _highest(_plain_attention, q, k, v)
+    got = lm.causal_attention(q, k, v, 128)
+    assert got.shape == want.shape == (2, 300, 2, 16) and got.dtype == jnp.float32
+    # bfloat16 operands and a bfloat16 output against float32: a few parts
+    # in a thousand of the largest entry (the first rows average few keys)
+    assert float(jnp.max(jnp.abs(got - want))) < 0.01 * float(jnp.max(jnp.abs(want)))
+    g_want = _highest(jax.grad(
+        lambda *a: jnp.sum(_plain_attention(*a) * ct), (0, 1, 2)), q, k, v)
+    g_got = jax.grad(
+        lambda *a: jnp.sum(lm.causal_attention(*a, 128) * ct), (0, 1, 2))(q, k, v)
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape
+        assert float(jnp.max(jnp.abs(a - b))) < 0.02 * float(jnp.max(jnp.abs(b)))
+
+
+def test_padded_positions_move_no_output_and_receive_no_gradient():
+    # 300 positions are padded to 384 inside; 384 positions are not padded.
+    # Whatever lies behind position 299 must read as the padding does.
+    q, k, v, ct = _qkv(384, seed=12)
+    cut = lambda *a: tuple(t[:, :300] for t in a)  # noqa: E731
+    got = lm.causal_attention(*cut(q, k, v), 128)
+    assert jnp.array_equal(got, lm.causal_attention(q, k, v, 128)[:, :300])
+    loss = lambda *a: jnp.sum(lm.causal_attention(*a, 128)[:, :300]  # noqa: E731
+                              * ct[:, :300])
+    g_pad = jax.grad(loss, (0, 1, 2))(*cut(q, k, v))
+    g_all = jax.grad(loss, (0, 1, 2))(q, k, v)
+    for a, b in zip(g_pad, g_all):
+        assert jnp.array_equal(a, b[:, :300])
+        assert not np.any(np.asarray(b[:, 300:]))
+    # one kernel object a (padded length, heads, block): built once
+    before = lm._attention_kernel.cache_info().misses
+    lm.causal_attention(*cut(q, k, v), 128)
+    assert lm._attention_kernel.cache_info().misses == before
+
+
+def test_gauge_counts_the_attention_layers_that_took_the_kernel(seeded):
+    from hefl_tpu.obs import metrics as obs_metrics
+
+    _, v, tokens = seeded
+    gauge = obs_metrics.gauge("model.fused_attention_layers")
+    gauge.set(0)
+    module = lm.JoyAIFlash(num_classes=VOCAB, arch=TINY, seed=3)
+    jax.eval_shape(lambda p: module.apply({"base": v["base"], "params": p},
+                                          tokens), v["params"])
+    # 1 dense + 2 expert layers + the prediction module
+    assert gauge.value == TINY.dense_layers + TINY.expert_layers + 1 == 4
+    create_model("smallcnn", num_classes=2, input_shape=(16, 16, 3))
+    assert obs_metrics.snapshot()["model.fused_attention_layers"] == 0
+
+
 def test_expert_block_matches_reference(ref, seeded):
     conf, v, _ = seeded
     w, g = v["base"]["blocks"][1], v["params"]["blocks"][1]

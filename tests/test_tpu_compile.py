@@ -111,3 +111,33 @@ def test_grouped_expert_product_compiles_for_described_v5e(
         jax.config.update("jax_enable_compilation_cache", prev)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_fused_attention_gradient_compiles_for_described_v5e(
+        one_chip, monkeypatch):
+    """`jax.grad` of `models/lm.causal_attention` at JoyAI-LLM-Flash's widths
+    (32 heads of 192 / 128) and the benchmark's batch (2 x 4,096 positions),
+    the preset's block: the forward kernel and the gradient's (`dkv`, which
+    forms `dq` too) lower through Mosaic, and no score block reaches HBM (the
+    blocked XLA form read 38.5 GB here; q, k, v, their gradients and the
+    layout changes are under 4)."""
+    from hefl_tpu.models import lm
+
+    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    arch = lm.PRESETS["joyai_llm_flash"]
+    dq = arch.qk_nope_head_dim + arch.qk_rope_head_dim
+    args = [jax.ShapeDtypeStruct((2, 4096, arch.heads, d), jnp.bfloat16,
+                                 sharding=one_chip)
+            for d in (dq, dq, arch.v_head_dim)]
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(lm.causal_attention(q, k, v, arch.q_block)),
+            (0, 1, 2))).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert text.count("tpu_custom_call") >= 2
+    assert compiled.cost_analysis()["bytes accessed"] < 4e9
