@@ -185,6 +185,36 @@ def _mixed_radix_digits(ctx: NTTContext, residues: jnp.ndarray):
     return digits
 
 
+def decode_coefficients(ctx: NTTContext, scale: float) -> np.ndarray:
+    """The float decode's L factors, float32[L]: digit i is worth
+    `p_0..p_{i-1} / scale`, the product formed on the host in float64 and
+    rounded to float32 once. `decode_with_coefficients` takes them as data,
+    not as constants: a program compiled around it serves every scale (a
+    round with another surviving-client count) unchanged."""
+    p = np.asarray(ctx.p)[:, 0]
+    inv_scale = 1.0 / float(scale)
+    coeffs = [inv_scale]
+    radix = 1.0
+    for i in range(1, len(p)):
+        radix *= float(int(p[i - 1]))
+        coeffs.append(radix * inv_scale)
+    return np.asarray(coeffs, dtype=np.float32)
+
+
+def decode_with_coefficients(
+    ctx: NTTContext, residues: jnp.ndarray, coeffs: jnp.ndarray
+) -> jnp.ndarray:
+    """Canonical residues uint32[..., L, N] and `decode_coefficients`'
+    float32[L] -> float32[..., N]: exact digits, then the float32
+    recombination, digit 0 first. The jittable body of `decode`, and of
+    the owner's compiled decode (`fl.secure._decode_unpack`)."""
+    digits = _mixed_radix_digits(ctx, residues)
+    out = digits[0].astype(jnp.float32) * coeffs[0]
+    for i in range(1, len(digits)):
+        out = out + digits[i].astype(jnp.float32) * coeffs[i]
+    return out
+
+
 def decode(ctx: NTTContext, residues: jnp.ndarray, scale: float) -> jnp.ndarray:
     """Canonical residues uint32[..., L, N] -> float32[..., N] (jittable).
 
@@ -193,15 +223,9 @@ def decode(ctx: NTTContext, residues: jnp.ndarray, scale: float) -> jnp.ndarray:
     of magnitude below the SGD noise floor, and far below the reference's
     per-weight fixed-point error budget.
     """
-    digits = _mixed_radix_digits(ctx, residues)
-    p = np.asarray(ctx.p)[:, 0]
-    inv_scale = 1.0 / float(scale)
-    out = digits[0].astype(jnp.float32) * jnp.float32(inv_scale)
-    radix = 1.0
-    for i in range(1, len(digits)):
-        radix *= float(int(p[i - 1]))
-        out = out + digits[i].astype(jnp.float32) * jnp.float32(radix * inv_scale)
-    return out
+    return decode_with_coefficients(
+        ctx, residues, decode_coefficients(ctx, scale)
+    )
 
 
 def decode_exact(

@@ -58,6 +58,7 @@ from hefl_tpu.fl.fedavg import (
 )
 from hefl_tpu.ckks.modular import add_mod as modular_add_mod
 from hefl_tpu.ckks.modular import barrett_mod, barrett_mu
+from hefl_tpu.obs import metrics as obs_metrics
 from hefl_tpu.obs import scopes as obs_scopes
 from hefl_tpu.obs import spans as obs_spans
 from hefl_tpu.parallel import (
@@ -68,7 +69,8 @@ from hefl_tpu.parallel import (
 )
 from hefl_tpu.parallel.collectives import MAX_PSUM_CLIENTS, hierarchical_psum_mod
 
-# decrypt_average's host steps, as spans: kernel, decode, unpack
+# decrypt_average's host steps, as spans: kernel (the decrypt's launch),
+# decode (the compiled decode's launch), unpack (what is left after it)
 _DECRYPT_STEP = obs_spans.PHASE_PREFIX + "decrypt."
 
 
@@ -506,6 +508,31 @@ def aggregate_encrypted(ctx: CkksContext, cts: Ciphertext) -> Ciphertext:
     )
 
 
+@partial(jax.jit, static_argnums=(0, 1))
+def _decode_unpack(ntt, spec: PackSpec, res: jax.Array, coeffs: jax.Array):
+    """Decrypted residues uint32[n_ct, L, N] -> the parameter pytree, as ONE
+    program: exact mixed-radix digits, the float32 recombination by
+    `coeffs` (float32[L], `encoding.decode_coefficients`), the padding cut
+    and `spec.unravel`. The ring's tables and the `PackSpec` are static
+    (both are built once an experiment; the specs of one tree at one ring
+    compare equal, so a process traces this once a model); the decode
+    scale is data, so a round with another surviving-client count runs the
+    same executable. Takes the residues' sharding as it finds it
+    (`decrypt_sharded`'s ciphertext axis)."""
+    return unpack_blocks(
+        encoding.decode_with_coefficients(ntt, res, coeffs), spec
+    )
+
+
+def _decode_average(ntt, spec: PackSpec, res: jax.Array, scale: float):
+    """Launch the compiled float decode at this round's scale. (A helper
+    of its own: `decrypt_average`'s frame keeps its size.)"""
+    obs_metrics.counter("he.decode_programs").inc()
+    return _decode_unpack(
+        ntt, spec, res, encoding.decode_coefficients(ntt, scale)
+    )
+
+
 def decrypt_average(
     ctx: CkksContext,
     sk: SecretKey,
@@ -524,7 +551,10 @@ def decrypt_average(
     `decrypt_import_weights` (FLPyfhelin.py:263-281). Division by the
     client count happens in the decode scale — exact, no ciphertext op.
     `exact=True` routes through the host bignum CRT (the trust-boundary
-    path used for final model export); default is the jittable f32 decode.
+    path used for final model export); default is the compiled f32 decode:
+    digits, recombination and unpack as one program (`_decode_unpack`),
+    launched once behind the decrypt kernel and counted by
+    `he.decode_programs`.
     `mesh` (a `parallel.make_ct_mesh` mesh) shards the decrypt over the
     ciphertext axis — bitwise-equal residues, owner-side throughput scaling
     with devices (ISSUE 4).
@@ -579,7 +609,8 @@ def decrypt_average(
         surviving = int(num_clients)
     # The owner's three host steps, each a span (children of the driver's
     # `hefl.phase.decrypt` when it is open): launching the decrypt kernel,
-    # the op-by-op decode, and the unpack into the parameter pytree.
+    # launching the compiled decode (the exact and packed decodes run on
+    # the host inside it), and whatever unpacking the host still does.
     with jax.named_scope(obs_scopes.DECRYPT):
         with obs_spans.span(_DECRYPT_STEP + "kernel"):
             if mesh is not None:
@@ -605,19 +636,23 @@ def decrypt_average(
                 return unravel(base_flat + jnp.asarray(delta))
         # (the decode scale is written out twice rather than named: this
         # frame keeps the size it had, see run_experiment's note on frames)
-        with obs_spans.span(_DECRYPT_STEP + "decode"):
-            if exact:
+        if exact:
+            with obs_spans.span(_DECRYPT_STEP + "decode"):
                 blocks = jnp.asarray(
                     encoding.decode_exact(
                         ctx.ntt, np.asarray(res), ct_sum.scale * surviving
                     ).astype(np.float32)
                 )
-            else:
-                blocks = encoding.decode(
-                    ctx.ntt, res, ct_sum.scale * surviving
-                )
+            with obs_spans.span(_DECRYPT_STEP + "unpack"):
+                return unpack_blocks(blocks, spec)
+        with obs_spans.span(_DECRYPT_STEP + "decode"):
+            blocks = _decode_average(
+                ctx.ntt, spec, res, ct_sum.scale * surviving
+            )
+        # the program unpacked too (`blocks` is the pytree already): the
+        # span stays, so that every round records the same set of them
         with obs_spans.span(_DECRYPT_STEP + "unpack"):
-            return unpack_blocks(blocks, spec)
+            return blocks
 
 
 def secure_fedavg_round(

@@ -54,7 +54,9 @@ def test_two_rounds_leave_one_of_each_sub_span_a_round(tmp_path, monkeypatch):
     the set-up steps once, each round's phases and their steps once a
     round, children inside their parents; `history[r]["phases"]` is what it
     was. The same call's result names its three backends, keeps no
-    utilization of its own, and its event log has the run's schema."""
+    utilization of its own, and its event log has the run's schema. The
+    owner's decode is one program, compiled in round 0 and launched once a
+    round: round 1 compiles nothing."""
     from hefl_tpu.obs import events as obs_events
     from hefl_tpu.obs import spans as obs_spans
 
@@ -80,6 +82,16 @@ def test_two_rounds_leave_one_of_each_sub_span_a_round(tmp_path, monkeypatch):
         e["phase"] for e in evs if e["event"] == "round_phase"}
     end = [e for e in evs if e["event"] == "experiment_end"][-1]
     assert end["metrics"]["analysis.violations"] == 0
+    # the compiled decode: launched once a round, compiled at most once and
+    # then in round 0's decrypt (an earlier call of this process with the
+    # same tree and ring has left its program: equal PackSpecs share it);
+    # nothing at all compiles once round 0 has ended
+    round_ends = [e["ts"] for e in evs if e["event"] == "round_end"]
+    compiles = [e for e in evs if e["event"] == "compile"]
+    assert len(round_ends) == 2
+    assert [e["fun_name"] for e in compiles if e["ts"] > round_ends[0]] == []
+    assert [e["fun_name"] for e in compiles].count("jit(_decode_unpack)") <= 1
+    assert out["obs"]["metrics"]["he.decode_programs"] == 2
     rows = obs_spans.recorded()
     call = max(s.call for s in rows if s.call is not None)
     rows = [s for s in rows if s.call == call]

@@ -392,3 +392,230 @@ def test_owner_decrypt_takes_a_single_device_copy():
     # already on one device: passed through untouched
     single = jnp.asarray(x)
     assert _on_one_device(Ciphertext(c0=single, c1=single, scale=1.0)).c0 is single
+
+
+# ------------------------------------------------- the owner's compiled decode
+# (PR 30) `decrypt_average`'s float path is ONE program of the decrypted
+# residues: digits, float32 recombination, unpack. The eager decode stays
+# the reference, with the pre-PR recombination written out below.
+
+
+def _decode_as_before(ntt, res, scale):
+    """`encoding.decode` as it stood before it was split into coefficients
+    and body: each factor formed in float64 and rounded by `jnp.float32`."""
+    digits = encoding._mixed_radix_digits(ntt, res)
+    p = np.asarray(ntt.p)[:, 0]
+    inv_scale = 1.0 / float(scale)
+    out = digits[0].astype(jnp.float32) * jnp.float32(inv_scale)
+    radix = 1.0
+    for i in range(1, len(digits)):
+        radix *= float(int(p[i - 1]))
+        out = out + digits[i].astype(jnp.float32) * jnp.float32(radix * inv_scale)
+    return out
+
+
+def _one_rounding(ntt, res, scale):
+    """float32 eps x the sum of the recombination's |terms|: what a
+    compiler that contracts a multiply-add (one rounding, not two) may
+    move a decoded value by."""
+    digits = encoding._mixed_radix_digits(ntt, res)
+    coeffs = encoding.decode_coefficients(ntt, scale).astype(np.float64)
+    mag = sum(np.abs(np.asarray(d, np.float64)) * c for d, c in zip(digits, coeffs))
+    return np.finfo(np.float32).eps * mag
+
+
+@pytest.fixture(scope="module")
+def bench_ring():
+    """The benchmark's ring (`HEConfig()`: N 4096, 3 primes, scale 2^30) and
+    a 55-row parameter tree (222,786 values, the tail row padded)."""
+    ctx = CkksContext.create()
+    assert ctx.n == 4096 and np.asarray(ctx.ntt.p).shape[0] == 3
+    template = {
+        "conv": {"kernel": np.zeros((3, 3, 32, 64), np.float32),
+                 "bias": np.zeros((64,), np.float32)},
+        "dense": {"kernel": np.zeros((1596, 128), np.float32),
+                  "bias": np.zeros((2,), np.float32)},
+    }
+    spec = PackSpec.for_params(template, ctx.n)
+    assert spec.n_ct == 55 and spec.total % ctx.n != 0
+    return ctx, spec
+
+
+def _residues(ntt, v):
+    """Exact integers int64[n_ct, N] -> canonical residues uint32[n_ct, L, N]."""
+    p = np.asarray(ntt.p)[:, 0].astype(np.int64)
+    return jnp.asarray(
+        np.stack([np.mod(v, pi) for pi in p], axis=-2).astype(np.uint32)
+    )
+
+
+def _leaves(tree):
+    return [np.asarray(x) for x in jax.tree_util.tree_leaves(tree)]
+
+
+def test_compiled_decode_is_the_eager_decode_on_an_aggregate(bench_ring):
+    from hefl_tpu.fl.secure import _decode_unpack
+
+    ctx, spec = bench_ring
+    ntt, clients = ctx.ntt, 4
+    rng = np.random.default_rng(30)
+    # four clients' weights |w| < 1 at scale 2^30, summed, plus decrypt noise
+    w = rng.uniform(-1, 1, (clients, spec.n_ct, ctx.n))
+    v = np.rint(w * ctx.scale).astype(np.int64).sum(0)
+    v += np.rint(rng.normal(0, 8, v.shape)).astype(np.int64)
+    res, scale = _residues(ntt, v), ctx.scale * clients
+    got = _decode_unpack(
+        ntt, spec, res, encoding.decode_coefficients(ntt, scale)
+    )
+    eager = unpack_blocks(encoding.decode(ntt, res, scale), spec)
+    before = unpack_blocks(_decode_as_before(ntt, res, scale), spec)
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(eager)
+    for a, b, c in zip(_leaves(got), _leaves(eager), _leaves(before)):
+        assert a.shape == b.shape and a.dtype == np.float32
+        np.testing.assert_array_equal(b, c)   # the split moved no bit
+        np.testing.assert_array_equal(a, b)   # nor did compiling it
+    gold = encoding.decode_exact(ntt, np.asarray(res), scale)
+    flat = np.concatenate([a.ravel() for a in _leaves(got)])
+    np.testing.assert_allclose(
+        flat, gold.reshape(-1)[: spec.total], rtol=2.0**-19, atol=0
+    )
+    # and the true mean, to the noise: 8 / (4 * 2^30)
+    np.testing.assert_allclose(
+        flat, w.mean(0).reshape(-1)[: spec.total], atol=1e-7
+    )
+
+
+def test_compiled_decode_on_random_full_size_residues(bench_ring):
+    from hefl_tpu.fl.secure import _decode_unpack
+
+    ctx, spec = bench_ring
+    ntt = ctx.ntt
+    rng = np.random.default_rng(31)
+    p = np.asarray(ntt.p)[:, 0].astype(np.int64)
+    res = jnp.asarray(
+        np.stack(
+            [rng.integers(0, pi, (spec.n_ct, ctx.n)) for pi in p], axis=-2
+        ).astype(np.uint32)
+    )
+    # the digits are exact integer arithmetic: compiled == eager, bit for bit
+    eager_digits = encoding._mixed_radix_digits(ntt, res)
+    jit_digits = jax.jit(lambda r: tuple(encoding._mixed_radix_digits(ntt, r)))(res)
+    for a, b in zip(jit_digits, eager_digits):
+        assert a.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the floats: within one float32 rounding of the eager recombination
+    scale = ctx.scale * 4
+    got = _decode_unpack(
+        ntt, spec, res, encoding.decode_coefficients(ntt, scale)
+    )
+    eager = np.asarray(encoding.decode(ntt, res, scale))
+    np.testing.assert_array_equal(
+        eager, np.asarray(_decode_as_before(ntt, res, scale))
+    )
+    flat = np.concatenate([a.ravel() for a in _leaves(got)])
+    room = _one_rounding(ntt, res, scale).reshape(-1)[: spec.total]
+    assert np.all(np.abs(flat - eager.reshape(-1)[: spec.total]) <= room)
+    gold = encoding.decode_exact(ntt, np.asarray(res), scale)
+    np.testing.assert_allclose(
+        flat, gold.reshape(-1)[: spec.total], rtol=2.0**-19, atol=0
+    )
+
+
+@pytest.fixture(scope="module")
+def masked_sum(ctx_keys):
+    """Four clients' encrypted sum, one PackSpec for every case below, and
+    the compiled decode's cache size before any of them ran."""
+    from hefl_tpu.fl.secure import _decode_unpack
+
+    ctx, sk, pk = ctx_keys
+    # a tree no other test of this process packs: PackSpecs of equal trees
+    # compare equal and would find the program already traced
+    trees = [
+        {"w": jax.random.normal(jax.random.key(300 + i), (7, 11, 5)) * 0.5,
+         "b": jax.random.normal(jax.random.key(310 + i), (13,)) * 0.5}
+        for i in range(4)
+    ]
+    spec = PackSpec.for_params(trees[0], ctx.n)
+    cts = [
+        encrypt_params(ctx, pk, t, jax.random.key(400 + i))
+        for i, t in enumerate(trees)
+    ]
+    ct_sum = aggregate_encrypted(
+        ctx,
+        ops.Ciphertext(
+            c0=jnp.stack([c.c0 for c in cts]),
+            c1=jnp.stack([c.c1 for c in cts]),
+            scale=cts[0].scale,
+        ),
+    )
+    return spec, ct_sum, _decode_unpack._cache_size()
+
+
+@pytest.mark.parametrize("surviving", [1, 3, 4])
+def test_one_decode_program_serves_every_surviving_count(
+    ctx_keys, masked_sum, surviving
+):
+    # Partial participation changes the decode's denominator from round to
+    # round; the scale is data of the program, so nothing compiles again.
+    from hefl_tpu.fl.faults import EXCLUDED_SCHEDULED, RoundMeta
+    from hefl_tpu.fl.secure import _decode_unpack
+
+    ctx, sk, _ = ctx_keys
+    spec, ct_sum, programs_before = masked_sum
+    bits = [0] * surviving + [EXCLUDED_SCHEDULED] * (4 - surviving)
+    meta = RoundMeta.from_bits(bits)
+    assert meta.surviving == surviving
+    got = decrypt_average(ctx, sk, ct_sum, spec=spec, meta=meta)
+    assert _decode_unpack._cache_size() == programs_before + 1
+    res = ops.decrypt(ctx, sk, ct_sum)
+    want = unpack_blocks(
+        encoding.decode(ctx.ntt, res, ct_sum.scale * surviving), spec
+    )
+    room = _one_rounding(ctx.ntt, res, ct_sum.scale * surviving)
+    room = unpack_blocks(jnp.asarray(room, jnp.float32), spec)
+    for a, b, r in zip(_leaves(got), _leaves(want), _leaves(room)):
+        assert np.all(np.abs(a - b) <= r)
+    # a sum of four decoded over s is 4/s times the mean of four
+    full = decrypt_average(ctx, sk, ct_sum, 4, spec)
+    for a, b in zip(_leaves(got), _leaves(full)):
+        np.testing.assert_allclose(a, b * (4 / surviving), rtol=1e-6, atol=1e-7)
+
+
+def test_decode_programs_counts_the_compiled_float_path_alone(ctx_keys):
+    from hefl_tpu.ckks.packing import PackedSpec
+    from hefl_tpu.ckks.quantize import PackingConfig
+    from hefl_tpu.fl import encrypt_stack_packed
+    from hefl_tpu.obs import metrics as obs_metrics
+
+    ctx, sk, pk = ctx_keys
+    counter = obs_metrics.counter("he.decode_programs")
+    params = _rand_pytree(jax.random.key(21))
+    spec = PackSpec.for_params(params, ctx.n)
+    ct = encrypt_params(ctx, pk, params, jax.random.key(22))
+    n0 = counter.value
+    decrypt_average(ctx, sk, ct, 1, spec)
+    assert counter.value == n0 + 1
+    decrypt_average(ctx, sk, ct, 1, spec)
+    assert counter.value == n0 + 2
+    # the host bignum export and the packed integers do not go through it
+    decrypt_average(ctx, sk, ct, 1, spec, exact=True)
+    assert counter.value == n0 + 2
+    trees = [
+        jax.tree_util.tree_map(
+            lambda t: t + 0.05 * jax.random.normal(jax.random.key(60 + i), t.shape),
+            params,
+        )
+        for i in range(2)
+    ]
+    pspec = PackedSpec.for_params(
+        params, ctx, PackingConfig(bits=8, interleave=2, clip=0.25), 2
+    )
+    cts, _ = encrypt_stack_packed(
+        ctx, pk, jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees),
+        params, jax.random.split(jax.random.key(23), 2), pspec,
+    )
+    decrypt_average(
+        ctx, sk, aggregate_encrypted(ctx, cts), 2,
+        packing=pspec, base_params=params,
+    )
+    assert counter.value == n0 + 2

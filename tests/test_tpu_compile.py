@@ -141,3 +141,33 @@ def test_fused_attention_gradient_compiles_for_described_v5e(
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
     assert text.count("tpu_custom_call") >= 2
     assert compiled.cost_analysis()["bytes accessed"] < 4e9
+
+
+def test_owner_decode_program_compiles_for_described_v5e(one_chip):
+    """The owner's compiled decode + unpack (`fl.secure._decode_unpack`, PR
+    30) at the benchmark's ring and about resnet20's size, 64 rows into 65 leaves:
+    one program with no host callback and no custom call, its outputs the
+    parameter tree's leaves."""
+    import numpy as np
+
+    from hefl_tpu.ckks.keys import CkksContext
+    from hefl_tpu.ckks.packing import PackSpec
+    from hefl_tpu.fl.secure import _decode_unpack
+
+    ctx = CkksContext.create()
+    tree = {f"w{i}": np.zeros((3, 3, 16, 28), np.float32) for i in range(64)}
+    tree["head"] = np.zeros((64, 10), np.float32)
+    spec = PackSpec.for_params(tree, ctx.n)
+    assert spec.n_ct == 64 and len(tree) == 65
+    res = jax.ShapeDtypeStruct((spec.n_ct, 3, ctx.n), jnp.uint32, sharding=one_chip)
+    coeffs = jax.ShapeDtypeStruct((3,), jnp.float32, sharding=one_chip)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = _decode_unpack.lower(ctx.ntt, spec, res, coeffs).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    text = compiled.as_text()
+    assert "custom-call" not in text and "callback" not in text
+    shapes = jax.tree_util.tree_leaves(compiled.out_info)
+    assert len(shapes) == 65 and all(s.dtype == jnp.float32 for s in shapes)
