@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from hefl_tpu.models.cnn import LogReg, MedCNN, SmallCNN, count_params
 from hefl_tpu.models.lm import (
     PRESETS as LM_PRESETS,
+    FrozenBaseLM,
     JoyAIFlash,
     frozen_base,
     is_token_model,
@@ -41,6 +42,8 @@ MODEL_REGISTRY: dict[str, tuple[type, int, tuple[int, int, int]]] = {
 TOKEN_MODELS: dict[str, int] = {
     "joyai_llm_flash": 16160,
     "joyai_llm_flash_tiny": 64,
+    "deepseek_v32": 16160,
+    "deepseek_v32_tiny": 64,
 }
 
 
@@ -61,7 +64,7 @@ def create_model(
     (`frozen_base(module)`), never returned here.
     """
     if name in TOKEN_MODELS:
-        module = JoyAIFlash(
+        module = FrozenBaseLM(
             num_classes=num_classes or TOKEN_MODELS[name],
             arch=LM_PRESETS[name], seed=int(seed),
         )
@@ -77,8 +80,9 @@ def create_model(
         rng = jax.random.key(0)
     # MedCNN's stages set it as they are traced; no other model has any.
     obs_metrics.gauge("model.polyphase_stages").set(0)
-    # a token model's forward sets it as it is traced (models/lm.py)
+    # a token model's forward sets them as it is traced (models/lm.py)
     obs_metrics.gauge("model.fused_attention_layers").set(0)
+    obs_metrics.gauge("model.sparse_attention_layers").set(0)
     dummy = jnp.zeros(
         (1, *(input_shape if input_shape is not None else default_shape)), jnp.float32
     )
@@ -91,6 +95,7 @@ __all__ = [
     "MedCNN",
     "SmallCNN",
     "ResNet20",
+    "FrozenBaseLM",
     "JoyAIFlash",
     "frozen_base",
     "set_frozen_base",

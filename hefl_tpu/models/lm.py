@@ -1,11 +1,31 @@
-"""A DeepSeek-V3-shaped language model as a frozen base and a trained subset.
+"""DeepSeek-V3-shaped language models as a frozen base and a trained subset.
 
-`JoyAIFlash` is JoyAI-LLM-Flash (huggingface.co/jdopensource/JoyAI-LLM-Flash,
-config.json; every key a DeepSeek-V3 key, so the equations are those of
-arXiv:2412.19437 section 2): multi-head latent attention, one leading dense
-layer, expert layers with a sigmoid router (256 wide, 8 a token, one shared
-expert, `noaux_tc` selection over `s + bias`, weights normalised over the
-selected and scaled by 2.5) and one multi-token-prediction module.
+One module class, `FrozenBaseLM`, over an `LMArch`; two published models:
+
+  * **JoyAI-LLM-Flash** (`PRESETS["joyai_llm_flash"]`; huggingface.co/
+    jdopensource/JoyAI-LLM-Flash, config.json; every key a DeepSeek-V3 key,
+    so the equations are those of arXiv:2412.19437 section 2): multi-head
+    latent attention, one leading dense layer, expert layers with a sigmoid
+    router (256 wide, 8 a token, one shared expert, `noaux_tc` selection
+    over `s + bias`, weights normalised over the selected and scaled by 2.5)
+    and one multi-token-prediction module.
+  * **DeepSeek-V3.2-Exp** (`PRESETS["deepseek_v32"]`; huggingface.co/
+    deepseek-ai/DeepSeek-V3.2-Exp, `model_type` `deepseek_v32`): the same
+    layer at hidden 7168 and 128 heads, with what the first model lacks:
+    **learned sparse attention** (a lightning indexer a layer, `select_keys`:
+    I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]) over 64 indexer heads,
+    a query keeps its 2,048 keys of largest I, and the main attention's
+    softmax runs over those alone, `selected_attention`), **group-limited
+    routing** (`route`: 8 groups, the experts of the 4 best), **YaRN**
+    frequencies and softmax scale (`rope`, `softmax_scale`), and an expert
+    layer that holds 8 of 256 experts and costs what its held pairs cost
+    (`held_experts` in blocks). Its indexer is frozen with the base and its
+    products are bfloat16 (published: FP8 after a Hadamard turn, trained by
+    an alignment loss): `benchmarks/configs/deepseek-v32-exp-l5e8.json`
+    lists each departure.
+
+With `index_topk` 0, `n_group` 1 and no `rope_scaling` the traced program is
+the first model's, operation for operation.
 
 What a federation can afford of such a model (PERF.md, PR 26-27: a trained
 parameter costs a client 16 bytes for its step and 24 for its ciphertext, a
@@ -26,13 +46,14 @@ held experts' terms are computed by a grouped matrix product
 (`grouped_matmul`, every (token, held expert) pair whatever the
 imbalance: no capacity, nothing dropped) and summed with the shared expert;
 what absent experts would add is left out. Compute is bfloat16 with float32
-accumulation; the residual stream, the norms, the router and the softmax are
-float32. Attention is causal and one fused Pallas kernel a layer
-(`causal_attention`: splash attention, forward and gradient): scores and
+accumulation; the residual stream, the norms, the router, the selection and
+the softmax are float32. Attention is one fused Pallas kernel a layer
+(splash attention, forward and gradient), causal (`causal_attention`) or
+over the indexer's selection (`selected_attention`): scores and
 probabilities live a block at a time in the chip's fast memory and never in
-HBM, the blocks above the diagonal are skipped, and memory is linear in the
-sequence. Every block is made again for the gradient (a `jax.checkpoint` a
-layer) but for attention's output and log-sum-exp, which are kept.
+HBM, blocks no query of which attends any key are skipped, and memory is
+linear in the sequence. Every block is made again for the gradient (a
+`jax.checkpoint` a layer); what is kept besides its input is `_kept`'s.
 
 A module here is a frozen dataclass, hashable like a flax module, with the
 same `apply({"params": ...}, x)`; `bind(base)` gives the module that the
@@ -81,6 +102,20 @@ class LMArch:
     init_std: float = 0.02            # assumed: V3's initializer_range
     q_block: int = 1024               # attention's query and key block
     loss_chunk: int = 2048            # tokens a slice of the head's logits
+    # group-limited routing: the experts in `n_group` groups, a token's
+    # experts taken from its `topk_group` best groups (1, 1: no groups)
+    n_group: int = 1
+    topk_group: int = 1
+    # YaRN: (factor, original positions, beta_fast, beta_slow,
+    # mscale_all_dim), or None for plain RoPE
+    rope_scaling: tuple | None = None
+    # the indexer of learned sparse attention (0 heads: none, attention is
+    # causal over every key): a query attends its `index_topk` best keys
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_block: int = 256            # queries a slice of the indexer's scores
+    pair_block: int = 512             # rows a block of the held experts' pairs
 
 
 PRESETS = {
@@ -93,6 +128,22 @@ PRESETS = {
         intermediate=128, moe_intermediate=32, n_experts=8,
         experts_per_tok=2, expert_layers=2, held_start=0, held_experts=4,
         q_block=128, loss_chunk=16),
+    # the benchmark's `deepseek-v32-exp-l5e8`: every width as published, a
+    # chip's share of 32 that divide every expert layer (experts 0-7)
+    "deepseek_v32": LMArch(
+        hidden=7168, heads=128, intermediate=18432, moe_intermediate=2048,
+        rope_theta=10_000.0, held_experts=8, n_group=8, topk_group=4,
+        rope_scaling=(40.0, 4096, 32.0, 1.0, 1.0),
+        index_heads=64, index_head_dim=128, index_topk=2048, q_block=512),
+    "deepseek_v32_tiny": LMArch(
+        hidden=64, heads=4, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        intermediate=128, moe_intermediate=32, n_experts=16,
+        experts_per_tok=2, expert_layers=2, held_start=0, held_experts=4,
+        rope_theta=10_000.0, n_group=4, topk_group=2,
+        rope_scaling=(40.0, 16, 32.0, 1.0, 1.0),
+        index_heads=4, index_head_dim=16, index_topk=8, index_block=16,
+        pair_block=32, q_block=128, loss_chunk=16),
 }
 
 
@@ -117,17 +168,52 @@ def _mm(x, w):
     return jnp.dot(x.astype(BF16), w, preferred_element_type=F32)
 
 
-def rope(x, theta: float):
-    """Rotary embedding over interleaved pairs (`rope_interleave`), no
-    scaling. x: [B, S, H, d]; the position is the index along S."""
+def yarn_ramp(d: int, theta: float, scaling: tuple):
+    """YaRN's share of interpolation a frequency, f32[d / 2] (NumPy): 0
+    where a pair turns more than `beta_fast` times over the original
+    positions (kept), 1 where fewer than `beta_slow` times (divided by the
+    factor), linear between the two pair indices."""
+    import numpy as np
+
+    _, positions, fast, slow, _ = scaling
+    cd = lambda r: d * math.log(positions / (2 * math.pi * r)) / (  # noqa: E731
+        2 * math.log(theta))
+    low = max(math.floor(cd(fast)), 0)
+    high = min(math.ceil(cd(slow)), d - 1)
+    return np.clip((np.arange(d // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+
+
+def rope(x, theta: float, scaling: tuple | None = None,
+         interleaved: bool = True):
+    """Rotary embedding. x: [B, S, H, d]; the position is the index along S.
+    Pair i is (x_2i, x_2i+1) (`rope_interleave`) or, half-split, (x_i,
+    x_i+d/2); it turns by position * theta^(-2i/d), with `scaling` at
+    YaRN's frequencies (`yarn_ramp`; cos and sin are not scaled)."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
+    if scaling is not None:
+        ramp = yarn_ramp(d, theta, scaling)
+        inv = inv / scaling[0] * ramp + inv * (1.0 - ramp)
     ang = jnp.arange(x.shape[1], dtype=F32)[:, None] * inv[None, :]
     cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    if not interleaved:
+        a, b = x.astype(F32)[..., :d // 2], x.astype(F32)[..., d // 2:]
+        return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
     x = x.astype(F32).reshape(*x.shape[:-1], d // 2, 2)
     a, b = x[..., 0], x[..., 1]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos], -1).reshape(
         *x.shape[:-2], d)
+
+
+def softmax_scale(arch: LMArch) -> float:
+    """1 / sqrt(d_qk), times YaRN's mscale squared where RoPE is scaled
+    (m = 0.1 * mscale_all_dim * ln(factor) + 1)."""
+    scale = 1.0 / math.sqrt(arch.qk_nope_head_dim + arch.qk_rope_head_dim)
+    if arch.rope_scaling is not None:
+        factor, _, _, _, all_dim = arch.rope_scaling
+        scale *= (0.1 * all_dim * math.log(factor) + 1.0) ** 2
+    return scale
 
 
 def _interpret() -> bool:
@@ -169,10 +255,11 @@ def _attention_kernel(seq: int, heads: int, block: int, interpret: bool):
     return jax.tree_util.tree_map(np.asarray, kernel)
 
 
-def causal_attention(q, k, v, q_block: int):
-    """softmax(q k^T / sqrt(d)) v, causal. q, k: [B, S, H, dq]; v: [B, S, H,
-    dv] -> f32[B, S, H, dv]. One fused Pallas kernel (`_attention_kernel`)
-    and one more for its gradient: bfloat16 operands, float32 scores, a
+def causal_attention(q, k, v, q_block: int, scale: float | None = None):
+    """softmax(q k^T / sqrt(d)) v, causal (`scale` in place of 1 / sqrt(d)).
+    q, k: [B, S, H, dq]; v: [B, S, H, dv] -> f32[B, S, H, dv]. One fused
+    Pallas kernel (`_attention_kernel`) and one more for its gradient:
+    bfloat16 operands, float32 scores, a
     float32 running maximum, sum and accumulator over the keys, the
     probabilities narrowed to bfloat16 for the product with v, the division
     at the end, the output narrowed to bfloat16 (as the product that takes
@@ -190,32 +277,225 @@ def causal_attention(q, k, v, q_block: int):
     pad = up(s, blk) - s
     heads_first = lambda x: jnp.pad(  # noqa: E731
         x.astype(BF16), ((0, 0), (0, pad), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
-    q = q.astype(F32) * (1.0 / math.sqrt(dq))
+    q = q.astype(F32) * (1.0 / math.sqrt(dq) if scale is None else scale)
     kernel = _attention_kernel(s + pad, h, blk, _interpret())
     o = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
     return o.transpose(0, 2, 1, 3)[:, :s].astype(F32)
 
 
+def _kept(arch: LMArch):
+    """What a layer's checkpoint keeps for the gradient besides the layer's
+    input: attention's output and log-sum-exp (`ATTN_SAVED`), so the forward
+    kernel does not run again; nothing where an indexer picks the keys (128
+    heads of 8,192 positions a layer: 0.27 GB that the step has no room
+    for)."""
+    if arch.index_topk:
+        return jax.checkpoint_policies.nothing_saveable
+    return jax.checkpoint_policies.save_only_these_names(ATTN_SAVED)
+
+
+HEADS_A_CALL = 16   # the fused gradient keeps a float32 dq a key block a head
+
+
+def selected_attention(heads_of, xs, picked, q_block: int):
+    """softmax(q k^T) v over the keys `picked` bool[B, S, S] names for each
+    query (every head the same ones), `HEADS_A_CALL` heads at a time:
+    `heads_of(x)`, for x a slice of `xs` along its leading (group) axis,
+    gives that group's (q, k, v), [B, S, heads, d] each, q already scaled;
+    -> bf16[B, S, all heads, dv]. The kernels and the arithmetic are
+    `causal_attention`'s (splash attention, forward and gradient), given the
+    mask as an array: it is laid out in blocks once (and once transposed for
+    the gradient), every group runs against that one layout, and a block no
+    query of which picks any key is skipped (above the diagonal, all of
+    them). No score block reaches HBM, and no array of all heads' queries,
+    keys or values exists: a group's are made from `xs` when it runs and
+    made again for its gradient. Query blocks are half the key blocks (a
+    mask block lies in the chip's fast memory as 32-bit words); a padded
+    query picks key 0, a padded key is picked by none. No gradient reaches
+    `picked`."""
+    import importlib
+
+    splash = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.splash_attention")
+    b, s, _ = picked.shape
+    up = lambda n, m: -(-n // m) * m  # noqa: E731
+    bq = min(up(q_block, 128), up(s, 128))
+    pad = up(s, bq) - s
+    bk = 2 * bq if (s + pad) % (2 * bq) == 0 else bq
+    step = min(bk, 512)
+    sizes = splash.BlockSizes(
+        block_q=bq, block_kv=bk, block_kv_compute=step, block_q_dkv=bq,
+        block_kv_dkv=bk, block_kv_dkv_compute=step, use_fused_bwd_kernel=True)
+    picked = jnp.pad(picked, ((0, 0), (0, pad), (0, pad)))
+    picked = picked.at[:, s:, 0].set(True)
+    kernels = [splash.make_splash_mha(
+        picked[i][None], block_sizes=sizes, head_shards=1, q_seq_shards=1,
+        residual_checkpoint_name=ATTN_SAVED, interpret=_interpret())
+        for i in range(b)]
+    one_head = lambda x: jnp.pad(  # noqa: E731
+        x.astype(BF16), ((0, pad), (0, 0), (0, 0))).transpose(1, 0, 2)[:, None]
+
+    @functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(ATTN_SAVED))
+    def group(x):
+        q, k, v = heads_of(x)
+        o = jnp.stack([jax.vmap(kernels[i])(
+            one_head(q[i]), one_head(k[i]), one_head(v[i]))
+            for i in range(b)])                     # [B, heads, 1, S, dv]
+        return o[:, :, 0, :s].transpose(0, 2, 1, 3)
+
+    o = jax.lax.map(group, xs)                      # [groups, B, S, heads, dv]
+    g, _, _, n, dv = o.shape
+    return o.transpose(1, 2, 0, 3, 4).reshape(b, s, g * n, dv)
+
+
+def kth_largest_mask(scores, k: int):
+    """bool[r, n]: the `k` entries a row of f32[r, n] that `jax.lax.top_k`
+    returns (the largest, the lower index first among equals), found without
+    a sort: the k-th largest value a bit at a time over an order-preserving
+    integer image of the floats (32 counting passes), then the first of its
+    equals by a running count."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def grow(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[:, None], -1) >= k
+        return jnp.where(enough, cand, prefix)
+
+    kth = jax.lax.fori_loop(0, 32, grow, jnp.zeros(key.shape[0], jnp.uint32))
+    above, equal = key > kth[:, None], key == kth[:, None]
+    need = k - jnp.sum(above, -1)
+    return above | (equal & (jnp.cumsum(equal, -1) <= need[:, None]))
+
+
+def layer_norm(x, gain, bias, eps: float):
+    x = x.astype(F32)
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * gain + bias
+
+
+def index_scores(arch: LMArch, w, x, c_q):
+    """The indexer's inputs to its score: (q bf16[B, S, Hi, di], k bf16[B, S,
+    di], weights f32[B, S, Hi]). q from the main queries' normed latent,
+    k = LayerNorm(x W_k) one shared head; the first `qk_rope_head_dim`
+    dims of both turned half-split at the main frequencies."""
+    b, s, _ = x.shape
+    hi, di, dr = arch.index_heads, arch.index_head_dim, arch.qk_rope_head_dim
+    turn = lambda t: jnp.concatenate(  # noqa: E731
+        [rope(t[..., :dr], arch.rope_theta, arch.rope_scaling, False),
+         t[..., dr:]], -1)
+    q = turn(_mm(c_q, w["q"]).reshape(b, s, hi, di))
+    k = turn(layer_norm(_mm(x, w["k"]), w["k_gain"], w["k_bias"],
+                        arch.eps)[:, :, None, :])[:, :, 0]
+    weights = _mm(x, w["w"]) * (hi ** -0.5 * di ** -0.5)
+    return q.astype(BF16), k.astype(BF16), weights
+
+
+def select_keys(arch: LMArch, w, x, c_q):
+    """The lightning indexer and its selection: bool[B, S, S], true where
+    query t attends key s. I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s]) over
+    the indexer's heads, for s <= t; a query keeps its `index_topk` keys of
+    largest I (all of them while it has no more), ties by `top_k`'s rule.
+    Scores and selection are made `index_block` queries at a time: a slice's
+    [queries, heads, S] products are summed over the heads where they are
+    made, and neither they nor I [S, S] exist whole anywhere. x and c_q are
+    detached, as published: no gradient reaches the indexer or passes
+    through the selection."""
+    with jax.named_scope(obs_scopes.DSA_INDEX):
+        q, k, weights = index_scores(
+            arch, w, jax.lax.stop_gradient(x), jax.lax.stop_gradient(c_q))
+        b, s, hi, di = q.shape
+        rows = min(arch.index_block, s)
+        pad = (-s) % rows
+        by_rows = lambda t: jnp.pad(  # noqa: E731
+            t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)).reshape(
+                b, (s + pad) // rows, rows, *t.shape[2:])
+        first = jnp.arange(0, s + pad, rows)
+
+        def one(keys):                    # a sequence's keys [S, di]
+            def picked(part):             # `rows` queries from position lo
+                lo, qs, ws = part
+                sc = jnp.einsum("qjd,sd->qjs", qs, keys,
+                                preferred_element_type=F32)
+                score = jnp.sum(jax.nn.relu(sc) * ws[:, :, None], axis=1)
+                causal = ((lo + jnp.arange(rows))[:, None]
+                          >= jnp.arange(s)[None, :])
+                return causal & kth_largest_mask(
+                    jnp.where(causal, score, -jnp.inf), arch.index_topk)
+            return picked
+
+        out = jnp.stack([
+            jax.lax.map(one(k[i]), (first, by_rows(q)[i], by_rows(weights)[i]))
+            for i in range(b)])
+        return out.reshape(b, s + pad, s)[:, :s]
+
+
 def latent_attention(arch: LMArch, w, g, x):
     """Multi-head latent attention. w: the block's frozen matrices, g: its
     trained gains (`q_norm`, `kv_norm`), x: [B, S, D] (already normed)."""
+    return _attend(arch, w, g, x)[0]
+
+
+def _attend(arch: LMArch, w, g, x):
+    """-> (`latent_attention`'s output, the (query, key) pairs its indexer
+    picked, int32, or None for a model without one)."""
     with jax.named_scope(obs_scopes.MLA):
         b, s, _ = x.shape
         h, dn, dr, dv = (arch.heads, arch.qk_nope_head_dim,
                          arch.qk_rope_head_dim, arch.v_head_dim)
         c_q = rms_norm(_mm(x, w["q_a"]), g["q_norm"], arch.eps)
+        if arch.index_topk:
+            return _attend_selected(arch, w, g, x, c_q)
         q = _mm(c_q, w["q_b"]).reshape(b, s, h, dn + dr)
         kv_a = _mm(x, w["kv_a"])
         c_kv, k_r = kv_a[..., :arch.kv_lora_rank], kv_a[..., arch.kv_lora_rank:]
         kv = _mm(rms_norm(c_kv, g["kv_norm"], arch.eps), w["kv_b"]).reshape(
             b, s, h, dn + dv)
-        q_r = rope(q[..., dn:], arch.rope_theta)
-        k_r = rope(k_r[:, :, None, :], arch.rope_theta)      # one shared head
+        q_r = rope(q[..., dn:], arch.rope_theta, arch.rope_scaling)
+        k_r = rope(k_r[:, :, None, :], arch.rope_theta,
+                   arch.rope_scaling)                          # one shared head
         q = jnp.concatenate([q[..., :dn], q_r], -1)
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_r, (b, s, h, dr))], -1)
-        o = causal_attention(q, k, kv[..., dn:], arch.q_block)
-        return _mm(o.reshape(b, s, h * dv), w["o"])
+        o = causal_attention(q, k, kv[..., dn:], arch.q_block,
+                             softmax_scale(arch))
+        return _mm(o.reshape(b, s, h * dv), w["o"]), None
+
+
+def _attend_selected(arch: LMArch, w, g, x, c_q):
+    """`_attend` where an indexer picks each query's keys: the same
+    projections, made `HEADS_A_CALL` heads at a time inside
+    `selected_attention` (at 128 heads of 8,192 positions all heads' q, k
+    and v are 1.1 GB in bfloat16, twice that in float32)."""
+    b, s, _ = x.shape
+    h, dn, dr, dv = (arch.heads, arch.qk_nope_head_dim,
+                     arch.qk_rope_head_dim, arch.v_head_dim)
+    picked = select_keys(arch, w["index"], x, c_q)
+    kv_a = _mm(x, w["kv_a"])
+    c_kv = rms_norm(kv_a[..., :arch.kv_lora_rank], g["kv_norm"], arch.eps)
+    k_r = rope(kv_a[..., arch.kv_lora_rank:][:, :, None, :], arch.rope_theta,
+               arch.rope_scaling)                              # one shared head
+    grp = next(n for n in range(min(h, HEADS_A_CALL), 0, -1) if h % n == 0)
+
+    def heads(ws):                    # the heads these columns make
+        q = _mm(c_q, ws[0]).reshape(b, s, grp, dn + dr)
+        kv = _mm(c_kv, ws[1]).reshape(b, s, grp, dn + dv)
+        q_r = rope(q[..., dn:], arch.rope_theta, arch.rope_scaling)
+        q = jnp.concatenate([q[..., :dn], q_r], -1) * softmax_scale(arch)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (b, s, grp, dr))], -1)
+        return q, k, kv[..., dn:]
+
+    by_group = lambda m: m.reshape(  # noqa: E731
+        m.shape[0], h // grp, -1).swapaxes(0, 1)
+    with jax.named_scope(obs_scopes.DSA_ATTEND):
+        o = selected_attention(heads, (by_group(w["q_b"]), by_group(w["kv_b"])),
+                               picked, arch.q_block)
+    return (_mm(o.reshape(b, s, h * dv), w["o"]),
+            jnp.sum(picked, dtype=jnp.int32))
 
 
 def glu(w, x):
@@ -226,15 +506,45 @@ def glu(w, x):
     return _mm(jax.nn.silu(gu[..., :f]) * gu[..., f:], w["down"])
 
 
+STREAM_BYTES = 2 ** 27   # a float32 [tokens, hidden] array above this is large
+GLU_BYTES = 2 ** 29   # the most a float32 [tokens, gate and up] array may hold
+
+
+def glu_by_parts(w, x):
+    """`glu` over x [B, S, D] with its tokens in as few equal parts (a power
+    of two) as keep the float32 gate-and-up array under `GLU_BYTES`, each
+    part made again for the gradient; one part is `glu` itself."""
+    b, s, d = x.shape
+    parts = 1
+    while (b * s * w["gate_up"].shape[-1] * 4 > parts * GLU_BYTES
+           and (b * s) % (2 * parts) == 0):
+        parts *= 2
+    if parts == 1:
+        return glu(w, x)
+    return jax.lax.map(jax.checkpoint(lambda part: glu(w, part)),
+                       x.reshape(parts, -1, d)).reshape(b, s, d)
+
+
 def route(arch: LMArch, router, bias, x):
     """The published router, float32: s = sigmoid(W_r x) over all experts,
     the `experts_per_tok` largest of s + bias, weights routed_scaling * s_k
-    / sum of the selected s. x: [T, D] -> (experts int32[T, k], weights
-    f32[T, k])."""
+    / sum of the selected s. With `n_group` groups of experts the choice is
+    group-limited: a group scores the sum of its two largest s + bias, and
+    only the experts of the `topk_group` best groups can be chosen. x: [T,
+    D] -> (experts int32[T, k], weights f32[T, k])."""
     with jax.named_scope(obs_scopes.MOE_ROUTE):
         s = jax.nn.sigmoid(jnp.dot(x.astype(F32), router.T, precision=HIGHEST,
                                    preferred_element_type=F32))
-        _, idx = jax.lax.top_k(s + bias, arch.experts_per_tok)
+        choice = s + bias
+        if arch.n_group > 1:
+            t, n = choice.shape
+            groups = choice.reshape(t, arch.n_group, n // arch.n_group)
+            best = jnp.sum(jax.lax.top_k(groups, 2)[0], -1)
+            _, keep = jax.lax.top_k(best, arch.topk_group)
+            kept = jnp.zeros((t, arch.n_group), bool).at[
+                jnp.arange(t)[:, None], keep].set(True)
+            choice = jnp.where(kept[:, :, None], groups, -jnp.inf).reshape(t, n)
+        _, idx = jax.lax.top_k(choice, arch.experts_per_tok)
         w = jnp.take_along_axis(s, idx, axis=-1)
         w = arch.routed_scaling * w / jnp.sum(w, -1, keepdims=True)
         return idx.astype(jnp.int32), w
@@ -304,11 +614,24 @@ def _grouped_bwd(res, dy):
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+def pair_blocks(arch: LMArch, pairs: int) -> tuple[int, int]:
+    """(blocks, rows a block) the held experts' sorted pairs are computed
+    in. A chip that holds half of the layer's experts or more takes every
+    pair in one grouped product; one that holds fewer takes the held pairs,
+    which sort first, in blocks of `pair_block` rows, as many as hold them."""
+    if 2 * arch.held_experts >= arch.n_experts or pairs <= arch.pair_block:
+        return 1, pairs
+    return -(-pairs // arch.pair_block), arch.pair_block
+
+
 def held_experts(arch: LMArch, w, x, idx, weights):
     """The held experts' part of the layer: sum over the selected experts
     that live here of weight * E(x). Every (token, held expert) pair is
-    computed, sorted by expert into one grouped matrix product; pairs of
-    absent experts sort behind the last group and add nothing.
+    computed, sorted by expert into a grouped matrix product; pairs of
+    absent experts sort behind the last group and add nothing. Where the
+    chip holds few of the experts (`pair_blocks`) the product runs over the
+    held pairs' blocks alone, so the layer costs what its held pairs cost:
+    still every pair, whatever the imbalance.
     -> (y f32[T, D], load int32[held]: pairs a held expert computed)."""
     with jax.named_scope(obs_scopes.MOE_EXPERTS):
         t, k = idx.shape
@@ -318,6 +641,12 @@ def held_experts(arch: LMArch, w, x, idx, weights):
         key = jnp.where(here, local, held).reshape(t * k)
         order = jnp.argsort(key, stable=True)
         load = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        blocks, rows = pair_blocks(arch, t * k)
+        if blocks > 1:
+            # (padded to whole blocks: a pair behind the held ones, of token 0)
+            return _held_blocks(
+                w, x.astype(F32), jnp.pad(order, (0, blocks * rows - t * k)),
+                load, jnp.where(here, weights, 0.0).reshape(t * k), k, rows), load
         xs = x.astype(BF16)[order // k]                       # [T*k, D]
         with jax.named_scope(obs_scopes.MOE_GMM):
             gu = grouped_matmul(xs, w["gate_up"], load)
@@ -330,6 +659,69 @@ def held_experts(arch: LMArch, w, x, idx, weights):
         back = jnp.argsort(order)                              # the inverse
         y = (ys[back] * pair_w[:, None]).reshape(t, k, -1).sum(1)
         return y, load
+
+
+def _pair_block(w, x, order, load, pair_w, k: int, rows: int, i):
+    """Block i of the sorted pairs: (their tokens, their places in `pair_w`,
+    and the function (the tokens' rows of x, the pairs' weights) -> the
+    pairs' weighted outputs f32[rows, D]). Its groups are the parts of the
+    experts' runs that fall inside it; rows behind the last held pair read
+    0."""
+    lo = i * rows
+    mine = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    ends = jnp.cumsum(load)
+    sizes = (jnp.clip(ends, lo, lo + rows)
+             - jnp.clip(ends - load, lo, lo + rows)).astype(jnp.int32)
+
+    def outputs(xs, ws):
+        with jax.named_scope(obs_scopes.MOE_GMM):
+            gu = grouped_matmul(xs.astype(BF16), w["gate_up"], sizes)
+        f = gu.shape[-1] // 2
+        hid = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(BF16)
+        with jax.named_scope(obs_scopes.MOE_GMM):
+            return grouped_matmul(hid, w["down"], sizes) * ws[:, None]
+
+    return mine // k, mine, outputs
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_blocks(w, x, order, load, pair_w, k: int, rows: int):
+    """`held_experts` a block of `rows` sorted pairs at a time (`order`
+    padded to whole blocks), for as many blocks as hold a held pair (a loop
+    whose trip count is data: the blocks behind the last held pair are never
+    entered). Its gradient, with respect to x and the pairs' weights, walks
+    the same blocks and makes each one's rows again. -> y f32[T, D]."""
+
+    def add(i, y):
+        tokens, mine, outputs = _pair_block(w, x, order, load, pair_w, k, rows, i)
+        return y.at[tokens].add(outputs(x[tokens], pair_w[mine]))
+
+    y0 = jnp.zeros((x.shape[0], w["down"].shape[-1]), F32)
+    return jax.lax.fori_loop(0, -(-jnp.sum(load) // rows), add, y0)
+
+
+def _held_blocks_fwd(w, x, order, load, pair_w, k, rows):
+    return (_held_blocks(w, x, order, load, pair_w, k, rows),
+            (w, x, order, load, pair_w))
+
+
+def _held_blocks_bwd(k, rows, res, dy):
+    w, x, order, load, pair_w = res
+
+    def back(i, carry):
+        dx, dw = carry
+        tokens, mine, outputs = _pair_block(w, x, order, load, pair_w, k, rows, i)
+        _, vjp = jax.vjp(outputs, x[tokens], pair_w[mine])
+        dxs, dws = vjp(dy[tokens])
+        return dx.at[tokens].add(dxs), dw.at[mine].add(dws)
+
+    dx, dw = jax.lax.fori_loop(
+        0, -(-jnp.sum(load) // rows), back,
+        (jnp.zeros_like(x), jnp.zeros_like(pair_w)))
+    return None, dx, None, None, dw
+
+
+_held_blocks.defvjp(_held_blocks_fwd, _held_blocks_bwd)
 
 
 def expert_layer(arch: LMArch, w, router, x):
@@ -345,13 +737,15 @@ def expert_layer(arch: LMArch, w, router, x):
 def block(arch: LMArch, w, g, h):
     """One transformer block on the float32 residual stream h. A block with
     `experts` among its frozen matrices is an expert block (its trained
-    leaves then hold the router). -> (h, (load, selections) or None)."""
-    h = h + latent_attention(arch, w["attn"], g, rms_norm(h, g["ln_attn"], arch.eps))
+    leaves then hold the router). -> (h, (load, selections) or None, the
+    pairs its indexer picked or None)."""
+    a, count = _attend(arch, w["attn"], g, rms_norm(h, g["ln_attn"], arch.eps))
+    h = h + a
     x = rms_norm(h, g["ln_mlp"], arch.eps)
     if "experts" in w:
         y, load, idx = expert_layer(arch, w, g["router"], x)
-        return h + y, (load, idx)
-    return h + glu(w["mlp"], x), None
+        return h + y, (load, idx), count
+    return h + glu_by_parts(w["mlp"], x), None, count
 
 
 # --------------------------------------------------------------------------
@@ -368,6 +762,10 @@ def _leaf_shapes(arch: LMArch, vocab: int):
             "kv_a": (d, arch.kv_lora_rank + dr),
             "kv_b": (arch.kv_lora_rank, h * (dn + dv)),
             "o": (h * dv, d)}
+    if arch.index_topk:
+        hi, di = arch.index_heads, arch.index_head_dim
+        attn["index"] = {"q": (arch.q_lora_rank, hi * di), "k": (d, di),
+                         "k_gain": (di,), "k_bias": (di,), "w": (d, hi)}
     gains = {"ln_attn": (d,), "ln_mlp": (d,), "q_norm": (arch.q_lora_rank,),
              "kv_norm": (arch.kv_lora_rank,)}
     f, e = arch.moe_intermediate, arch.held_experts
@@ -392,11 +790,12 @@ _is_shape = lambda t: isinstance(t, tuple)  # noqa: E731
 
 
 @dataclasses.dataclass(frozen=True)
-class JoyAIFlash:
-    """The model as `fl/` sees it: hashable, `apply({"params": trained,
-    "base": base}, tokens)`. `num_classes` is the vocabulary held here;
-    `seed` is the base's (two modules of different seeds have different
-    bases and are different keys of every cache)."""
+class FrozenBaseLM:
+    """A model of this file as `fl/` sees it, whichever `arch` it has:
+    hashable, `apply({"params": trained, "base": base}, tokens)`.
+    `num_classes` is the vocabulary held here; `seed` is the base's (two
+    modules of different seeds have different bases and are different keys
+    of every cache)."""
 
     num_classes: int
     arch: LMArch = LMArch()
@@ -421,11 +820,13 @@ class JoyAIFlash:
         bfloat16 (the router's bias buffer in float32): normal(std)."""
         key = jax.random.key(self.seed) if key is None else key
         shapes = _leaf_shapes(self.arch, self.num_classes)[0]
-        leaves, tree = jax.tree_util.tree_flatten(shapes, is_leaf=_is_shape)
-        out = [_normal_leaf(jax.random.fold_in(key, 1000 + i),
-                            self.arch.init_std, shape=s,
-                            dtype="float32" if len(s) == 1 else "bfloat16")
-               for i, s in enumerate(leaves)]
+        leaves, tree = jax.tree_util.tree_flatten_with_path(
+            shapes, is_leaf=_is_shape)
+        out = [jnp.ones(s, F32) if "k_gain" in jax.tree_util.keystr(path)
+               else _normal_leaf(jax.random.fold_in(key, 1000 + i),
+                                 self.arch.init_std, shape=s,
+                                 dtype="float32" if len(s) == 1 else "bfloat16")
+               for i, (path, s) in enumerate(leaves)]   # (LayerNorm's gain: 1)
         return jax.tree_util.tree_unflatten(tree, out)
 
     def bind(self, base):
@@ -433,43 +834,55 @@ class JoyAIFlash:
 
     # ---- forward -------------------------------------------------------------
 
-    def hidden(self, variables, tokens):
-        """tokens int[B, S + 2] -> (h_main, h_mtp, routed): the two heads'
-        normed inputs, float32 [B, S, D], and of every expert layer (the
+    def hidden(self, variables, tokens, normed: bool = True):
+        """tokens int[B, S + 2] -> (h_main, h_mtp, routed, picked): the two
+        heads' normed inputs, float32 [B, S, D]; of every expert layer (the
         prediction module's last) the load int32[layers, held] and the
-        selections int32[layers, T, k]."""
+        selections int32[layers, T, k]; and of every attention layer the
+        (query, key) pairs its indexer picked, int32[layers], or None for
+        a model without one. Without `normed` the two heads' inputs come
+        before their last norms and the prediction module's input is made
+        again for the gradient (`loss`, where a [tokens, hidden] array is
+        large)."""
         arch, p, base = self.arch, variables["params"], variables["base"]
         s = tokens.shape[1] - 2
         emb = lambda t: base["embed"][t].astype(F32)  # noqa: E731
         blk = jax.checkpoint(
-            lambda w, g, h: block(arch, w, g, h),
-            policy=jax.checkpoint_policies.save_only_these_names(ATTN_SAVED))
-        h, routed = emb(tokens[:, :s]), []
+            lambda w, g, h: block(arch, w, g, h), policy=_kept(arch))
+        h, routed, picked = emb(tokens[:, :s]), [], []
         for w, g in zip(base["blocks"], p["blocks"]):
-            h, seen = blk(w, g, h)
+            h, seen, count = blk(w, g, h)
+            picked.append(count)
             if seen is not None:
                 routed.append(seen)
-        h_main = rms_norm(h, p["final_norm"], arch.eps)
+        h_main = rms_norm(h, p["final_norm"], arch.eps) if normed else h
         with jax.named_scope(obs_scopes.MTP):
             m, mb = p["mtp"], base["mtp"]
-            both = jnp.concatenate(
+            joined = lambda h, m: _mm(jnp.concatenate(  # noqa: E731
                 [rms_norm(h, m["hnorm"], arch.eps),
-                 rms_norm(emb(tokens[:, 1:s + 1]), m["enorm"], arch.eps)], -1)
-            h2, seen = blk(mb["block"], m["block"], _mm(both, mb["eh"]))
-            h_mtp = rms_norm(h2, m["norm"], arch.eps)
+                 rms_norm(emb(tokens[:, 1:s + 1]), m["enorm"], arch.eps)], -1),
+                mb["eh"])
+            h2, seen, count = blk(
+                mb["block"], m["block"],
+                joined(h, m) if normed else jax.checkpoint(joined)(h, m))
+            h_mtp = rms_norm(h2, m["norm"], arch.eps) if normed else h2
         routed.append(seen)
-        # every block's attention is the fused kernel: `causal_attention`
-        # has one path
+        picked.append(count)
+        # every block's attention is the fused kernel, causal or over the
+        # indexer's selection
         obs_metrics.gauge("model.fused_attention_layers").set(
             len(base["blocks"]) + 1)
-        return h_main, h_mtp, (jnp.stack([r[0] for r in routed]),
-                               jnp.stack([r[1] for r in routed]))
+        obs_metrics.gauge("model.sparse_attention_layers").set(
+            len(picked) if arch.index_topk else 0)
+        return (h_main, h_mtp, (jnp.stack([r[0] for r in routed]),
+                                jnp.stack([r[1] for r in routed])),
+                jnp.stack(picked) if arch.index_topk else None)
 
     def apply(self, variables, tokens, routed: bool = False):
         """-> (logits of the main head, of the prediction module), float32
         [B, S, vocab]: position i predicts token i + 1 and token i + 2.
         With `routed` also `hidden`'s (loads, selections)."""
-        h_main, h_mtp, seen = self.hidden(variables, tokens)
+        h_main, h_mtp, seen, _ = self.hidden(variables, tokens)
         with jax.named_scope(obs_scopes.LM_HEAD):
             head = variables["base"]["head"]
             out = _mm(h_main, head), _mm(h_mtp, head)
@@ -479,23 +892,64 @@ class JoyAIFlash:
         """-> (CE_main + mtp_weight * CE_mtp, (CE_main, next-token accuracy,
         loads)), means over every position of every sequence. The logits are
         made a slice of `loss_chunk` tokens at a time and made again for the
-        gradient: no [tokens, vocab] array outlives its slice."""
-        h_main, h_mtp, (loads, _) = self.hidden(variables, tokens)
+        gradient: no [tokens, vocab] array outlives its slice. A model with
+        an indexer appends four columns to `loads` (`COUNTED`)."""
+        arch, p = self.arch, variables["params"]
         s = tokens.shape[1] - 2
+        # a float32 [tokens, hidden] array over `STREAM_BYTES` is not kept
+        # for the gradient where the layer before can make it again: the
+        # heads' last norms then run inside the head's own checkpoint
+        lean = tokens.shape[0] * s * arch.hidden * 4 > STREAM_BYTES
+        h_main, h_mtp, (loads, _), picked = self.hidden(variables, tokens,
+                                                        normed=not lean)
+        if picked is not None:
+            loads = _with_counts(arch, loads, picked, *tokens.shape)
         head = variables["base"]["head"]
-        ce, acc = _head_ce(h_main, head, tokens[:, 1:s + 1], self.arch.loss_chunk)
-        ce2, _ = _head_ce(h_mtp, head, tokens[:, 2:s + 2], self.arch.loss_chunk)
-        return ce + self.arch.mtp_weight * ce2, (ce, acc, loads)
+        if lean:
+            normed_ce = jax.checkpoint(lambda h, gain, t: _head_ce(
+                rms_norm(h, gain, arch.eps), head, t, arch.loss_chunk))
+            ce, acc = normed_ce(h_main, p["final_norm"], tokens[:, 1:s + 1])
+            ce2, _ = normed_ce(h_mtp, p["mtp"]["norm"], tokens[:, 2:s + 2])
+        else:
+            ce, acc = _head_ce(h_main, head, tokens[:, 1:s + 1], arch.loss_chunk)
+            ce2, _ = _head_ce(h_mtp, head, tokens[:, 2:s + 2], arch.loss_chunk)
+        return ce + arch.mtp_weight * ce2, (ce, acc, loads)
+
+
+JoyAIFlash = FrozenBaseLM   # the name the first model of this file came under
+
+
+COUNTED = 4   # columns a model with an indexer appends to `loss`'s loads
+
+
+def _with_counts(arch: LMArch, loads, picked, sequences: int, length: int):
+    """loads int32[layers, held] -> [layers, held + COUNTED]: a marker (-1,
+    so that a sum over sequences stays negative), the rows the layer's
+    grouped product was given, the (query, key) pairs its block's indexer
+    picked and the causal pairs they were picked from (an expert layer its
+    own block's; the dense layers' go to row 0). What `record_expert_load`
+    turns into gauges."""
+    s, n = length - 2, loads.shape[0]
+    blocks, rows = pair_blocks(arch, sequences * s * arch.experts_per_tok)
+    held = jnp.sum(loads, -1)
+    given = rows * jnp.clip(-(-held // rows), 0 if blocks > 1 else 1, blocks)
+    front = picked.shape[0] - n                      # the dense layers
+    mine = picked[front:].at[0].add(jnp.sum(picked[:front]))
+    causal = jnp.full((n,), sequences * s * (s + 1) // 2, jnp.int32).at[0].mul(
+        front + 1)
+    return jnp.concatenate(
+        [loads, jnp.stack([jnp.full((n,), -1, jnp.int32), given, mine, causal],
+                          -1)], -1)
 
 
 class BoundLM:
-    """A `JoyAIFlash` with its base: what the client code holds inside a
+    """A `FrozenBaseLM` with its base: what the client code holds inside a
     round program, where the base is the program's argument. Same `apply`
     and `loss`, over `{"params": trained}`."""
 
     token_model = True
 
-    def __init__(self, module: JoyAIFlash, base):
+    def __init__(self, module: FrozenBaseLM, base):
         self.module, self.base = module, base
 
     def apply(self, variables, tokens, routed: bool = False):
@@ -572,10 +1026,21 @@ def set_frozen_base(module, base) -> None:
 
 def record_expert_load(loads) -> None:
     """Gauge `moe.load_max_over_mean`: the busiest held expert's pairs over
-    the mean, worst layer, of an evaluation forward."""
+    the mean, worst layer, of an evaluation forward. From the columns a
+    model with an indexer appends (`_with_counts`; the marker is negative)
+    also `moe.rows_over_held_pairs` (rows given to the grouped product over
+    pairs held, worst layer) and `dsa.selected_share` (picked over causal
+    (query, key) pairs, percent)."""
     import numpy as np
 
     loads = np.asarray(loads, np.float64)
+    if loads.shape[-1] > COUNTED and loads[0, -COUNTED] < 0:
+        given, picked, causal = loads[:, -COUNTED + 1:].T
+        loads = loads[:, :-COUNTED]
+        obs_metrics.gauge("moe.rows_over_held_pairs").set(
+            float(np.max(given / np.maximum(loads.sum(-1), 1.0))))
+        obs_metrics.gauge("dsa.selected_share").set(
+            100.0 * float(picked.sum() / causal.sum()))
     mean = np.maximum(loads.mean(-1), 1e-9)
     obs_metrics.gauge("moe.load_max_over_mean").set(
         float(np.max(loads.max(-1) / mean)))

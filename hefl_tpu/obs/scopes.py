@@ -47,6 +47,8 @@ MLA = "hefl.mla"                      # latent attention: projections, RoPE, sof
 MOE_ROUTE = "hefl.moe.route"          # sigmoid router, top-k, weights
 MOE_EXPERTS = "hefl.moe.experts"      # sort + grouped product of the held experts
 MOE_GMM = "hefl.moe_gmm"              # the grouped product's Pallas calls alone
+DSA_INDEX = "hefl.dsa.index"          # the indexer's scores and its selection
+DSA_ATTEND = "hefl.dsa.attend"        # attention over the selected keys alone
 MTP = "hefl.mtp"                      # the multi-token-prediction module
 LM_HEAD = "hefl.lm_head"              # head logits + cross-entropy, by slices
 
@@ -79,6 +81,8 @@ PHASES = (
     MOE_ROUTE,
     MOE_EXPERTS,
     MOE_GMM,
+    DSA_INDEX,
+    DSA_ATTEND,
     MTP,
     LM_HEAD,
 )

@@ -1,8 +1,9 @@
-"""The token model with a frozen base (hefl_tpu/models/lm.py) against its
-plain reference (benchmarks/reference/joyai_llm_flash.py) on seeded weights
-at a small size, the share of a stated deployment tied to the uncut layer,
-routing under a planted imbalance, token data, and the encrypted round of the
-trained subset alone. No device or topology call at import time."""
+"""The token models with a frozen base (hefl_tpu/models/lm.py) against their
+plain references (benchmarks/reference/joyai_llm_flash.py, deepseek_v32.py)
+on seeded weights at a small size, the share of a stated deployment tied to
+the uncut layer, routing under a planted imbalance, the indexer's selection
+and attention over it, token data, and the encrypted round of the trained
+subset alone. No device or topology call at import time."""
 
 from __future__ import annotations
 
@@ -10,6 +11,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -29,21 +31,20 @@ VOCAB = 50
 
 
 def _load(path):
-    spec = importlib.util.spec_from_file_location("_ref_joyai", path)
+    name = "_ref_" + os.path.basename(path)[:-3]
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
-@pytest.fixture(scope="module")
-def ref():
-    return _load(os.path.join(ROOT, "benchmarks", "reference",
-                              "joyai_llm_flash.py"))
+REFERENCES = {"joyai_llm_flash_tiny": "joyai_llm_flash.py",
+              "deepseek_v32_tiny": "deepseek_v32.py"}
 
 
 def _conf(arch: lm.LMArch, vocab: int = VOCAB) -> dict:
     """The reference's configuration keys for a system preset."""
-    return dict(
+    conf = dict(
         hidden_size=arch.hidden, num_attention_heads=arch.heads,
         q_lora_rank=arch.q_lora_rank, kv_lora_rank=arch.kv_lora_rank,
         qk_nope_head_dim=arch.qk_nope_head_dim,
@@ -58,22 +59,57 @@ def _conf(arch: lm.LMArch, vocab: int = VOCAB) -> dict:
         routed_scaling_factor=arch.routed_scaling,
         held=dict(router_width=arch.n_experts, first_expert=arch.held_start,
                   mtp_loss_weight=arch.mtp_weight, init_std=arch.init_std))
+    if arch.index_topk:
+        factor, positions, fast, slow, all_dim = arch.rope_scaling
+        conf.update(
+            n_group=arch.n_group, topk_group=arch.topk_group,
+            index_n_heads=arch.index_heads, index_head_dim=arch.index_head_dim,
+            index_topk=arch.index_topk,
+            rope_scaling=dict(
+                beta_fast=fast, beta_slow=slow, factor=factor, mscale=1,
+                mscale_all_dim=all_dim, type="yarn",
+                original_max_position_embeddings=positions))
+    return conf
 
 
 TINY = lm.PRESETS["joyai_llm_flash_tiny"]
+SPARSE = lm.PRESETS["deepseek_v32_tiny"]
+POSITIONS = {"joyai_llm_flash_tiny": 24,
+             "deepseek_v32_tiny": 40}      # five times the tiny indexer's top 8
 
 
-@pytest.fixture(scope="module")
-def seeded(ref):
-    """The reference's seeded weights, the gains moved off 1 so they count."""
-    conf = _conf(TINY)
+@pytest.fixture(scope="module", params=sorted(REFERENCES))
+def case(request):
+    """A tiny model, its reference, and the reference's seeded weights with
+    the gains moved off 1 so they count."""
+    arch = lm.PRESETS[request.param]
+    ref = _load(os.path.join(ROOT, "benchmarks", "reference",
+                             REFERENCES[request.param]))
+    conf = _conf(arch)
     v = ref.init(3, conf)
     p = jax.tree_util.tree_map(
         lambda a: a * (1 + 0.1 * jax.random.normal(jax.random.key(a.size),
                                                    a.shape))
         if a.ndim == 1 else a, v["params"])
-    tokens = jax.random.randint(jax.random.key(0), (2, 26), 0, VOCAB)
-    return conf, {"base": v["base"], "params": p}, tokens
+    positions = POSITIONS[request.param]
+    tokens = jax.random.randint(jax.random.key(0), (2, positions + 2), 0, VOCAB)
+    # both references' layers under one signature: (z, w, g, x) -> array
+    z = ref._sizes(conf)
+    if arch.index_topk:
+        mm = ref._Products(None)
+        attention = lambda w, g, x: ref._attention(  # noqa: E731
+            z, w, g, x, mm, yarn=z["yarn"])[0]
+        blk = lambda w, g, h: ref._block(  # noqa: E731
+            z, w, g, h, mm, None, None, None, {"yarn": z["yarn"]}, True)
+    else:
+        mm = lambda a, b: a @ b.astype(jnp.float32)  # noqa: E731
+        attention = lambda w, g, x: ref._attention(z, w, g, x, mm)  # noqa: E731
+        blk = lambda w, g, h: ref._block(z, w, g, h, mm, None, None, None)  # noqa: E731
+    return types.SimpleNamespace(
+        name=request.param, arch=arch, ref=ref, conf=conf, z=z, mm=mm,
+        positions=positions,
+        variables={"base": v["base"], "params": p}, tokens=tokens,
+        attention=attention, block=blk)
 
 
 def _highest(fn, *a, **kw):
@@ -81,19 +117,30 @@ def _highest(fn, *a, **kw):
         return fn(*a, **kw)
 
 
-def test_latent_attention_block_matches_reference(ref, seeded):
-    conf, v, _ = seeded
+def test_latent_attention_block_matches_reference(case):
+    v, arch = case.variables, case.arch
     w, g = v["base"]["blocks"][0]["attn"], v["params"]["blocks"][0]
-    x = jax.random.normal(jax.random.key(1), (2, 24, TINY.hidden), jnp.float32)
-    mm = lambda a, b: a @ b.astype(jnp.float32)  # noqa: E731
-    want = _highest(ref._attention, ref._sizes(conf), w, g, x, mm)
-    got = lm.latent_attention(TINY, w, g, x)
-    assert got.shape == want.shape == (2, 24, TINY.hidden)
+    x = jax.random.normal(jax.random.key(1), (2, case.positions, arch.hidden),
+                          jnp.float32)
+    want = _highest(case.attention, w, g, x)
+    got = lm.latent_attention(arch, w, g, x)
+    assert got.shape == want.shape == (2, case.positions, arch.hidden)
+    same = jnp.ones((2, case.positions), bool)
+    if arch.index_topk:
+        # compared where both indexers select the same 8 keys (bfloat16
+        # products against float32 rank a pair at the edge differently)
+        c_q = lm.rms_norm(lm._mm(x, w["q_a"]), g["q_norm"], arch.eps)
+        ours = lm.select_keys(arch, w["index"], x, c_q)
+        theirs = _highest(case.ref._attention, case.z, w, g, x, case.mm,
+                          yarn=case.z["yarn"])[1]
+        same = jnp.all(ours == theirs, -1)
+        assert float(jnp.mean(same)) > 0.9
     # bfloat16 operands against float32: a few parts in a thousand
-    assert float(jnp.max(jnp.abs(got - want))) < 0.02 * float(jnp.std(want)) + 1e-4
+    assert float(jnp.max(jnp.where(same[..., None], jnp.abs(got - want), 0))
+                 ) < 0.02 * float(jnp.std(want)) + 1e-4
     # causal: a later token does not move an earlier position
     x2 = x.at[:, -1].add(1.0)
-    assert jnp.array_equal(lm.latent_attention(TINY, w, g, x2)[:, :-1],
+    assert jnp.array_equal(lm.latent_attention(arch, w, g, x2)[:, :-1],
                            got[:, :-1])
 
 
@@ -153,37 +200,47 @@ def test_padded_positions_move_no_output_and_receive_no_gradient():
     assert lm._attention_kernel.cache_info().misses == before
 
 
-def test_gauge_counts_the_attention_layers_that_took_the_kernel(seeded):
+def test_gauge_counts_the_attention_layers_that_took_the_kernel(case):
     from hefl_tpu.obs import metrics as obs_metrics
 
-    _, v, tokens = seeded
+    v, tokens, arch = case.variables, case.tokens, case.arch
     gauge = obs_metrics.gauge("model.fused_attention_layers")
     gauge.set(0)
-    module = lm.JoyAIFlash(num_classes=VOCAB, arch=TINY, seed=3)
+    module = lm.JoyAIFlash(num_classes=VOCAB, arch=arch, seed=3)
     jax.eval_shape(lambda p: module.apply({"base": v["base"], "params": p},
                                           tokens), v["params"])
     # 1 dense + 2 expert layers + the prediction module
-    assert gauge.value == TINY.dense_layers + TINY.expert_layers + 1 == 4
+    assert gauge.value == arch.dense_layers + arch.expert_layers + 1 == 4
+    # ... all of them over an indexer's selection, where the model has one
+    assert obs_metrics.gauge("model.sparse_attention_layers").value == (
+        4 if arch.index_topk else 0)
     create_model("smallcnn", num_classes=2, input_shape=(16, 16, 3))
     assert obs_metrics.snapshot()["model.fused_attention_layers"] == 0
 
 
-def test_expert_block_matches_reference(ref, seeded):
-    conf, v, _ = seeded
+def test_expert_block_matches_reference(case):
+    v, arch = case.variables, case.arch
     w, g = v["base"]["blocks"][1], v["params"]["blocks"][1]
-    h = jax.random.normal(jax.random.key(2), (2, 24, TINY.hidden), jnp.float32)
-    mm = lambda a, b: a @ b.astype(jnp.float32)  # noqa: E731
-    want, aux = _highest(ref._block, ref._sizes(conf), w, g, h, mm, None, None,
-                         None)
-    got, (load, idx) = lm.block(TINY, w, g, h)
-    assert jnp.array_equal(idx, aux["experts"])      # the float32 router agrees
-    assert np.asarray(load).tolist() == np.asarray(aux["loads"]).tolist()
-    assert float(jnp.max(jnp.abs(got - want))) < 0.02 * float(jnp.std(want))
+    h = jax.random.normal(jax.random.key(2), (2, case.positions, arch.hidden),
+                          jnp.float32)
+    want, aux = _highest(case.block, w, g, h)
+    got, (load, idx), picked = lm.block(arch, w, g, h)
+    # the float32 router agrees (but for a token whose attention read a key
+    # the two indexers rank differently)
+    assert float(jnp.mean((idx == aux["experts"]).astype(jnp.float32))) >= (
+        0.97 if arch.index_topk else 1.0)
+    if not arch.index_topk:
+        assert np.asarray(load).tolist() == np.asarray(aux["loads"]).tolist()
+        assert picked is None
+    else:
+        assert int(picked) == 2 * case.ref.selected_pairs(case.positions,
+                                                          arch.index_topk)
+    assert float(jnp.max(jnp.abs(got - want))) < 0.03 * float(jnp.std(want))
 
 
-def test_whole_model_logits_loss_and_gradients_match_reference(ref, seeded):
-    conf, v, tokens = seeded
-    module = lm.JoyAIFlash(num_classes=VOCAB, arch=TINY, seed=3)
+def test_whole_model_logits_loss_and_gradients_match_reference(case):
+    ref, conf, v, tokens = case.ref, case.conf, case.variables, case.tokens
+    module = lm.FrozenBaseLM(num_classes=VOCAB, arch=case.arch, seed=3)
     (l_ref, (z1, z2, aux)), g_ref = _highest(jax.value_and_grad(
         lambda q: ref.loss({"base": v["base"], "params": q}, tokens, conf),
         has_aux=True), v["params"])
@@ -191,9 +248,11 @@ def test_whole_model_logits_loss_and_gradients_match_reference(ref, seeded):
     (l_sys, (ce, acc, _)), g_sys = jax.value_and_grad(
         lambda q: module.loss({"base": v["base"], "params": q}, tokens),
         has_aux=True)(v["params"])
-    assert s1.shape == s2.shape == (2, 24, VOCAB)
+    assert s1.shape == s2.shape == (2, case.positions, VOCAB)
     scale = float(jnp.std(z1))
-    assert float(jnp.max(jnp.abs(s1 - z1))) < 0.05 * scale
+    assert float(jnp.max(jnp.abs(s1 - z1))) < (
+        0.3 if case.arch.index_topk else 0.05) * scale
+    assert float(jnp.max(jnp.abs(s2 - z2))) < 0.3 * float(jnp.std(z2))
     assert float(jnp.mean((sel == aux["experts"]).astype(jnp.float32))) > 0.98
     assert abs(float(l_sys) - float(l_ref)) < 1e-4 * float(l_ref)
     assert 0.0 <= float(acc) <= 1.0 and float(ce) < float(l_sys)
@@ -204,54 +263,294 @@ def test_whole_model_logits_loss_and_gradients_match_reference(ref, seeded):
     assert len(flat_s) == len(flat_r) == 3 * 4 + 2 + 1 + 3 + 5
     for (path, a), b in zip(flat_s, flat_r):
         gap = float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-12))
-        limit = 0.4 if "mtp" in jax.tree_util.keystr(path) else 0.02
+        limit = 0.4 if "mtp" in jax.tree_util.keystr(path) else (
+            0.1 if case.arch.index_topk else 0.02)
         assert gap < limit, (jax.tree_util.keystr(path), gap)
+    if case.arch.index_topk:
+        # the selections: each layer's, by the system's indexer on what the
+        # reference's indexer saw, are the reference's but for pairs at the
+        # edge (bfloat16 products against float32)
+        xs, c_qs = _highest(
+            lambda: ref.forward(v, tokens, conf, keep_inputs=True))[2]["index_in"]
+        layers = v["base"]["blocks"] + [v["base"]["mtp"]["block"]]
+        for k, layer in enumerate(layers):
+            got = lm.select_keys(case.arch, layer["attn"]["index"], xs[k], c_qs[k])
+            want = aux["picked"][k]
+            assert int(jnp.sum(got)) == int(jnp.sum(want))
+            assert float(jnp.sum(got & want) / jnp.sum(want)) > 0.98
 
 
-def test_two_shares_and_the_shared_expert_add_up_to_the_uncut_layer(ref, seeded):
-    """At 8 experts in shares of 4: the two shares' routed parts plus the
-    shared expert counted once are the uncut reference's layer."""
-    whole = dataclasses.replace(TINY, held_start=0, held_experts=8)
-    conf = _conf(whole)
-    z = ref._sizes(conf)
-    w = ref.init(5, conf)["base"]["blocks"][1]
-    router = 0.5 * jax.random.normal(jax.random.key(9), (8, TINY.hidden))
-    x = jax.random.normal(jax.random.key(4), (40, TINY.hidden), jnp.float32)
-    mm = lambda a, b: a @ b.astype(jnp.float32)  # noqa: E731
+def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer(case):
+    """The share test: the routed parts that every share gives (each
+    `held_start` in turn: 8 experts in two shares of 4, or 16 experts under
+    group-limited routing in four), with the shared expert counted once, are
+    the uncut reference's layer."""
+    arch, ref = case.arch, case.ref
+    n, held = arch.n_experts, arch.held_experts
+    whole = dataclasses.replace(arch, held_start=0, held_experts=n)
+    z = ref._sizes(_conf(whole))
+    w = ref.init(5, _conf(whole))["base"]["blocks"][1]
+    router = 0.5 * jax.random.normal(jax.random.key(9), (n, arch.hidden))
+    x = jax.random.normal(jax.random.key(4), (40, arch.hidden), jnp.float32)
     idx_r, w_r = _highest(ref._route, z, router, w["bias"], x, None)
-    routed_ref, _ = _highest(ref._experts, z, w["experts"], x, idx_r, w_r, mm,
-                             None, None)
-    uncut = routed_ref + _highest(ref._glu, w["shared"], x, mm)
+    routed_ref, _ = _highest(ref._experts, z, w["experts"], x, idx_r, w_r,
+                             case.mm, None, None)
+    uncut = routed_ref + _highest(ref._glu, w["shared"], x, case.mm)
     idx, weights = lm.route(whole, router, w["bias"], x)
     assert jnp.array_equal(idx, idx_r)
     parts, pairs = [], 0
-    for start in (0, 4):
-        share = dataclasses.replace(TINY, held_start=start, held_experts=4)
-        mine = {k: v[start:start + 4] for k, v in w["experts"].items()}
+    for start in range(0, n, held):
+        share = dataclasses.replace(arch, held_start=start)
+        mine = {k: v[start:start + held] for k, v in w["experts"].items()}
         y, load = lm.held_experts(share, mine, x, idx, weights)
         parts.append(y)
         pairs += int(jnp.sum(load))
-    assert pairs == 40 * TINY.experts_per_tok       # every pair, once
-    total = parts[0] + parts[1] + lm.glu(w["shared"], x)
+    assert len(parts) == n // held
+    assert pairs == 40 * arch.experts_per_tok       # every pair, once
+    total = sum(parts) + lm.glu(w["shared"], x)
     assert float(jnp.max(jnp.abs(total - uncut))) < 0.02 * float(jnp.std(uncut))
 
 
-def test_planted_imbalance_drops_no_pair(ref, seeded):
+def test_planted_imbalance_drops_no_pair(case):
     """A router that sends every token to the same two held experts: every
-    pair is computed (no capacity), and the layer is still the reference's."""
-    conf, v, _ = seeded
+    pair is computed (no capacity; in blocks of the held pairs where the
+    chip holds few of the experts), the layer is still the reference's, and
+    so is its gradient with respect to the tokens and the weights."""
+    arch, ref, v = case.arch, case.ref, case.variables
     w = v["base"]["blocks"][1]
-    bias = jnp.zeros(8).at[jnp.array([1, 2])].set(10.0)    # always selected
-    router = 0.02 * jax.random.normal(jax.random.key(6), (8, TINY.hidden))
-    x = jax.random.normal(jax.random.key(7), (64, TINY.hidden), jnp.float32)
-    idx, weights = lm.route(TINY, router, bias, x)
+    bias = jnp.zeros(arch.n_experts).at[jnp.array([1, 2])].set(10.0)
+    router = 0.02 * jax.random.normal(jax.random.key(6),
+                                      (arch.n_experts, arch.hidden))
+    x = jax.random.normal(jax.random.key(7), (64, arch.hidden), jnp.float32)
+    idx, weights = lm.route(arch, router, bias, x)
     assert set(np.asarray(idx).ravel().tolist()) == {1, 2}
-    y, load = lm.held_experts(TINY, w["experts"], x, idx, weights)
+    blocks, rows = lm.pair_blocks(arch, idx.size)
+    assert (blocks, rows) == ((4, 32) if arch.index_topk else (1, 128))
+    y, load = lm.held_experts(arch, w["experts"], x, idx, weights)
     assert np.asarray(load).tolist() == [0, 64, 64, 0]
-    mm = lambda a, b: a @ b.astype(jnp.float32)  # noqa: E731
-    want, _ = _highest(ref._experts, ref._sizes(conf), w["experts"], x, idx,
-                       weights, mm, None, None)
+    want, _ = _highest(ref._experts, case.z, w["experts"], x, idx, weights,
+                       case.mm, None, None)
     assert float(jnp.max(jnp.abs(y - want))) < 0.02 * float(jnp.std(want))
+    ct = jax.random.normal(jax.random.key(8), y.shape)
+    got = jax.grad(lambda a, b: jnp.sum(lm.held_experts(
+        arch, w["experts"], a, idx, b)[0] * ct), (0, 1))(x, weights)
+    ref_g = _highest(jax.grad(lambda a, b: jnp.sum(ref._experts(
+        case.z, w["experts"], a, idx, b, case.mm, None, None)[0] * ct), (0, 1)),
+        x, weights)
+    for a, b in zip(got, ref_g):
+        assert float(jnp.max(jnp.abs(a - b))) < 0.05 * float(jnp.max(jnp.abs(b)))
+
+
+def test_no_held_pair_and_every_pair_held_cost_their_blocks():
+    """Blocks of the held pairs alone: none when no token is routed here,
+    all of them when every token is (nothing dropped either way)."""
+    arch = SPARSE
+    w = jax.eval_shape(lm.FrozenBaseLM(num_classes=VOCAB, arch=arch).init_base)
+    w = jax.tree_util.tree_map(
+        lambda a: 0.05 * jax.random.normal(jax.random.key(a.size), a.shape,
+                                           a.dtype), w["blocks"][1]["experts"])
+    x = jax.random.normal(jax.random.key(1), (48, arch.hidden), jnp.float32)
+    away = jnp.full((48, 2), 9, jnp.int32)                 # experts held elsewhere
+    y, load = lm.held_experts(arch, w, x, away, jnp.ones((48, 2)))
+    assert not np.any(np.asarray(load)) and not np.any(np.asarray(y))
+    here = jnp.tile(jnp.array([[0, 3]], jnp.int32), (48, 1))
+    y, load = lm.held_experts(arch, w, x, here, jnp.ones((48, 2)))
+    assert np.asarray(load).tolist() == [48, 0, 0, 48]
+    one = dataclasses.replace(arch, held_experts=arch.n_experts)   # one block
+    full = {k: jnp.concatenate([v, jnp.zeros((12, *v.shape[1:]), v.dtype)])
+            for k, v in w.items()}
+    want, _ = lm.held_experts(one, full, x, here, jnp.ones((48, 2)))
+    assert jnp.allclose(y, want, rtol=1e-5, atol=1e-6)
+
+
+def test_group_limited_routing_matches_reference():
+    """The selection leaves the `topk_group` best groups only (a group's
+    score: the sum of its two largest s + bias), and so differs from plain
+    top-k routing."""
+    arch = SPARSE
+    ref = _load(os.path.join(ROOT, "benchmarks", "reference", "deepseek_v32.py"))
+    z = ref._sizes(_conf(arch))
+    router = 0.5 * jax.random.normal(jax.random.key(3), (16, arch.hidden))
+    bias = 0.1 * jax.random.normal(jax.random.key(4), (16,))
+    x = jax.random.normal(jax.random.key(5), (200, arch.hidden), jnp.float32)
+    idx, w = lm.route(arch, router, bias, x)
+    idx_r, w_r = _highest(ref._route, z, router, bias, x, None)
+    assert jnp.array_equal(idx, idx_r) and jnp.allclose(w, w_r, rtol=1e-5)
+    plain, _ = _highest(ref._route, z, router, bias, x, None, groups=False)
+    assert not jnp.array_equal(idx, plain)          # the groups bind
+    groups = np.asarray(idx) // 4
+    s = np.asarray(jax.nn.sigmoid(jnp.dot(x, router.T, precision="highest"))
+                   + bias).reshape(200, 4, 4)
+    best = np.argsort(-np.sort(s, -1)[..., -2:].sum(-1), -1)[:, :2]
+    assert all(set(g) <= set(b) for g, b in zip(groups, best))
+    assert jnp.allclose(jnp.sum(w, -1), arch.routed_scaling, rtol=1e-5)
+
+
+def test_yarn_frequencies_against_the_closed_form():
+    """Published DeepSeek-V3.2 scaling over 64 rope dims: pairs below 10
+    keep theta^(-2i/64), pairs past 23 are divided by 40, a linear ramp
+    between; the softmax scale carries m^2, m = 0.1 ln 40 + 1."""
+    arch = lm.PRESETS["deepseek_v32"]
+    ramp = lm.yarn_ramp(64, arch.rope_theta, arch.rope_scaling)
+    i = np.arange(32)
+    cd = lambda r: 64 * np.log(4096 / (2 * np.pi * r)) / (2 * np.log(1e4))  # noqa: E731
+    low, high = int(np.floor(cd(32))), int(np.ceil(cd(1)))
+    assert (low, high) == (10, 23)
+    assert np.allclose(ramp, np.clip((i - 10) / 13, 0, 1))
+    # rope() turns position 1 by the scaled frequencies
+    x = jnp.zeros((1, 2, 1, 64)).at[..., 0::2].set(1.0)
+    got = lm.rope(x, arch.rope_theta, arch.rope_scaling)[0, 1, 0]
+    f = 1e4 ** (-2 * i / 64)
+    f = f / 40 * ramp + f * (1 - ramp)
+    assert np.allclose(got[0::2], np.cos(f), atol=1e-6)
+    assert np.allclose(got[1::2], np.sin(f), atol=1e-6)
+    half = lm.rope(x[..., :2].repeat(32, -1), arch.rope_theta,
+                   arch.rope_scaling, interleaved=False)[0, 1, 0]
+    assert np.allclose(half[:32], np.cos(f), atol=1e-6)     # pair i: (x_i, x_i+32)
+    assert np.allclose(half[32:], np.sin(f), atol=1e-6)
+    m = 0.1 * np.log(40) + 1
+    assert lm.softmax_scale(arch) == pytest.approx(m * m / np.sqrt(192))
+    assert lm.softmax_scale(lm.PRESETS["joyai_llm_flash"]) == 1 / np.sqrt(192)
+
+
+def test_kth_largest_mask_is_top_k_with_its_ties():
+    scores = jax.random.normal(jax.random.key(0), (16, 200))
+    scores = jnp.round(scores * 4) / 4                    # many equal values
+    scores = scores.at[3, :50].set(-jnp.inf).at[4].set(1.0)
+    for k in (1, 7, 64, 200, 300):
+        _, idx = jax.lax.top_k(scores, min(k, 200))
+        want = np.zeros((16, 200), bool)
+        np.put_along_axis(want, np.asarray(idx), True, axis=1)
+        assert np.array_equal(np.asarray(lm.kth_largest_mask(scores, k)), want), k
+
+
+def test_selection_does_not_depend_on_the_slice_of_queries_it_is_made_in():
+    """`select_keys` scores and selects `index_block` queries at a time (a
+    last slice padded): whole, 16 at a time and 7 at a time, the same keys."""
+    arch = SPARSE
+    w = lm.FrozenBaseLM(num_classes=VOCAB, arch=arch, seed=4).init_base()[
+        "blocks"][0]["attn"]["index"]
+    w = jax.tree_util.tree_map(lambda a: a * 8, w)        # scores that differ
+    x = jax.random.normal(jax.random.key(2), (2, 50, arch.hidden), jnp.float32)
+    c_q = jax.random.normal(jax.random.key(3), (2, 50, arch.q_lora_rank))
+    picks = [np.asarray(lm.select_keys(
+        dataclasses.replace(arch, index_block=rows), w, x, c_q))
+        for rows in (64, 16, 7)]
+    assert picks[0].sum() == 2 * (36 + 42 * 8)
+    assert np.array_equal(picks[0], picks[1])
+    assert np.array_equal(picks[0], picks[2])
+
+
+def test_selection_is_causal_has_min_t_plus_1_k_members_and_dense_when_short():
+    arch = SPARSE
+    base = lm.FrozenBaseLM(num_classes=VOCAB, arch=arch, seed=2).init_base()
+    w, g = base["blocks"][0]["attn"], {
+        "q_norm": jnp.ones(arch.q_lora_rank), "kv_norm": jnp.ones(arch.kv_lora_rank)}
+    w = jax.tree_util.tree_map(lambda a: a * 8, w)        # scores that differ
+    x = jax.random.normal(jax.random.key(1), (2, 50, arch.hidden), jnp.float32)
+    c_q = lm.rms_norm(lm._mm(x, w["q_a"]), g["q_norm"], arch.eps)
+    picked = np.asarray(lm.select_keys(arch, w["index"], x, c_q))
+    assert picked.shape == (2, 50, 50) and not np.triu(picked, 1).any()
+    assert np.array_equal(picked.sum(-1),
+                          np.tile(np.minimum(np.arange(50) + 1, 8), (2, 1)))
+    assert picked[:, np.arange(8), :][:, :, :8].sum() == 2 * 36   # all causal keys
+    # the picked keys are top_k's over the scores the indexer's parts give
+    q, k, wt = lm.index_scores(arch, w["index"], x, c_q)
+    score = jnp.einsum("bqj,bqjs->bqs", wt, jax.nn.relu(jnp.einsum(
+        "bqjd,bsd->bqjs", q, k, preferred_element_type=jnp.float32)))
+    score = jnp.where(np.tril(np.ones((50, 50), bool)), score, -jnp.inf)
+    _, idx = jax.lax.top_k(score, 8)
+    want = np.zeros((2, 50, 50), bool)
+    np.put_along_axis(want, np.asarray(idx), True, axis=2)
+    assert np.array_equal(picked, want & np.tril(np.ones((50, 50), bool)))
+    # at S <= k every causal key is picked: the output is the dense path's
+    short = x[:, :8]
+    dense = dataclasses.replace(arch, index_topk=0, index_heads=0)
+    got = lm.latent_attention(arch, w, g, short)
+    assert jnp.allclose(got, lm.latent_attention(dense, w, g, short),
+                        rtol=2e-2, atol=2e-3)
+
+
+def test_attention_over_a_selection_and_its_gradients_match_plain_attention():
+    # three query blocks of 128 against key blocks of 128 (384 is no multiple
+    # of 256), heads in two groups; 300 positions padded to 384
+    q, k, v, ct = _qkv(300, h=32, b=1)
+    rnd = np.asarray(jax.random.uniform(jax.random.key(5), (1, 300, 300)) < 0.3)
+    picked = jnp.asarray((rnd | np.eye(300, dtype=bool)) & np.tril(
+        np.ones((300, 300), bool)))
+    scale = 1 / np.sqrt(q.shape[-1])
+
+    def plain(q, k, v):
+        sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        p = jax.nn.softmax(jnp.where(picked[:, None], sc, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    def ours(q, k, v):
+        groups = lambda x: x.reshape(1, 300, 2, 16, -1).transpose(2, 0, 1, 3, 4)  # noqa: E731
+        return lm.selected_attention(
+            lambda xs: (xs[0] * scale, xs[1], xs[2]),
+            (groups(q), groups(k), groups(v)), picked, 128).astype(jnp.float32)
+
+    want, got = _highest(plain, q, k, v), ours(q, k, v)
+    assert got.shape == want.shape == (1, 300, 32, 16)
+    assert float(jnp.max(jnp.abs(got - want))) < 0.01 * float(jnp.max(jnp.abs(want)))
+    g_want = _highest(jax.grad(lambda *a: jnp.sum(plain(*a) * ct), (0, 1, 2)),
+                      q, k, v)
+    g_got = jax.grad(lambda *a: jnp.sum(ours(*a) * ct), (0, 1, 2))(q, k, v)
+    for a, b in zip(g_got, g_want):
+        assert float(jnp.max(jnp.abs(a - b))) < 0.02 * float(jnp.max(jnp.abs(b)))
+
+
+def test_loss_with_a_lean_tail_is_the_loss(case, monkeypatch):
+    """Where a [tokens, hidden] float32 array is large the heads' last norms
+    and the prediction module's input are made again for the gradient:
+    the same loss, the same gradient."""
+    v, tokens = case.variables, case.tokens
+    module = lm.FrozenBaseLM(num_classes=VOCAB, arch=case.arch, seed=3)
+    vg = lambda: jax.value_and_grad(lambda q: module.loss(  # noqa: E731
+        {"base": v["base"], "params": q}, tokens)[0])(v["params"])
+    l_kept, g_kept = vg()
+    monkeypatch.setattr(lm, "STREAM_BYTES", 0)
+    l_lean, g_lean = vg()
+    assert float(l_lean) == pytest.approx(float(l_kept), rel=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(g_lean),
+                    jax.tree_util.tree_leaves(g_kept)):
+        assert jnp.allclose(a, b, rtol=2e-3, atol=1e-6)
+
+
+def test_dense_mlp_by_parts_is_the_mlp(monkeypatch):
+    w = {"gate_up": jax.random.normal(jax.random.key(0), (32, 128)).astype(
+        jnp.bfloat16), "down": jax.random.normal(jax.random.key(1), (64, 32)
+                                                 ).astype(jnp.bfloat16)}
+    x = jax.random.normal(jax.random.key(2), (2, 24, 32))
+    want = lm.glu(w, x)
+    assert lm.glu_by_parts(w, x) is not None
+    monkeypatch.setattr(lm, "GLU_BYTES", 2 * 24 * 128 * 4 // 4)   # four parts
+    got = lm.glu_by_parts(w, x)
+    assert jnp.allclose(got, want, rtol=1e-6, atol=1e-6)
+    g = jax.grad(lambda a: jnp.sum(lm.glu_by_parts(w, a) ** 2))(x)
+    assert jnp.allclose(g, jax.grad(lambda a: jnp.sum(lm.glu(w, a) ** 2))(x),
+                        rtol=1e-5, atol=1e-5)
+
+
+def test_forward_flops_are_the_models_own_count():
+    """ISSUE 31's arithmetic at the published widths and 8,192 positions:
+    5.73 GFLOP a token, 1.45 of them the indexer, the selection's attention."""
+    ref = _load(os.path.join(ROOT, "benchmarks", "reference", "deepseek_v32.py"))
+    conf = json.load(open(os.path.join(ROOT, "benchmarks", "configs",
+                                       "deepseek-v32-exp-l5e8.json")))
+    parts = ref.forward_flops(conf, 8192)
+    assert ref.selected_pairs(8192, 2048) == 14_681_088
+    assert parts["attend_selected"] == pytest.approx(
+        2 * 128 * 320 * 14_681_088 / 8192)
+    assert parts["indexer"] == pytest.approx(
+        2 * 13_959_424 - 512 + 2 * 64 * 128 * 8193 / 2)
+    sparse = 6 * (parts["attend_selected"] + parts["indexer"])
+    assert sparse == pytest.approx(1.451e9, rel=1e-3)
+    assert parts["total"] == pytest.approx(5.727e9, rel=1e-3)
+    assert parts["held_experts"] == pytest.approx(8 * 8 / 256 * 6 * 7168 * 2048)
+    # a round of the cell: 2 clients x (2 x 1 trained + 1 validation) sequences
+    assert 6 * parts["total"] * 8192 == pytest.approx(281.5e12, rel=1e-3)
 
 
 def test_grouped_matmul_is_the_ragged_product_and_differentiates_its_rows():
@@ -304,6 +603,39 @@ def test_published_preset_is_the_configuration_file():
     assert 3.31e9 < count(base) < 3.33e9
     assert {a.dtype for a in jax.tree_util.tree_leaves(base)
             if a.ndim > 1} == {jnp.dtype(jnp.bfloat16)}
+
+
+def test_published_sparse_preset_is_the_configuration_file():
+    path = os.path.join(ROOT, "benchmarks", "configs",
+                        "deepseek-v32-exp-l5e8.json")
+    conf = json.load(open(path))
+    arch = lm.PRESETS["deepseek_v32"]
+    ours = _conf(arch, conf["vocab_size"])
+    ours["first_k_dense_replace"] = 3        # published; 1 of them held
+    ours["held"]["dense_layers"] = 1
+    ours["rope_scaling"]["mscale"] = conf["rope_scaling"]["mscale"]
+    for key, value in ours.items():
+        assert conf[key] == value, key
+    assert conf["held"]["dense_layers"] == arch.dense_layers == 1
+    assert conf["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                               "vocab_size"]
+    module, _ = create_model("deepseek_v32", seed=1)
+    assert module.num_classes == conf["vocab_size"] == 16160
+    count = lambda t: sum(int(np.prod(a.shape))  # noqa: E731
+                          for a in jax.tree_util.tree_leaves(t))
+    trained = count(jax.eval_shape(module.init_trained))
+    assert trained == conf["deployment"]["trained_parameters"] == 9_302_016
+    assert -(-trained // 4096) == conf["deployment"]["ciphertexts_a_client"] == 2271
+    base = jax.eval_shape(module.init_base)
+    assert count(base) == 3_918_990_080
+    assert count(base["blocks"][1]["attn"]["index"]) == 13_959_424
+    # the reference makes the same leaves
+    ref = _load(os.path.join(ROOT, "benchmarks", "reference", "deepseek_v32.py"))
+    twin = jax.eval_shape(lambda: ref.init(0, conf))
+    assert (jax.tree_util.tree_structure(twin["base"])
+            == jax.tree_util.tree_structure(base))
+    assert all(a.shape == b.shape for a, b in zip(
+        jax.tree_util.tree_leaves(twin["base"]), jax.tree_util.tree_leaves(base)))
 
 
 def _tiny_round_inputs(seed=5):

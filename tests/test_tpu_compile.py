@@ -143,6 +143,89 @@ def test_fused_attention_gradient_compiles_for_described_v5e(
     assert compiled.cost_analysis()["bytes accessed"] < 4e9
 
 
+def test_selection_compiles_for_described_v5e_without_a_heads_by_keys_array(
+        one_chip):
+    """`models/lm.select_keys` at DeepSeek-V3.2-Exp's indexer (64 heads of
+    128, the 2,048 largest) and the benchmark's 8,192 positions: nothing of
+    [heads, S, S] in HBM (64 x 8192^2 float32 would be 17 GB) and no float32
+    [S, S] either: the program's temporaries are a slice of 256 queries'."""
+    from hefl_tpu.models import lm
+
+    arch = lm.PRESETS["deepseek_v32"]
+    s = 8192
+    shape = lambda *dims, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dt, sharding=one_chip)
+    w = {"q": shape(arch.q_lora_rank, arch.index_heads * arch.index_head_dim),
+         "k": shape(arch.hidden, arch.index_head_dim),
+         "k_gain": shape(arch.index_head_dim, dt=jnp.float32),
+         "k_bias": shape(arch.index_head_dim, dt=jnp.float32),
+         "w": shape(arch.hidden, arch.index_heads)}
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(lambda w, x, c_q: lm.select_keys(arch, w, x, c_q)).lower(
+            w, shape(1, s, arch.hidden, dt=jnp.float32),
+            shape(1, s, arch.q_lora_rank, dt=jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes == s * s          # one byte a (query, key)
+    assert m.temp_size_in_bytes < 1.2e9
+
+
+def test_sparse_round_program_fits_a_described_v5e(one_chip, monkeypatch):
+    """The encrypted round of the benchmark's `deepseek-v32.sync_s8k` (two
+    clients, one step of one sequence of 8,192 positions, validation, 2 x
+    2,271 ciphertext rows) at the published widths, compiled whole for a
+    described chip: the selection's attention and the grouped product lower
+    through Mosaic, and arguments (the 7.84 GB base), outputs and
+    temporaries stay under 15.0 GB by the compiler's count."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    import hefl_tpu.fl.fedavg as fedavg
+    import hefl_tpu.fl.secure as secure
+    from hefl_tpu.ckks.keys import keygen
+    from hefl_tpu.experiment import HEConfig
+    from hefl_tpu.fl import TrainConfig
+    from hefl_tpu.models import lm
+
+    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    # the program's base is its last argument: no 7.84 GB made here
+    monkeypatch.setattr(fedavg, "with_frozen_base", lambda module, fn: fn)
+    mesh = Mesh(np.array([one_chip._device]), ("clients",))
+    whole, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("clients"))
+    module = lm.FrozenBaseLM(num_classes=16160, arch=lm.PRESETS["deepseek_v32"])
+    shapes = lambda tree, sh: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+    cfg = TrainConfig(epochs=1, batch_size=1, num_classes=16160,
+                      val_fraction=0.5, lr=1e-3, lr_decay=0.0, augment=False)
+    ctx = HEConfig().build()
+    _, pk = keygen(ctx, jax.random.key(0))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 2))
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        fn = secure._build_secure_round_fn.__wrapped__(
+            module, cfg, mesh, ctx, False, None, 2)
+        compiled = fn.lower(
+            shapes(jax.eval_shape(module.init_trained), whole), shapes(pk, whole),
+            jax.ShapeDtypeStruct((2, 2, 8194), jnp.int32, sharding=split),
+            jax.ShapeDtypeStruct((2, 2), jnp.int32, sharding=split),
+            shapes(keys, split), shapes(keys, split),
+            shapes(jax.eval_shape(module.init_base), whole)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert "gmm" in text
+    m = compiled.memory_analysis()
+    assert 7.8e9 < m.argument_size_in_bytes < 7.95e9
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes) < 15.0e9
+
+
 def test_owner_decode_program_compiles_for_described_v5e(one_chip):
     """The owner's compiled decode + unpack (`fl.secure._decode_unpack`, PR
     30) at the benchmark's ring and about resnet20's size, 64 rows into 65 leaves:
