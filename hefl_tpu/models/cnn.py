@@ -27,7 +27,8 @@ block sit side by side in the lanes and the pool is an elementwise maximum
 of four lane groups. Same parameters, same products summed, the same
 first-maximum pool gradient; at 256x256x3 the first stage takes 4x4 blocks
 and hands its pooled map, still in 2x2 form, to the second (2x2 blocks),
-and the step went 47.1 -> 14.4 ms on a TPU v5e.
+and the step went 47.1 -> 14.4 ms on a TPU v5e, then 12.8 ms with the pool
+as one pass over the stage's arrays each way (`_relu_pool4`, PR 32).
 """
 
 from __future__ import annotations
@@ -113,24 +114,45 @@ def _relu_pool4(y):
     if that maximum is not positive. `jnp.max` would split it between ties,
     which bf16 activations make common, and the training step would no
     longer be the reference's. The backward needs the winning phase alone
-    (int8), not the activation."""
+    (int8), not the activation.
+
+    The form is written for what XLA makes of it (PERF.md section 5, PR 32;
+    `tests/test_tpu_compile.py` holds it), one pass over the stage's arrays
+    each way. Forward, ONE comparison tree gives the maximum and the winning
+    phase (ties to the lower index at every node), so they come out of one
+    fusion that reads `y` once and `y` dies there. As the maximum and then
+    four compares against it, XLA sank the phase's whole chain into the
+    backward, kept `y` (520 MB a step at MedCNN's first stage) live until
+    then and read it again. Backward, ONE select over the whole lane width
+    against a constant lane-phase index, which XLA fuses into the operand
+    of the convolutions that take the cotangent (kernel and input gradient),
+    so the [..., 4g] cotangent is never written. As four selects and a
+    concatenation it wrote four arrays and read them back twice."""
     return _relu_pool4_fwd(y)[0]
 
 
 def _relu_pool4_fwd(y):
     g = y.shape[-1] // 4
-    phases = [y[..., p * g : (p + 1) * g] for p in range(4)]
-    top = jnp.maximum(
-        jnp.maximum(phases[0], phases[1]), jnp.maximum(phases[2], phases[3])
+    p0, p1, p2, p3 = (y[..., p * g : (p + 1) * g] for p in range(4))
+    m01, m23 = jnp.maximum(p0, p1), jnp.maximum(p2, p3)
+    row0 = m01 >= m23  # ties to the lower index at every node: the FIRST maximum
+    first = jnp.where(
+        row0,
+        jnp.where(p0 >= p1, jnp.int8(0), jnp.int8(1)),
+        jnp.where(p2 >= p3, jnp.int8(2), jnp.int8(3)),
     )
-    first = jnp.full(top.shape, 4, jnp.int8)
-    for p in (3, 2, 1, 0):
-        first = jnp.where(phases[p] == top, jnp.int8(p), first)
-    return nn.relu(top), jnp.where(top > 0, first, jnp.int8(4))
+    top = jnp.where(row0, m01, m23)
+    pos = top > 0
+    return jnp.where(pos, top, 0), jnp.where(pos, first, jnp.int8(4))
 
 
 def _relu_pool4_bwd(first, g):
-    return (jnp.concatenate([jnp.where(first == p, g, 0) for p in range(4)], axis=-1),)
+    n = g.shape[-1]
+    phase = jnp.asarray(np.arange(4 * n) // n, jnp.int8)
+    # four copies side by side, not `jnp.tile`: behind its broadcast and
+    # reshape XLA fuses nothing and the step reads more than before PR 32
+    first4, g4 = (jnp.concatenate([a] * 4, axis=-1) for a in (first, g))
+    return (jnp.where(first4 == phase, g4, 0),)
 
 
 _relu_pool4.defvjp(_relu_pool4_fwd, _relu_pool4_bwd)

@@ -167,6 +167,81 @@ def test_polyphase_pool_gradient_goes_to_the_first_maximum(level, block):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+def _relu_pool4_before_pr32_fwd(y):
+    """`_relu_pool4_fwd` as PR 25 wrote it: the maximum, then the lowest
+    phase equal to it, four compares and selects. The oracle."""
+    g = y.shape[-1] // 4
+    phases = [y[..., p * g : (p + 1) * g] for p in range(4)]
+    top = jnp.maximum(
+        jnp.maximum(phases[0], phases[1]), jnp.maximum(phases[2], phases[3])
+    )
+    first = jnp.full(top.shape, 4, jnp.int8)
+    for p in (3, 2, 1, 0):
+        first = jnp.where(phases[p] == top, jnp.int8(p), first)
+    return nn.relu(top), jnp.where(top > 0, first, jnp.int8(4))
+
+
+def _relu_pool4_before_pr32_bwd(first, g):
+    return (jnp.concatenate([jnp.where(first == p, g, 0) for p in range(4)], axis=-1),)
+
+
+def _pool_windows(case, n, key):
+    """[2, 5, 7, 4, n] bf16: the four phases of 70 windows a lane."""
+    shape = (2, 5, 7, 4, n)
+    k_val, k_where, k_sign = jax.random.split(key, 3)
+    # halves in [-4, 4): exact in bf16, so equal values are common anyway
+    y = jnp.round(jax.random.uniform(k_val, shape, minval=-4.0, maxval=4.0) * 2) / 2
+    order = jnp.argsort(jax.random.uniform(k_where, shape), axis=-2)  # a permutation of the phases a window
+    if case.startswith("ties"):
+        # the window's maximum planted at 2, 3 or 4 phases chosen at random
+        planted = order < int(case[-1])
+        y = jnp.where(planted, jnp.max(y, axis=-2, keepdims=True), y)
+    elif case == "negative":
+        y = -jnp.abs(y) - 0.5
+    elif case == "zeros":
+        # maxima that are exactly zero, of either sign, alone and tied
+        zero = jnp.where(jax.random.bernoulli(k_sign, 0.5, shape), 0.0, -0.0)
+        y = jnp.where(order < 2, zero, -jnp.abs(y))
+    else:
+        assert case == "random"
+    return y.astype(jnp.bfloat16).reshape(2, 5, 7, 4 * n)
+
+
+@pytest.mark.parametrize("lanes", [32, 128])
+@pytest.mark.parametrize(
+    "case", ["ties2", "ties3", "ties4", "negative", "zeros", "random"]
+)
+def test_relu_pool4_is_bit_for_bit_the_formulation_it_replaced(case, lanes):
+    # PR 32 rewrote the pool for what XLA makes of it (one comparison tree
+    # forward, one select backward); the numbers must not move: the pooled
+    # map, the saved winning phase and the cotangent, every bit of them.
+    y = _pool_windows(case, lanes, jax.random.key(lanes + len(case)))
+    ct = jax.random.normal(jax.random.key(3), (2, 5, 7, lanes)).astype(jnp.bfloat16)
+
+    def bits(a):
+        return np.asarray(a).view(np.uint16 if a.dtype == jnp.bfloat16 else np.int8)
+
+    want_out, want_first = _relu_pool4_before_pr32_fwd(y)
+    got_out, got_first = cnn._relu_pool4_fwd(y)
+    assert got_out.dtype == want_out.dtype and got_first.dtype == jnp.int8
+    np.testing.assert_array_equal(bits(got_first), bits(want_first))
+    np.testing.assert_array_equal(bits(got_out), bits(want_out))
+    np.testing.assert_array_equal(bits(cnn._relu_pool4(y)), bits(want_out))
+    if case == "negative":
+        assert np.all(np.asarray(got_first) == 4)
+    elif case.startswith("ties"):
+        # the first of k tied phases is one of the 5 - k lowest
+        winners = set(np.unique(np.asarray(got_first)).tolist()) - {4}
+        assert winners == set(range(5 - int(case[-1])))
+
+    (want_dy,) = _relu_pool4_before_pr32_bwd(want_first, ct)
+    vjp = lambda y, ct: jax.vjp(cnn._relu_pool4, y)[1](ct)[0]  # noqa: E731
+    # eagerly and under jit: what XLA fuses may not change a bit either
+    for got_dy in (vjp(y, ct), jax.jit(vjp)(y, ct)):
+        assert got_dy.dtype == want_dy.dtype and got_dy.shape == y.shape
+        np.testing.assert_array_equal(bits(got_dy), bits(want_dy))
+
+
 @pytest.mark.parametrize(
     "hw,widths,taken",
     [

@@ -14,6 +14,7 @@ hold, so nothing here may run while any module is imported.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -254,3 +255,83 @@ def test_owner_decode_program_compiles_for_described_v5e(one_chip):
     assert "custom-call" not in text and "callback" not in text
     shapes = jax.tree_util.tree_leaves(compiled.out_info)
     assert len(shapes) == 65 and all(s.dtype == jnp.float32 for s in shapes)
+
+
+def _hlo_computations(text):
+    """{computation name: its instruction lines} of an optimized HLO module's
+    text; the entry computation under "ENTRY"."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = comps.setdefault("ENTRY" if head.group(1) else head.group(2), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line.strip())
+    return comps
+
+
+def test_polyphase_pool_is_one_pass_each_way_on_a_described_v5e(one_chip):
+    """`medcnn.sync_e10`'s step (4 clients x 32 images of 256x256x3 under
+    `vmap`, forward and gradient) compiled for a described chip keeps the
+    form PR 32 gave `models/cnn._relu_pool4` (PERF.md section 5): the step
+    reads and writes under 8.3 GB by the compiler's count (6.9; 9.92 before);
+    each polyphase stage's conv output has ONE consumer, the forward fusion
+    that makes the pooled map and the winning phase together; and the
+    backward's select on the winning phase is fused into the convolutions
+    that take it, so that no fusion but the forward's writes a pooled-map-
+    sized array from a select. A JAX or XLA upgrade that undoes any of the
+    three costs the cell a tenth of its round."""
+    import optax
+
+    from hefl_tpu.models import MedCNN
+
+    model = MedCNN()
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 256, 256, 3)))["params"])
+
+    def loss(p, x, y):
+        logits = model.apply({"params": p}, x)
+        return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(
+            jax.tree_util.tree_map(lambda a: on_chip((4, *a.shape), a.dtype), params),
+            on_chip((4, 32, 256, 256, 3), jnp.bfloat16),
+            on_chip((4, 32), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 8.3e9, cost["bytes accessed"]
+
+    comps = _hlo_computations(compiled.as_text())
+    entry = comps["ENTRY"]
+    name_of = lambda line: re.match(r"(?:ROOT )?(%[\w.\-]+) = ", line).group(1)  # noqa: E731
+    for conv_out, pooled in (("bf16[4,32,63,63,512]", "bf16[4,32,63,63,128]"),
+                             ("bf16[4,32,62,62,128]", "bf16[4,32,62,62,32]")):
+        made = [name_of(l) for l in entry
+                if l.split(" = ", 1)[1].startswith(conv_out + "{")]
+        assert len(made) == 1, (conv_out, made)  # the conv output; no [., 4n] cotangent
+        users = [l for l in entry
+                 if re.search(re.escape(made[0]) + r"[,)]", l.split(" = ", 1)[1])]
+        assert len(users) == 1 and " fusion(" in users[0], (conv_out, users)
+        forward = name_of(users[0])
+        assert "s8[" in users[0].split(" fusion(")[0]  # it makes `first` as well
+        for line in entry:
+            call = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+            if not call or pooled not in line.split(" fusion(")[0]:
+                continue
+            body = {name_of(l): l for l in comps[call.group(1)]}
+            root = next(l for l in comps[call.group(1)] if l.startswith("ROOT"))
+            outs = (re.findall(r"%[\w.\-]+", root.split(" tuple(")[1])
+                    if " tuple(" in root else [name_of(root)])
+            selects = [o for o in outs
+                       if body[o].split(" = ", 1)[1].startswith(pooled + "{")
+                       and re.search(r"\} select\(", body[o])]
+            assert not selects or name_of(line) == forward, (pooled, line[:200])
