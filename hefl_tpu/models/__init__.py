@@ -44,6 +44,8 @@ TOKEN_MODELS: dict[str, int] = {
     "joyai_llm_flash_tiny": 64,
     "deepseek_v32": 16160,
     "deepseek_v32_tiny": 64,
+    "mimo_v2_flash": 19072,
+    "mimo_v2_flash_tiny": 64,
 }
 
 
@@ -83,6 +85,7 @@ def create_model(
     # a token model's forward sets them as it is traced (models/lm.py)
     obs_metrics.gauge("model.fused_attention_layers").set(0)
     obs_metrics.gauge("model.sparse_attention_layers").set(0)
+    obs_metrics.gauge("model.window_attention_layers").set(0)
     dummy = jnp.zeros(
         (1, *(input_shape if input_shape is not None else default_shape)), jnp.float32
     )

@@ -1,6 +1,8 @@
-"""DeepSeek-V3-shaped language models as a frozen base and a trained subset.
+"""Expert language models as a frozen base and a trained subset.
 
-One module class, `FrozenBaseLM`, over an `LMArch`; two published models:
+One module class, `FrozenBaseLM`, over an `LMArch`; three published models,
+two of them DeepSeek-V3-shaped (latent attention, one shared expert, a
+prediction module) and one with grouped-query attention of two kinds:
 
   * **JoyAI-LLM-Flash** (`PRESETS["joyai_llm_flash"]`; huggingface.co/
     jdopensource/JoyAI-LLM-Flash, config.json; every key a DeepSeek-V3 key,
@@ -23,9 +25,27 @@ One module class, `FrozenBaseLM`, over an `LMArch`; two published models:
     products are bfloat16 (published: FP8 after a Hadamard turn, trained by
     an alignment loss): `benchmarks/configs/deepseek-v32-exp-l5e8.json`
     lists each departure.
+  * **MiMo-V2-Flash** (`PRESETS["mimo_v2_flash"]`; huggingface.co/XiaomiMiMo/
+    MiMo-V2-Flash, `model_type` `mimo_v2_flash`): **grouped-query attention
+    with no latents** (`grouped_attention`: 64 query heads of 192 over 4 or
+    8 KV heads, values of 128 scaled by 0.707; K and V stay as many heads
+    wide as they are made), **layers of two kinds in one model**
+    (`layer_pattern`: five window layers of 128 keys to one global layer,
+    each kind with its own KV heads, RoPE base and leaf shapes), **a
+    trained sink a head** in the window layers' softmax (it takes mass and
+    gives no value), **RoPE over the first 64 dims of a head**, **no shared
+    expert and no prediction module** (`shared_experts`, `mtp_modules` 0:
+    the loss is the next-token cross-entropy alone), 16 of 256 experts held:
+    the expert layer is an eighth of its round and more as the routers
+    train, so its held pairs go through one grouped product of a fixed
+    number of rows with gathers around it (`_held_front`: the same work
+    whatever the routers do) and only what is behind `pair_front` in blocks.
+    `benchmarks/configs/mimo-v2-flash-l7e16.json` lists what is assumed.
 
-With `index_topk` 0, `n_group` 1 and no `rope_scaling` the traced program is
-the first model's, operation for operation.
+Which attention a layer runs follows from its `arch` (`kv_heads` empty:
+latent). With `index_topk` 0, `n_group` 1 and no `rope_scaling` the traced
+program is the first model's, operation for operation; with `kv_heads`
+empty, one shared expert and one prediction module it is the first two's.
 
 What a federation can afford of such a model (PERF.md, PR 26-27: a trained
 parameter costs a client 16 bytes for its step and 24 for its ciphertext, a
@@ -36,9 +56,9 @@ frozen one 2) decides the layout. The parameters are two pytrees:
     the round program (never a constant of it), is in no `ClientState`, no
     optimizer, no `PackSpec` and no ciphertext, and no gradient with respect
     to it is ever formed.
-  * the **trained subset** (`init_trained`): every router matrix and every
-    RMSNorm gain, float32. It is what `create_model` returns as `params`:
-    what is stepped, encrypted, summed and decrypted.
+  * the **trained subset** (`init_trained`): every router matrix, every
+    RMSNorm gain and every sink, float32. It is what `create_model` returns
+    as `params`: what is stepped, encrypted, summed and decrypted.
 
 The expert layer is told which experts it holds (`held_start`,
 `held_experts`): selection and normalisation run over all `n_experts`, the
@@ -48,8 +68,9 @@ imbalance: no capacity, nothing dropped) and summed with the shared expert;
 what absent experts would add is left out. Compute is bfloat16 with float32
 accumulation; the residual stream, the norms, the router, the selection and
 the softmax are float32. Attention is one fused Pallas kernel a layer
-(splash attention, forward and gradient), causal (`causal_attention`) or
-over the indexer's selection (`selected_attention`): scores and
+(splash attention, forward and gradient), causal (`causal_attention`), over
+the indexer's selection (`selected_attention`) or grouped, causal or over a
+window (`grouped_heads`): scores and
 probabilities live a block at a time in the chip's fast memory and never in
 HBM, blocks no query of which attends any key are skipped, and memory is
 linear in the sequence. Every block is made again for the gradient (a
@@ -62,6 +83,7 @@ client code sees inside a round program, its base the program's argument.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -116,6 +138,23 @@ class LMArch:
     index_topk: int = 0
     index_block: int = 256            # queries a slice of the indexer's scores
     pair_block: int = 512             # rows a block of the held experts' pairs
+    # rows of the sorted held pairs taken in one grouped product ahead of
+    # the blocks, every row computed (`_held_front`; 0: blocks alone)
+    pair_front: int = 0
+    # grouped-query attention with no latents (`kv_heads` empty: latent
+    # attention): a head is `qk_rope_head_dim` rotated dims (half-split
+    # pairs) then `qk_nope_head_dim` that pass through. A layer is of kind
+    # `layer_pattern[layer]`, 0 global (causal over every key) or 1 window
+    # (the `window` last keys, the query's own among them); by kind its KV
+    # heads, its RoPE base and whether a head has a trained sink
+    kv_heads: tuple = ()
+    rope_thetas: tuple = ()
+    sinks: tuple = ()
+    layer_pattern: tuple = ()
+    window: int = 0
+    value_scale: float = 1.0          # v is scaled by it
+    shared_experts: int = 1           # 0: an expert layer is its routed part
+    mtp_modules: int = 1              # 0: one head, next-token loss alone
 
 
 PRESETS = {
@@ -143,6 +182,25 @@ PRESETS = {
         rope_theta=10_000.0, n_group=4, topk_group=2,
         rope_scaling=(40.0, 16, 32.0, 1.0, 1.0),
         index_heads=4, index_head_dim=16, index_topk=8, index_block=16,
+        pair_block=32, q_block=128, loss_chunk=16),
+    # the benchmark's `mimo-v2-flash-l7e16`: every width as published, the
+    # published layers 0-6 (the leading dense layer and one period of five
+    # window layers to one global), a chip's share of 16 that divide every
+    # expert layer (experts 0-15)
+    "mimo_v2_flash": LMArch(
+        hidden=4096, heads=64, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, intermediate=16384, moe_intermediate=2048,
+        routed_scaling=1.0, eps=1e-5, expert_layers=6, held_experts=16,
+        kv_heads=(4, 8), rope_thetas=(5_000_000.0, 10_000.0),
+        sinks=(False, True), layer_pattern=(0, 1, 1, 1, 1, 0, 1), window=128,
+        value_scale=0.707, shared_experts=0, mtp_modules=0, pair_front=24576),
+    "mimo_v2_flash_tiny": LMArch(
+        hidden=64, heads=4, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, intermediate=128, moe_intermediate=32, n_experts=8,
+        experts_per_tok=2, routed_scaling=1.0, eps=1e-5, expert_layers=3,
+        held_experts=4, kv_heads=(1, 2), rope_thetas=(5_000_000.0, 10_000.0),
+        sinks=(False, True), layer_pattern=(0, 1, 1, 0), window=8,
+        value_scale=0.707, shared_experts=0, mtp_modules=0,
         pair_block=32, q_block=128, loss_chunk=16),
 }
 
@@ -283,13 +341,125 @@ def causal_attention(q, k, v, q_block: int, scale: float | None = None):
     return o.transpose(0, 2, 1, 3)[:, :s].astype(F32)
 
 
+@functools.lru_cache(maxsize=None)
+def _grouped_kernel(seq: int, heads: int, window: int, block: int,
+                    interpret: bool):
+    """The fused attention of `heads` query heads over ONE shared head of
+    keys and values (splash attention's multi-query form; `grouped_heads`
+    runs it a KV head at a time), over `seq` positions (a multiple of
+    `block`) -> (the kernel, the (query, key) pairs inside the blocks it
+    computes over the pairs its mask allows). `window` 0: causal over every
+    key, blocks and fused gradient as `_attention_kernel`'s. Otherwise a
+    query attends the `window` last keys, its own among them (`LocalMask`):
+    q and kv blocks of `block` both ways, so that a window of 128 pays for
+    256 keys and not for 1,024, and the gradient in two kernels (`dq`,
+    `dkv`), each over the blocks the window touches alone (the fused one
+    would keep a float32 dq a key block a head: 64 of them). Both take
+    `sinks`, a float32 scalar a head inside the softmax's denominator, and
+    return its gradient. Nothing of it is kept for the gradient (`_kept`)."""
+    import importlib
+
+    import numpy as np
+
+    splash = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.splash_attention")
+    if window:
+        sizes = splash.BlockSizes(
+            block_q=block, block_kv=block, block_kv_compute=block,
+            block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+            block_q_dq=block, block_kv_dq=block)
+        one = splash.LocalMask((seq, seq), (window - 1, 0), 0)
+    else:
+        step = min(block, 512)
+        sizes = splash.BlockSizes(
+            block_q=block, block_kv=block, block_kv_compute=step,
+            block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=step,
+            use_fused_bwd_kernel=True)
+        one = splash.CausalMask((seq, seq))
+    with jax.ensure_compile_time_eval():
+        kernel = splash.make_splash_mqa(
+            splash.MultiHeadMask([one] * heads), block_sizes=sizes,
+            head_shards=1, q_seq_shards=1, interpret=interpret)
+    kernel = jax.tree_util.tree_map(np.asarray, kernel)
+    # the block table: 0 skipped, 1 partly masked, 2 whole (one head's: the
+    # heads share a mask)
+    table = np.asarray(kernel.fwd_mask_info.block_mask)
+    computed = np.count_nonzero(table) / table.shape[0] * block * block
+    full = min(seq, window) if window else seq
+    allowed = full * (full + 1) // 2 + (seq - full) * window
+    return kernel, float(computed / allowed)
+
+
+def grouped_heads(q, k, v, sinks, window: int, q_block: int, scale: float):
+    """Grouped-query attention: softmax(q k^T * scale) v, query head j
+    reading KV head j // (H / G), causal (`window` 0) or over the `window`
+    last keys. q: [B, S, H, dq]; k: [B, S, G, dq]; v: [B, S, G, dv]; `sinks`
+    f32[H] or None: with them p[t, u] = exp(s[t, u]) / (exp(sink) + sum_u'
+    exp(s[t, u'])) -> f32[B, S, H, dv]. One KV head at a time (a loop of G
+    steps) through `_grouped_kernel` with that head's H / G query heads, so K
+    and V are never repeated and stay G heads wide in HBM, and the global
+    kind's fused gradient keeps one group's float32 dq at a time.
+    Arithmetic, padding and what reaches HBM are `causal_attention`'s: no
+    score block, forward or backward, and blocks no query of which attends
+    any key are skipped (all but two a query block, under a window)."""
+    b, s, h, dq = q.shape
+    g, dv = k.shape[2], v.shape[-1]
+    up = lambda n, m: -(-n // m) * m  # noqa: E731
+    blk = min(up(q_block, 128), up(s, 128))
+    if window:
+        blk = min(blk, up(window, 128))
+    pad = up(s, blk) - s
+    kernel, ratio = _grouped_kernel(s + pad, h // g, window, blk, _interpret())
+    if window:
+        obs_metrics.gauge("swa.block_pairs_over_window_pairs").set(ratio)
+    heads_first = lambda x: jnp.pad(  # noqa: E731
+        x.astype(BF16), ((0, 0), (0, pad), (0, 0), (0, 0)))
+    qs = heads_first(q.astype(F32) * scale).reshape(
+        b, s + pad, g, h // g, dq).transpose(2, 0, 3, 1, 4)  # [G, B, H/G, S, dq]
+    ks, vs = (heads_first(x).transpose(2, 0, 1, 3) for x in (k, v))
+
+    def one(group):                   # a KV head and its query heads
+        qg, kg, vg, sink = group
+        call = kernel if sink is None else functools.partial(kernel, sinks=sink)
+        with (jax.named_scope(obs_scopes.SWA_ATTEND) if window
+              else contextlib.nullcontext()):
+            return jax.vmap(call)(qg, kg, vg)
+
+    o = jax.lax.map(one, (qs, ks, vs, None if sinks is None
+                          else sinks.astype(F32).reshape(g, h // g)))
+    return o.transpose(1, 3, 0, 2, 4).reshape(b, s + pad, h, dv)[:, :s].astype(F32)
+
+
+def grouped_attention(arch: LMArch, kind: int, w, g, x):
+    """Grouped-query attention with no latents, of layer kind `kind` (0
+    global, 1 window). w: the block's frozen matrices (`q`, `k`, `v`, `o`),
+    g: its trained leaves (`sink` where the kind has one), x: [B, S, D]
+    (already normed). The first `qk_rope_head_dim` dims of every head of q
+    and k turn, half-split, at the kind's base; v is scaled."""
+    with jax.named_scope(obs_scopes.GQA):
+        b, s, _ = x.shape
+        h, dr, dv = arch.heads, arch.qk_rope_head_dim, arch.v_head_dim
+        dq, kv = arch.qk_nope_head_dim + dr, arch.kv_heads[kind]
+        turn = lambda t: jnp.concatenate(  # noqa: E731
+            [rope(t[..., :dr], arch.rope_thetas[kind], None, False),
+             t[..., dr:]], -1)
+        q = turn(_mm(x, w["q"]).reshape(b, s, h, dq))
+        k = turn(_mm(x, w["k"]).reshape(b, s, kv, dq))
+        v = arch.value_scale * _mm(x, w["v"]).reshape(b, s, kv, dv)
+        o = grouped_heads(q, k, v, g.get("sink"), arch.window if kind else 0,
+                          arch.q_block, softmax_scale(arch))
+        return _mm(o.reshape(b, s, h * dv), w["o"])
+
+
 def _kept(arch: LMArch):
     """What a layer's checkpoint keeps for the gradient besides the layer's
     input: attention's output and log-sum-exp (`ATTN_SAVED`), so the forward
     kernel does not run again; nothing where an indexer picks the keys (128
     heads of 8,192 positions a layer: 0.27 GB that the step has no room
-    for)."""
-    if arch.index_topk:
+    for) and nothing where attention is grouped (64 heads of 8,192
+    positions in each of seven layers held 1.7 GB of the step's temporaries
+    by the compiler's count, and put the round over 14 GB)."""
+    if arch.index_topk or arch.kv_heads:
         return jax.checkpoint_policies.nothing_saveable
     return jax.checkpoint_policies.save_only_these_names(ATTN_SAVED)
 
@@ -565,9 +735,10 @@ def _gmm_tiles(k: int, n: int) -> tuple[int, int]:
     return fit(k), fit(n)
 
 
-def _gmm_call(x, w, sizes, transpose: bool):
+def _gmm_call(x, w, sizes, transpose: bool, rows: int = GMM_ROWS):
     """out[rows of group g] = x[rows of group g] @ w[g] (w[g].T if
-    `transpose`), rows sorted by group, `sizes` rows a group. The Pallas
+    `transpose`), rows sorted by group, `sizes` rows a group, `rows` rows a
+    tile. The Pallas
     grouped product of `jax.experimental.pallas.ops.tpu.megablox` (the
     expert's matrix is found through scalar prefetch and, transposed, read as
     it lies: no copy of a frozen matrix in any layout). Interpreted off the
@@ -578,7 +749,7 @@ def _gmm_call(x, w, sizes, transpose: bool):
         "jax.experimental.pallas.ops.tpu.megablox.gmm").gmm
     m = x.shape[0]
     n = w.shape[1] if transpose else w.shape[2]
-    tm = min(GMM_ROWS, -(-m // 8) * 8)
+    tm = min(rows, -(-m // 8) * 8)
     pad = (-m) % tm
     if pad:
         x = jnp.concatenate([x, jnp.zeros((pad, x.shape[1]), x.dtype)])
@@ -624,14 +795,28 @@ def pair_blocks(arch: LMArch, pairs: int) -> tuple[int, int]:
     return -(-pairs // arch.pair_block), arch.pair_block
 
 
+FRONT_ROWS = 512   # rows a tile of the front's grouped products
+SUM_LEAD = 4       # pairs a token `_sum_by_token` gathers for every token
+SUM_BLOCK = 128    # pairs a block of the rest
+
+
+def front_pairs(arch: LMArch, pairs: int) -> int:
+    """The sorted held pairs `_held_front` takes ahead of the blocks: whole
+    blocks, `pair_front` at most; 0 where the pairs are one block."""
+    blocks, rows = pair_blocks(arch, pairs)
+    return min(arch.pair_front, pairs) // rows * rows if blocks > 1 else 0
+
+
 def held_experts(arch: LMArch, w, x, idx, weights):
     """The held experts' part of the layer: sum over the selected experts
     that live here of weight * E(x). Every (token, held expert) pair is
     computed, sorted by expert into a grouped matrix product; pairs of
     absent experts sort behind the last group and add nothing. Where the
     chip holds few of the experts (`pair_blocks`) the product runs over the
-    held pairs' blocks alone, so the layer costs what its held pairs cost:
-    still every pair, whatever the imbalance.
+    held pairs' blocks alone, as many as hold them (`_held_blocks`), behind
+    a front of `pair_front` sorted pairs in one product of that many rows
+    (`_held_front`: a fixed capacity, the same work whatever the routers
+    do): still every pair, whatever the imbalance.
     -> (y f32[T, D], load int32[held]: pairs a held expert computed)."""
     with jax.named_scope(obs_scopes.MOE_EXPERTS):
         t, k = idx.shape
@@ -644,9 +829,14 @@ def held_experts(arch: LMArch, w, x, idx, weights):
         blocks, rows = pair_blocks(arch, t * k)
         if blocks > 1:
             # (padded to whole blocks: a pair behind the held ones, of token 0)
-            return _held_blocks(
-                w, x.astype(F32), jnp.pad(order, (0, blocks * rows - t * k)),
-                load, jnp.where(here, weights, 0.0).reshape(t * k), k, rows), load
+            front = front_pairs(arch, t * k)
+            xf, padded, pair_w = (
+                x.astype(F32), jnp.pad(order, (0, blocks * rows - t * k)),
+                jnp.where(here, weights, 0.0).reshape(t * k))
+            y = _held_blocks(w, xf, padded, load, pair_w, k, rows, front // rows)
+            if front:
+                y = y + _held_front(w, xf, order, load, pair_w, k, front)
+            return y, load
         xs = x.astype(BF16)[order // k]                       # [T*k, D]
         with jax.named_scope(obs_scopes.MOE_GMM):
             gu = grouped_matmul(xs, w["gate_up"], load)
@@ -684,28 +874,29 @@ def _pair_block(w, x, order, load, pair_w, k: int, rows: int, i):
     return mine // k, mine, outputs
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _held_blocks(w, x, order, load, pair_w, k: int, rows: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _held_blocks(w, x, order, load, pair_w, k: int, rows: int, first: int):
     """`held_experts` a block of `rows` sorted pairs at a time (`order`
-    padded to whole blocks), for as many blocks as hold a held pair (a loop
-    whose trip count is data: the blocks behind the last held pair are never
-    entered). Its gradient, with respect to x and the pairs' weights, walks
-    the same blocks and makes each one's rows again. -> y f32[T, D]."""
+    padded to whole blocks), from block `first` on, for as many blocks as
+    hold a held pair (a loop whose trip count is data: the blocks behind the
+    last held pair are never entered). Its gradient, with respect to x and
+    the pairs' weights, walks the same blocks and makes each one's rows
+    again. -> y f32[T, D]."""
 
     def add(i, y):
         tokens, mine, outputs = _pair_block(w, x, order, load, pair_w, k, rows, i)
         return y.at[tokens].add(outputs(x[tokens], pair_w[mine]))
 
     y0 = jnp.zeros((x.shape[0], w["down"].shape[-1]), F32)
-    return jax.lax.fori_loop(0, -(-jnp.sum(load) // rows), add, y0)
+    return jax.lax.fori_loop(first, -(-jnp.sum(load) // rows), add, y0)
 
 
-def _held_blocks_fwd(w, x, order, load, pair_w, k, rows):
-    return (_held_blocks(w, x, order, load, pair_w, k, rows),
+def _held_blocks_fwd(w, x, order, load, pair_w, k, rows, first):
+    return (_held_blocks(w, x, order, load, pair_w, k, rows, first),
             (w, x, order, load, pair_w))
 
 
-def _held_blocks_bwd(k, rows, res, dy):
+def _held_blocks_bwd(k, rows, first, res, dy):
     w, x, order, load, pair_w = res
 
     def back(i, carry):
@@ -716,12 +907,112 @@ def _held_blocks_bwd(k, rows, res, dy):
         return dx.at[tokens].add(dxs), dw.at[mine].add(dws)
 
     dx, dw = jax.lax.fori_loop(
-        0, -(-jnp.sum(load) // rows), back,
+        first, -(-jnp.sum(load) // rows), back,
         (jnp.zeros_like(x), jnp.zeros_like(pair_w)))
     return None, dx, None, None, dw
 
 
 _held_blocks.defvjp(_held_blocks_fwd, _held_blocks_bwd)
+
+
+def _front_rows(order, load, pair_w, k: int, front: int):
+    """The front's rows: the first `front` sorted pairs and one tile behind
+    them that is in no group and reads 0. -> (the rows' tokens, their pairs'
+    weights, rows a matrix is given, which rows hold a held pair, pos
+    int32[T * k]: the row a pair sorted to, or the last row where it is not
+    among them). The rows behind the last held pair, pairs of absent
+    experts with weight 0, are given to the last held expert: every row of
+    the front is computed, so the products cost the same whatever the
+    routers do, in every round and at every seed, until the held pairs
+    outgrow the front."""
+    mine = jnp.pad(order[:front], (0, FRONT_ROWS))
+    ends = jnp.cumsum(load)
+    sizes = (jnp.clip(ends, 0, front)
+             - jnp.clip(ends - load, 0, front)).astype(jnp.int32)
+    n = jnp.sum(sizes)
+    place = jnp.argsort(order).astype(jnp.int32)             # the inverse
+    return (mine // k, pair_w[mine], sizes.at[-1].add(front - n),
+            jnp.arange(mine.shape[0]) < n,
+            jnp.where(place < n, place, mine.shape[0] - 1))  # held sort first
+
+
+def _sum_by_token(rows, pos, k: int):
+    """out[t] = sum over token t's k pairs of rows[pos[t * k + j]], the last
+    row of `rows` reading 0: the un-sort as gathers (a scatter-added row
+    costs the chip six times a gathered one). A token's `SUM_LEAD` first
+    rows that are not the last are gathered for every token; the few pairs
+    of tokens with more, sorted ahead of the rest, are added a block at a
+    time, for as many blocks as hold one."""
+    dummy = rows.shape[0] - 1
+    cols = jnp.sort(pos.reshape(-1, k), axis=1)    # the last row sorts behind
+    lead = min(k, SUM_LEAD)
+    y = rows[cols[:, :lead]].sum(1)
+    if lead == k:
+        return y
+    late = cols[:, lead:].reshape(-1)
+    late = jnp.pad(late, (0, (-late.shape[0]) % SUM_BLOCK),
+                   constant_values=dummy)
+    order = jnp.argsort(late)
+    last = y.shape[0] - 1
+
+    def add(i, y):
+        at = jax.lax.dynamic_slice(order, (i * SUM_BLOCK,), (SUM_BLOCK,))
+        return y.at[jnp.minimum(at // (k - lead), last)].add(rows[late[at]])
+
+    return jax.lax.fori_loop(
+        0, -(-jnp.sum(late < dummy) // SUM_BLOCK), add, y)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_front(w, x, order, load, pair_w, k: int, front: int):
+    """`held_experts` over the first `front` sorted pairs (`order` as it is
+    sorted, unpadded) in one grouped product a matrix, every row of it
+    computed (`_front_rows`): a fixed capacity with nothing dropped, since
+    the pairs behind it keep the blocks. The products, the rows' gather, the
+    activation and the un-sort cost the same whatever the routers do. Its
+    gradient, with respect to x and the pairs' weights, makes the rows
+    again. -> y f32[T, D]."""
+    return _held_front_fwd(w, x, order, load, pair_w, k, front)[0]
+
+
+def _held_front_fwd(w, x, order, load, pair_w, k, front):
+    tokens, ws, sizes, live, pos = _front_rows(order, load, pair_w, k, front)
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        gu = _gmm_call(x.astype(BF16)[tokens], w["gate_up"], sizes, False,
+                       FRONT_ROWS)
+    f = gu.shape[-1] // 2
+    hid = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(BF16)
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        ys = _gmm_call(hid, w["down"], sizes, False, FRONT_ROWS)
+    # (rows behind the last held pair read 0: their weight is 0, and the
+    # tile behind the front is not written)
+    ys = jnp.where(live[:, None], ys * ws[:, None], 0.0)
+    return _sum_by_token(ys, pos, k), (w, x, tokens, ws, sizes, live, pos)
+
+
+def _held_front_bwd(k, front, res, dy):
+    w, x, tokens, ws, sizes, live, pos = res
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        gu = _gmm_call(x.astype(BF16)[tokens], w["gate_up"], sizes, False,
+                       FRONT_ROWS)
+        # dy @ down^T a row; the pair's weight comes in behind it
+        dh = _gmm_call(dy.astype(BF16)[tokens], w["down"], sizes, True,
+                       FRONT_ROWS)
+    f = gu.shape[-1] // 2
+    g, u = gu[:, :f], gu[:, f:]
+    s = jax.nn.sigmoid(g)
+    act = g * s
+    dws = jnp.sum((act * u).astype(BF16).astype(F32) * dh, -1)
+    dh = dh * ws[:, None]
+    dgu = jnp.concatenate([dh * u * (s + act * (1.0 - s)), dh * act],
+                          -1).astype(BF16)
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        dxs = _gmm_call(dgu, w["gate_up"], sizes, True, FRONT_ROWS)
+    dx = _sum_by_token(jnp.where(live[:, None], dxs, 0.0), pos, k)
+    return None, dx, None, None, jnp.where(live, dws, 0.0)[pos]
+
+
+_held_front.defvjp(_held_front_fwd, _held_front_bwd)
 
 
 def expert_layer(arch: LMArch, w, router, x):
@@ -730,16 +1021,22 @@ def expert_layer(arch: LMArch, w, router, x):
     flat = x.reshape(b * s, d)
     idx, weights = route(arch, router, w["bias"], flat)
     y, load = held_experts(arch, w["experts"], flat, idx, weights)
-    y = y + glu(w["shared"], flat)
+    if arch.shared_experts:
+        y = y + glu(w["shared"], flat)
     return y.reshape(b, s, d), load, idx
 
 
-def block(arch: LMArch, w, g, h):
+def block(arch: LMArch, w, g, h, kind: int | None = None):
     """One transformer block on the float32 residual stream h. A block with
     `experts` among its frozen matrices is an expert block (its trained
-    leaves then hold the router). -> (h, (load, selections) or None, the
+    leaves then hold the router); `kind` is its place in `layer_pattern`
+    where attention is grouped. -> (h, (load, selections) or None, the
     pairs its indexer picked or None)."""
-    a, count = _attend(arch, w["attn"], g, rms_norm(h, g["ln_attn"], arch.eps))
+    x = rms_norm(h, g["ln_attn"], arch.eps)
+    if arch.kv_heads:
+        a, count = grouped_attention(arch, kind, w["attn"], g, x), None
+    else:
+        a, count = _attend(arch, w["attn"], g, x)
     h = h + a
     x = rms_norm(h, g["ln_mlp"], arch.eps)
     if "experts" in w:
@@ -757,6 +1054,9 @@ def _leaf_shapes(arch: LMArch, vocab: int):
     """(base shapes, trained shapes) as pytrees of tuples."""
     d, h = arch.hidden, arch.heads
     dn, dr, dv = arch.qk_nope_head_dim, arch.qk_rope_head_dim, arch.v_head_dim
+    gains = {"ln_attn": (d,), "ln_mlp": (d,)}
+    if arch.kv_heads:
+        return _grouped_leaf_shapes(arch, vocab, gains)
     attn = {"q_a": (d, arch.q_lora_rank),
             "q_b": (arch.q_lora_rank, h * (dn + dr)),
             "kv_a": (d, arch.kv_lora_rank + dr),
@@ -766,8 +1066,7 @@ def _leaf_shapes(arch: LMArch, vocab: int):
         hi, di = arch.index_heads, arch.index_head_dim
         attn["index"] = {"q": (arch.q_lora_rank, hi * di), "k": (d, di),
                          "k_gain": (di,), "k_bias": (di,), "w": (d, hi)}
-    gains = {"ln_attn": (d,), "ln_mlp": (d,), "q_norm": (arch.q_lora_rank,),
-             "kv_norm": (arch.kv_lora_rank,)}
+    gains = dict(gains, q_norm=(arch.q_lora_rank,), kv_norm=(arch.kv_lora_rank,))
     f, e = arch.moe_intermediate, arch.held_experts
     dense = {"attn": attn, "mlp": {"gate_up": (d, 2 * arch.intermediate),
                                    "down": (arch.intermediate, d)}}
@@ -784,6 +1083,32 @@ def _leaf_shapes(arch: LMArch, vocab: int):
                "mtp": {"hnorm": (d,), "enorm": (d,), "norm": (d,),
                        "block": moe_g}}
     return base, trained
+
+
+def _grouped_leaf_shapes(arch: LMArch, vocab: int, gains):
+    """`_leaf_shapes` of a model whose attention is grouped: a layer's `k`
+    and `v` are as wide as its kind's KV heads, a kind with sinks trains one
+    a head, and there is neither a shared expert nor a prediction module."""
+    d, h, dv = arch.hidden, arch.heads, arch.v_head_dim
+    dq = arch.qk_nope_head_dim + arch.qk_rope_head_dim
+    f, e = arch.moe_intermediate, arch.held_experts
+    blocks, blocks_g = [], []
+    for layer, kind in enumerate(arch.layer_pattern):
+        kv = arch.kv_heads[kind]
+        w = {"attn": {"q": (d, h * dq), "k": (d, kv * dq), "v": (d, kv * dv),
+                      "o": (h * dv, d)}}
+        g = dict(gains, sink=(h,)) if arch.sinks[kind] else dict(gains)
+        if layer < arch.dense_layers:
+            w["mlp"] = {"gate_up": (d, 2 * arch.intermediate),
+                        "down": (arch.intermediate, d)}
+        else:
+            w["experts"] = {"gate_up": (e, d, 2 * f), "down": (e, f, d)}
+            w["bias"] = (arch.n_experts,)
+            g["router"] = (arch.n_experts, d)
+        blocks.append(w)
+        blocks_g.append(g)
+    return ({"embed": (vocab, d), "head": (d, vocab), "blocks": blocks},
+            {"blocks": blocks_g, "final_norm": (d,)})
 
 
 _is_shape = lambda t: isinstance(t, tuple)  # noqa: E731
@@ -805,14 +1130,17 @@ class FrozenBaseLM:
     # ---- parameters --------------------------------------------------------
 
     def init_trained(self, key=None):
-        """The trained subset at its start: gains 1, routers normal(std)."""
+        """The trained subset at its start: gains 1, sinks 0, routers
+        normal(std)."""
         key = jax.random.key(self.seed) if key is None else key
         shapes = _leaf_shapes(self.arch, self.num_classes)[1]
-        leaves, tree = jax.tree_util.tree_flatten(shapes, is_leaf=_is_shape)
+        leaves, tree = jax.tree_util.tree_flatten_with_path(
+            shapes, is_leaf=_is_shape)
         out = [
-            jnp.ones(s, F32) if len(s) == 1 else self.arch.init_std
+            jnp.zeros(s, F32) if "sink" in jax.tree_util.keystr(path)
+            else jnp.ones(s, F32) if len(s) == 1 else self.arch.init_std
             * jax.random.normal(jax.random.fold_in(key, i), s, F32)
-            for i, s in enumerate(leaves)]
+            for i, (path, s) in enumerate(leaves)]
         return jax.tree_util.tree_unflatten(tree, out)
 
     def init_base(self, key=None):
@@ -836,64 +1164,74 @@ class FrozenBaseLM:
 
     def hidden(self, variables, tokens, normed: bool = True):
         """tokens int[B, S + 2] -> (h_main, h_mtp, routed, picked): the two
-        heads' normed inputs, float32 [B, S, D]; of every expert layer (the
-        prediction module's last) the load int32[layers, held] and the
-        selections int32[layers, T, k]; and of every attention layer the
-        (query, key) pairs its indexer picked, int32[layers], or None for
-        a model without one. Without `normed` the two heads' inputs come
+        heads' normed inputs, float32 [B, S, D] (h_mtp None for a model
+        without a prediction module); of every expert layer (the prediction
+        module's last) the load int32[layers, held] and the selections
+        int32[layers, T, k]; and of every attention layer the (query, key)
+        pairs its indexer picked, int32[layers], or None for a model
+        without one. Without `normed` the two heads' inputs come
         before their last norms and the prediction module's input is made
         again for the gradient (`loss`, where a [tokens, hidden] array is
         large)."""
         arch, p, base = self.arch, variables["params"], variables["base"]
         s = tokens.shape[1] - 2
         emb = lambda t: base["embed"][t].astype(F32)  # noqa: E731
-        blk = jax.checkpoint(
-            lambda w, g, h: block(arch, w, g, h), policy=_kept(arch))
+        kinds = arch.layer_pattern or (None,) * len(base["blocks"])
+        blk = {kind: jax.checkpoint(
+            lambda w, g, h, kind=kind: block(arch, w, g, h, kind),
+            policy=_kept(arch)) for kind in set(kinds)}   # one a layer kind
         h, routed, picked = emb(tokens[:, :s]), [], []
-        for w, g in zip(base["blocks"], p["blocks"]):
-            h, seen, count = blk(w, g, h)
+        for kind, w, g in zip(kinds, base["blocks"], p["blocks"]):
+            h, seen, count = blk[kind](w, g, h)
             picked.append(count)
             if seen is not None:
                 routed.append(seen)
         h_main = rms_norm(h, p["final_norm"], arch.eps) if normed else h
-        with jax.named_scope(obs_scopes.MTP):
-            m, mb = p["mtp"], base["mtp"]
-            joined = lambda h, m: _mm(jnp.concatenate(  # noqa: E731
-                [rms_norm(h, m["hnorm"], arch.eps),
-                 rms_norm(emb(tokens[:, 1:s + 1]), m["enorm"], arch.eps)], -1),
-                mb["eh"])
-            h2, seen, count = blk(
-                mb["block"], m["block"],
-                joined(h, m) if normed else jax.checkpoint(joined)(h, m))
-            h_mtp = rms_norm(h2, m["norm"], arch.eps) if normed else h2
-        routed.append(seen)
-        picked.append(count)
-        # every block's attention is the fused kernel, causal or over the
-        # indexer's selection
+        h_mtp = None
+        if arch.mtp_modules:
+            with jax.named_scope(obs_scopes.MTP):
+                m, mb = p["mtp"], base["mtp"]
+                joined = lambda h, m: _mm(jnp.concatenate(  # noqa: E731
+                    [rms_norm(h, m["hnorm"], arch.eps),
+                     rms_norm(emb(tokens[:, 1:s + 1]), m["enorm"], arch.eps)],
+                    -1), mb["eh"])
+                h2, seen, count = blk[None](
+                    mb["block"], m["block"],
+                    joined(h, m) if normed else jax.checkpoint(joined)(h, m))
+                h_mtp = rms_norm(h2, m["norm"], arch.eps) if normed else h2
+            routed.append(seen)
+            picked.append(count)
+        # every block's attention is a fused kernel: causal, over the
+        # indexer's selection, or grouped (causal or over a window)
         obs_metrics.gauge("model.fused_attention_layers").set(
-            len(base["blocks"]) + 1)
+            len(base["blocks"]) + arch.mtp_modules)
         obs_metrics.gauge("model.sparse_attention_layers").set(
             len(picked) if arch.index_topk else 0)
+        obs_metrics.gauge("model.window_attention_layers").set(
+            sum(arch.layer_pattern))
         return (h_main, h_mtp, (jnp.stack([r[0] for r in routed]),
                                 jnp.stack([r[1] for r in routed])),
                 jnp.stack(picked) if arch.index_topk else None)
 
     def apply(self, variables, tokens, routed: bool = False):
-        """-> (logits of the main head, of the prediction module), float32
-        [B, S, vocab]: position i predicts token i + 1 and token i + 2.
-        With `routed` also `hidden`'s (loads, selections)."""
+        """-> (logits of the main head, of the prediction module or None),
+        float32 [B, S, vocab]: position i predicts token i + 1 and token
+        i + 2. With `routed` also `hidden`'s (loads, selections)."""
         h_main, h_mtp, seen, _ = self.hidden(variables, tokens)
         with jax.named_scope(obs_scopes.LM_HEAD):
             head = variables["base"]["head"]
-            out = _mm(h_main, head), _mm(h_mtp, head)
+            out = (_mm(h_main, head),
+                   None if h_mtp is None else _mm(h_mtp, head))
             return out + (seen,) if routed else out
 
     def loss(self, variables, tokens):
         """-> (CE_main + mtp_weight * CE_mtp, (CE_main, next-token accuracy,
-        loads)), means over every position of every sequence. The logits are
+        loads)), means over every position of every sequence; CE_main alone
+        for a model without a prediction module. The logits are
         made a slice of `loss_chunk` tokens at a time and made again for the
         gradient: no [tokens, vocab] array outlives its slice. A model with
-        an indexer appends four columns to `loads` (`COUNTED`)."""
+        an indexer or with grouped attention appends four columns to `loads`
+        (`COUNTED`)."""
         arch, p = self.arch, variables["params"]
         s = tokens.shape[1] - 2
         # a float32 [tokens, hidden] array over `STREAM_BYTES` is not kept
@@ -902,16 +1240,20 @@ class FrozenBaseLM:
         lean = tokens.shape[0] * s * arch.hidden * 4 > STREAM_BYTES
         h_main, h_mtp, (loads, _), picked = self.hidden(variables, tokens,
                                                         normed=not lean)
-        if picked is not None:
+        if picked is not None or arch.kv_heads:
             loads = _with_counts(arch, loads, picked, *tokens.shape)
         head = variables["base"]["head"]
         if lean:
             normed_ce = jax.checkpoint(lambda h, gain, t: _head_ce(
                 rms_norm(h, gain, arch.eps), head, t, arch.loss_chunk))
             ce, acc = normed_ce(h_main, p["final_norm"], tokens[:, 1:s + 1])
-            ce2, _ = normed_ce(h_mtp, p["mtp"]["norm"], tokens[:, 2:s + 2])
         else:
             ce, acc = _head_ce(h_main, head, tokens[:, 1:s + 1], arch.loss_chunk)
+        if h_mtp is None:
+            return ce, (ce, acc, loads)
+        if lean:
+            ce2, _ = normed_ce(h_mtp, p["mtp"]["norm"], tokens[:, 2:s + 2])
+        else:
             ce2, _ = _head_ce(h_mtp, head, tokens[:, 2:s + 2], arch.loss_chunk)
         return ce + arch.mtp_weight * ce2, (ce, acc, loads)
 
@@ -919,7 +1261,7 @@ class FrozenBaseLM:
 JoyAIFlash = FrozenBaseLM   # the name the first model of this file came under
 
 
-COUNTED = 4   # columns a model with an indexer appends to `loss`'s loads
+COUNTED = 4   # columns `_with_counts` appends to `loss`'s loads
 
 
 def _with_counts(arch: LMArch, loads, picked, sequences: int, length: int):
@@ -927,16 +1269,24 @@ def _with_counts(arch: LMArch, loads, picked, sequences: int, length: int):
     so that a sum over sequences stays negative), the rows the layer's
     grouped product was given, the (query, key) pairs its block's indexer
     picked and the causal pairs they were picked from (an expert layer its
-    own block's; the dense layers' go to row 0). What `record_expert_load`
-    turns into gauges."""
+    own block's; the dense layers' go to row 0; both 0 where `picked` is
+    None: a model without an indexer). What `record_expert_load` turns into
+    gauges."""
     s, n = length - 2, loads.shape[0]
-    blocks, rows = pair_blocks(arch, sequences * s * arch.experts_per_tok)
+    pairs = sequences * s * arch.experts_per_tok
+    blocks, rows = pair_blocks(arch, pairs)
+    front = front_pairs(arch, pairs) // rows      # blocks `_held_front` takes
     held = jnp.sum(loads, -1)
     given = rows * jnp.clip(-(-held // rows), 0 if blocks > 1 else 1, blocks)
-    front = picked.shape[0] - n                      # the dense layers
-    mine = picked[front:].at[0].add(jnp.sum(picked[:front]))
-    causal = jnp.full((n,), sequences * s * (s + 1) // 2, jnp.int32).at[0].mul(
-        front + 1)
+    if front:
+        given = (jnp.maximum(given, front * rows) + FRONT_ROWS).astype(given.dtype)
+    if picked is None:
+        mine = causal = jnp.zeros((n,), jnp.int32)
+    else:
+        front = picked.shape[0] - n                      # the dense layers
+        mine = picked[front:].at[0].add(jnp.sum(picked[:front]))
+        causal = jnp.full((n,), sequences * s * (s + 1) // 2, jnp.int32).at[
+            0].mul(front + 1)
     return jnp.concatenate(
         [loads, jnp.stack([jnp.full((n,), -1, jnp.int32), given, mine, causal],
                           -1)], -1)
@@ -1027,10 +1377,10 @@ def set_frozen_base(module, base) -> None:
 def record_expert_load(loads) -> None:
     """Gauge `moe.load_max_over_mean`: the busiest held expert's pairs over
     the mean, worst layer, of an evaluation forward. From the columns a
-    model with an indexer appends (`_with_counts`; the marker is negative)
-    also `moe.rows_over_held_pairs` (rows given to the grouped product over
-    pairs held, worst layer) and `dsa.selected_share` (picked over causal
-    (query, key) pairs, percent)."""
+    model appends that has an indexer or grouped attention (`_with_counts`;
+    the marker is negative) also `moe.rows_over_held_pairs` (rows given to
+    the grouped product over pairs held, worst layer) and, with an indexer,
+    `dsa.selected_share` (picked over causal (query, key) pairs, percent)."""
     import numpy as np
 
     loads = np.asarray(loads, np.float64)
@@ -1039,8 +1389,9 @@ def record_expert_load(loads) -> None:
         loads = loads[:, :-COUNTED]
         obs_metrics.gauge("moe.rows_over_held_pairs").set(
             float(np.max(given / np.maximum(loads.sum(-1), 1.0))))
-        obs_metrics.gauge("dsa.selected_share").set(
-            100.0 * float(picked.sum() / causal.sum()))
+        if causal.sum():
+            obs_metrics.gauge("dsa.selected_share").set(
+                100.0 * float(picked.sum() / causal.sum()))
     mean = np.maximum(loads.mean(-1), 1e-9)
     obs_metrics.gauge("moe.load_max_over_mean").set(
         float(np.max(loads.max(-1) / mean)))

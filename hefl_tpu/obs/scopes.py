@@ -49,6 +49,8 @@ MOE_EXPERTS = "hefl.moe.experts"      # sort + grouped product of the held exper
 MOE_GMM = "hefl.moe_gmm"              # the grouped product's Pallas calls alone
 DSA_INDEX = "hefl.dsa.index"          # the indexer's scores and its selection
 DSA_ATTEND = "hefl.dsa.attend"        # attention over the selected keys alone
+GQA = "hefl.gqa"                      # grouped-query attention: projections, RoPE, softmax
+SWA_ATTEND = "hefl.swa.attend"        # a window layer's fused attention calls
 MTP = "hefl.mtp"                      # the multi-token-prediction module
 LM_HEAD = "hefl.lm_head"              # head logits + cross-entropy, by slices
 
@@ -83,6 +85,8 @@ PHASES = (
     MOE_GMM,
     DSA_INDEX,
     DSA_ATTEND,
+    GQA,
+    SWA_ATTEND,
     MTP,
     LM_HEAD,
 )
