@@ -28,7 +28,11 @@ of four lane groups. Same parameters, same products summed, the same
 first-maximum pool gradient; at 256x256x3 the first stage takes 4x4 blocks
 and hands its pooled map, still in 2x2 form, to the second (2x2 blocks),
 and the step went 47.1 -> 14.4 ms on a TPU v5e, then 12.8 ms with the pool
-as one pass over the stage's arrays each way (`_relu_pool4`, PR 32).
+as one pass over the stage's arrays each way (`_relu_pool4`, PR 32). In a
+polyphase stage the bias is added inside that pool, not by the lowering, so
+that its gradient is a sum over the pooled cotangent's n lanes and not over
+the 4n-lane cotangent that is never written (PR 35; PERF.md section 6 has
+what the step takes since).
 """
 
 from __future__ import annotations
@@ -106,34 +110,70 @@ def _to_depth(x, depth: int, hw, s: int, nb: int, mb: int):
 
 
 @jax.custom_vjp
-def _relu_pool4(y):
-    """ReLU of the maximum over the four pool phases, y[..., (p, g)] ->
-    [..., g], as lane slices (whole tiles at block 4), with the gradient of
-    `relu` then `reduce_window` max: a window's cotangent goes whole to its
-    FIRST maximum in window order (0,0), (0,1), (1,0), (1,1), and nowhere
-    if that maximum is not positive. `jnp.max` would split it between ties,
-    which bf16 activations make common, and the training step would no
-    longer be the reference's. The backward needs the winning phase alone
-    (int8), not the activation.
+def _relu_pool4(y, b):
+    """ReLU of the maximum over the four pool phases of `y + b`,
+    y[..., (p, q, c)] + b[..., c] -> [..., (q, c)], as lane slices (whole
+    tiles at block 4), with the gradient of `relu` then `reduce_window` max: a
+    window's cotangent goes whole to its FIRST maximum in window order
+    (0,0), (0,1), (1,0), (1,1), and nowhere if that maximum is not positive.
+    `jnp.max` would split it between ties, which bf16 activations make
+    common, and the training step would no longer be the reference's. The
+    backward needs the winning phase (int8) and the pooled output (which the
+    next stage keeps anyway), not the activation.
 
-    The form is written for what XLA makes of it (PERF.md section 5, PR 32;
-    `tests/test_tpu_compile.py` holds it), one pass over the stage's arrays
-    each way. Forward, ONE comparison tree gives the maximum and the winning
-    phase (ties to the lower index at every node), so they come out of one
-    fusion that reads `y` once and `y` dies there. As the maximum and then
-    four compares against it, XLA sank the phase's whole chain into the
+    y: [B, i, j, 4n], a conv output without its bias, lanes (pool phase,
+    block position, channel); b: [..., c], the channels' bias in `y`'s
+    dtype (c divides n), with whatever leading axes split `B` (none under
+    `vmap`; the client axis of the `fused` lowering's client-folded batch,
+    `_per_client`). The sum is the `conv -> + bias` that `_conv_bf16`
+    states, rounded once in `y`'s dtype.
+
+    The form is written for what XLA makes of it (PERF.md section 5, PRs 32
+    and 35; `tests/test_tpu_compile.py` holds it), one pass over the stage's
+    arrays each way. Forward, ONE comparison tree gives the maximum and the
+    winning phase (ties to the lower index at every node), so they come out
+    of one fusion that reads `y` once and `y` dies there. As the maximum and
+    then four compares against it, XLA sank the phase's whole chain into the
     backward, kept `y` (520 MB a step at MedCNN's first stage) live until
     then and read it again. Backward, ONE select over the whole lane width
     against a constant lane-phase index, which XLA fuses into the operand
     of the convolutions that take the cotangent (kernel and input gradient),
-    so the [..., 4g] cotangent is never written. As four selects and a
-    concatenation it wrote four arrays and read them back twice."""
-    return _relu_pool4_fwd(y)[0]
+    so the [..., 4n] cotangent is never written. As four selects and a
+    concatenation it wrote four arrays and read them back twice.
+
+    Why the bias is an argument (PR 35). Added to `y` by the lowering, its
+    gradient was JAX's transpose of that broadcast add: a `reduce_sum` over
+    the 4n-lane cotangent, which exists nowhere, so XLA made the select
+    again in two reductions of their own, four times the lanes the
+    information has, on the VPU: 12% of MedCNN's step on the chip. Every
+    window sends its cotangent to one phase or to none, so the same sum is
+    one over n lanes of the pooled cotangent where the window fired, and the
+    pool returns it. The mask is `out > 0`, not `first < 4`, though they are
+    the same array: with `first`, XLA pulled `first`'s producer into the
+    backward and held `y` live again (7.42 GB a step by the described
+    compile against the 6.905 before and the 6.58 of this form); behind
+    `lax.optimization_barrier((out, first))` the forward split into two
+    fusions that each read `y` (7.48). With `out`, the forward is what it
+    was, the add in the convolution's epilogue, and the sum rides the
+    input-gradient convolution that makes `g` as a second output. The bias
+    is added to the whole lane width at once, tiled from its channels in
+    one `jnp.tile` (a reshape to XLA; tiled in two steps it was a copy of
+    its own): added to the four slices, XLA gave `y` another layout and the
+    step read 7.6 GB."""
+    return _relu_pool4_fwd(y, b)[0]
 
 
-def _relu_pool4_fwd(y):
-    g = y.shape[-1] // 4
-    p0, p1, p2, p3 = (y[..., p * g : (p + 1) * g] for p in range(4))
+def _per_client(a, b):
+    """`a` [C*B, ...] with the leading axes of the bias `b` [..., n] split
+    off its batch: itself under `vmap` (b: [n]), [C, B, ...] for the
+    client-folded batch of the `fused` lowering, whose bias is [C, n]."""
+    return a.reshape(*b.shape[:-1], -1, *a.shape[1:])
+
+
+def _relu_pool4_fwd(y, b):
+    n = y.shape[-1] // 4
+    yb = _per_client(y, b) + jnp.expand_dims(jnp.tile(b, 4 * n // b.shape[-1]), (-4, -3, -2))
+    p0, p1, p2, p3 = (yb[..., p * n : (p + 1) * n] for p in range(4))
     m01, m23 = jnp.maximum(p0, p1), jnp.maximum(p2, p3)
     row0 = m01 >= m23  # ties to the lower index at every node: the FIRST maximum
     first = jnp.where(
@@ -143,16 +183,23 @@ def _relu_pool4_fwd(y):
     )
     top = jnp.where(row0, m01, m23)
     pos = top > 0
-    return jnp.where(pos, top, 0), jnp.where(pos, first, jnp.int8(4))
+    out = jnp.where(pos, top, 0).reshape(*y.shape[:-1], n)
+    first = jnp.where(pos, first, jnp.int8(4)).reshape(out.shape)
+    return out, (first, out, b)
 
 
-def _relu_pool4_bwd(first, g):
+def _relu_pool4_bwd(res, g):
+    first, out, b = res
     n = g.shape[-1]
     phase = jnp.asarray(np.arange(4 * n) // n, jnp.int8)
     # four copies side by side, not `jnp.tile`: behind its broadcast and
     # reshape XLA fuses nothing and the step reads more than before PR 32
     first4, g4 = (jnp.concatenate([a] * 4, axis=-1) for a in (first, g))
-    return (jnp.where(first4 == phase, g4, 0),)
+    # `out > 0` is `first < 4`, read from the array the compiler keeps
+    passed = _per_client(jnp.where(out > 0, g, 0), b)
+    db = jnp.sum(passed, axis=(-4, -3, -2)).astype(b.dtype)
+    db = db.reshape(*b.shape[:-1], -1, b.shape[-1]).sum(axis=-2)  # block positions
+    return jnp.where(first4 == phase, g4, 0), db
 
 
 _relu_pool4.defvjp(_relu_pool4_fwd, _relu_pool4_bwd)
@@ -169,6 +216,10 @@ def _polyphase_stage(conv, xs, kernel, bias, s: int):
     and bias: [..., Co], with whatever leading axes `conv(x, kernel, bias)`
     takes. -> the pooled map in s/2 form, [B, nb, mb, (s/2)**2 * Co]: plain
     at s = 2, and at s = 4 the very form a following block-2 stage takes.
+
+    The lowering is called WITHOUT the bias: the pool adds it, in the conv
+    output's dtype, at every phase and block position, and returns its
+    cotangent from the pooled cotangent. `_relu_pool4` says why.
     """
     ci, co = kernel.shape[-2:]
     # k2[u, v, (dy, dx, ci), (py, px, qy, qx, co)]
@@ -181,7 +232,8 @@ def _polyphase_stage(conv, xs, kernel, bias, s: int):
     k2 = k2.reshape(*kernel.shape[:-4], 2, 2, s * s * ci, s * s * co)
     # y[b, i, j, (py, px, qy, qx, co)] = the plain conv's output at
     # (s*i + 2*qy + py, s*j + 2*qx + px)
-    return _relu_pool4(conv(xs, k2, jnp.tile(bias, s * s)))
+    y = conv(xs, k2, None)
+    return _relu_pool4(y, bias.astype(y.dtype))
 
 
 def _conv_stages(conv, x, layers):
@@ -213,12 +265,15 @@ def _conv_stages(conv, x, layers):
 
 def _conv_bf16(x, kernel, bias):
     """flax.linen.Conv(dtype=bfloat16)'s computation: operands and bias
-    rounded to bf16, one 3x3 VALID NHWC convolution, bias added in bf16."""
-    x, kernel, bias = (a.astype(jnp.bfloat16) for a in (x, kernel, bias))
+    rounded to bf16, one 3x3 VALID NHWC convolution, bias added in bf16.
+    `bias=None` (as `folded_conv` takes it) leaves the sum to the caller: a
+    polyphase stage adds the same bf16 bias to the same bf16 output inside
+    `_relu_pool4`, which makes the bias gradient where it costs least."""
     y = lax.conv_general_dilated(
-        x, kernel, (1, 1), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC")
+        x.astype(jnp.bfloat16), kernel.astype(jnp.bfloat16), (1, 1), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )
-    return y + bias
+    return y if bias is None else y + bias.astype(jnp.bfloat16)
 
 
 class _ConvParams(nn.Module):

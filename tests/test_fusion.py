@@ -104,6 +104,43 @@ def test_folded_apply_matches_vmap_forward_medcnn():
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got), atol=5e-3)
 
 
+def test_folded_polyphase_stage_matches_vmap_loss_and_every_gradient():
+    # PR 35: in a polyphase stage the bias is added inside the pool, which
+    # returns the bias gradient itself. Under the `fused` lowering that bias
+    # has a client axis over a client-folded batch: SmallCNN at 100x100 takes
+    # block 4 in its first stage, and every leaf's gradient, the bias leaves
+    # included, must be `vmap`'s (clients with distinct weights and biases).
+    import optax
+
+    c, b, shape = 3, 4, (100, 100, 1)
+    model = SmallCNN(num_classes=10)
+    ps = _stacked(model, shape, c)
+    ps = jax.tree_util.tree_map(  # flax starts a bias at zero: move it
+        lambda t: t + 0.1 * jax.random.normal(jax.random.key(t.size), t.shape)
+        if t.ndim == 2 else t, ps)
+    x = jax.random.uniform(jax.random.key(1), (c, b) + shape)
+    labels = jax.random.randint(jax.random.key(2), (c, b), 0, 10)
+
+    def xent(logits):  # [C, B, classes] -> the clients' mean losses, summed
+        return optax.softmax_cross_entropy_with_integer_labels(logits, labels).mean(-1).sum()
+
+    vmapped = lambda ps: xent(jax.vmap(  # noqa: E731
+        lambda p, xx: model.apply({"params": p}, xx))(ps, x))
+    fused = lambda ps: xent(unfold_clients(  # noqa: E731
+        model.folded_apply(ps, fold_clients(x), num_clients=c), c))
+    (l_ref, g_ref), (l_got, g_got) = (
+        jax.jit(jax.value_and_grad(f))(ps) for f in (vmapped, fused))
+    np.testing.assert_allclose(float(l_got), float(l_ref), rtol=1e-3)
+    flat_ref = jax.tree_util.tree_flatten_with_path(g_ref)[0]
+    assert len(flat_ref) == 8
+    for (path, ga), gb in zip(flat_ref, jax.tree_util.tree_leaves(g_got)):
+        scale = float(jnp.max(jnp.abs(ga)))
+        assert scale > 0, path
+        np.testing.assert_allclose(
+            np.asarray(ga) / scale, np.asarray(gb) / scale, atol=2e-2,
+            err_msg=jax.tree_util.keystr(path))
+
+
 @pytest.mark.parametrize("strides,padding", [((1, 1), "VALID"), ((2, 2), "SAME")])
 def test_folded_conv_matches_flax_forward_and_grad(strides, padding):
     import flax.linen as nn

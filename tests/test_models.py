@@ -92,10 +92,11 @@ def test_models_are_deterministic_pure_functions():
 
 
 def _conv_f32(x, kernel, bias):
-    return lax.conv_general_dilated(
+    y = lax.conv_general_dilated(
         x, kernel, (1, 1), "VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC"), precision="highest",
-    ) + bias
+    )
+    return y if bias is None else y + bias
 
 
 def _plain_stage(x, kernel, bias):
@@ -207,6 +208,11 @@ def _pool_windows(case, n, key):
     return y.astype(jnp.bfloat16).reshape(2, 5, 7, 4 * n)
 
 
+def _bits(a):
+    """An array's bits as integers, so that -0.0 and 0.0 differ."""
+    return np.asarray(a).view({1: np.int8, 2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
 @pytest.mark.parametrize("lanes", [32, 128])
 @pytest.mark.parametrize(
     "case", ["ties2", "ties3", "ties4", "negative", "zeros", "random"]
@@ -217,16 +223,17 @@ def test_relu_pool4_is_bit_for_bit_the_formulation_it_replaced(case, lanes):
     # map, the saved winning phase and the cotangent, every bit of them.
     y = _pool_windows(case, lanes, jax.random.key(lanes + len(case)))
     ct = jax.random.normal(jax.random.key(3), (2, 5, 7, lanes)).astype(jnp.bfloat16)
-
-    def bits(a):
-        return np.asarray(a).view(np.uint16 if a.dtype == jnp.bfloat16 else np.int8)
+    # PR 35 made the bias the pool's second argument; -0.0 is the bias that
+    # leaves every bit of `y` as it is, zeros of either sign included
+    b = jnp.full((lanes,), -0.0, jnp.bfloat16)
 
     want_out, want_first = _relu_pool4_before_pr32_fwd(y)
-    got_out, got_first = cnn._relu_pool4_fwd(y)
+    got_out, (got_first, kept_out, _) = cnn._relu_pool4_fwd(y, b)
+    assert kept_out is got_out  # the second residual is the output itself
     assert got_out.dtype == want_out.dtype and got_first.dtype == jnp.int8
-    np.testing.assert_array_equal(bits(got_first), bits(want_first))
-    np.testing.assert_array_equal(bits(got_out), bits(want_out))
-    np.testing.assert_array_equal(bits(cnn._relu_pool4(y)), bits(want_out))
+    np.testing.assert_array_equal(_bits(got_first), _bits(want_first))
+    np.testing.assert_array_equal(_bits(got_out), _bits(want_out))
+    np.testing.assert_array_equal(_bits(cnn._relu_pool4(y, b)), _bits(want_out))
     if case == "negative":
         assert np.all(np.asarray(got_first) == 4)
     elif case.startswith("ties"):
@@ -235,11 +242,69 @@ def test_relu_pool4_is_bit_for_bit_the_formulation_it_replaced(case, lanes):
         assert winners == set(range(5 - int(case[-1])))
 
     (want_dy,) = _relu_pool4_before_pr32_bwd(want_first, ct)
-    vjp = lambda y, ct: jax.vjp(cnn._relu_pool4, y)[1](ct)[0]  # noqa: E731
+    vjp = lambda y, ct: jax.vjp(cnn._relu_pool4, y, b)[1](ct)[0]  # noqa: E731
     # eagerly and under jit: what XLA fuses may not change a bit either
     for got_dy in (vjp(y, ct), jax.jit(vjp)(y, ct)):
         assert got_dy.dtype == want_dy.dtype and got_dy.shape == y.shape
-        np.testing.assert_array_equal(bits(got_dy), bits(want_dy))
+        np.testing.assert_array_equal(_bits(got_dy), _bits(want_dy))
+
+
+@pytest.mark.parametrize("clients,positions", [(0, 1), (3, 1), (0, 4), (3, 4)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", ["random", "ties4", "negative", "sign"])
+def test_relu_pool4_with_the_bias_inside_is_the_pool_of_y_plus_bias(case, dtype, clients, positions):
+    # PR 35: the bias is added inside the pool, which returns its cotangent
+    # from the POOLED cotangent (n lanes). Against the pool of `y + bias`
+    # (the PR 25 formulation above, which PR 32's form equals bit for bit):
+    # output, winning phase and `dy` every bit, and `db` the old bias
+    # cotangent (a sum over the 4n-lane `dy`) folded onto the channels.
+    # `clients` > 0 is the `fused` lowering's layout: a [C*B, ...] batch
+    # and a [C, c] bias, added client by client. `positions` is a block's
+    # pooled positions (4 at block 4): the n lanes of a phase are (position,
+    # channel) and the bias has the channels alone.
+    n, dtype = 32, jnp.dtype(dtype)
+    ky, kb, kc = jax.random.split(jax.random.key(len(case) + clients), 3)
+    lead = (clients,) if clients else ()
+    batch = 2 * max(clients, 1)
+    y = _pool_windows(case if case != "sign" else "negative", n, ky)
+    y = jnp.concatenate([y] * max(clients, 1)).astype(dtype)  # [batch, 5, 7, 4n]
+    # halves, so every sum below is exact in float32
+    b = jnp.round(jax.random.uniform(kb, (*lead, n // positions), minval=-2.0, maxval=2.0) * 2) / 2
+    if case == "sign":  # all of `y` is negative; about half the windows turn positive
+        b = b + 2.0
+    elif case == "negative":
+        b = -jnp.abs(b)
+    b = b.astype(dtype)
+    ct = (jnp.round(jax.random.normal(kc, (batch, 5, 7, n)) * 4) / 4).astype(dtype)
+
+    per_client = lambda a: a.reshape(*lead, -1, *a.shape[1:])  # noqa: E731
+    b4 = jnp.expand_dims(jnp.tile(b, 4 * positions), (-4, -3, -2))
+    yb = (per_client(y) + b4).reshape(y.shape)
+    want_out, want_first = _relu_pool4_before_pr32_fwd(yb)
+    (want_dy,) = _relu_pool4_before_pr32_bwd(want_first, ct)
+    fired = float(np.mean(np.asarray(want_first) < 4))
+    if case == "negative":
+        assert fired == 0
+    elif case == "sign":
+        assert 0.2 < fired < 0.8  # the bias decides the sign, both ways
+        assert np.all(np.asarray(_relu_pool4_before_pr32_fwd(y)[1]) == 4)
+
+    got_out, (got_first, _, _) = cnn._relu_pool4_fwd(y, b)
+    np.testing.assert_array_equal(_bits(got_out), _bits(want_out))
+    np.testing.assert_array_equal(_bits(got_first), _bits(want_first))
+    # the old bias cotangent: `y + tile(bias)`'s transpose, a sum over `dy`
+    old_db = np.asarray(per_client(want_dy).astype(jnp.float32)).astype(np.float64)
+    old_db = old_db.sum(axis=(-4, -3, -2)).reshape(*lead, 4 * positions, -1).sum(axis=-2)
+    vjp = lambda y, b, ct: jax.vjp(cnn._relu_pool4, y, b)[1](ct)  # noqa: E731
+    for got_dy, got_db in (vjp(y, b, ct), jax.jit(vjp)(y, b, ct)):
+        assert got_dy.dtype == dtype and got_dy.shape == y.shape
+        np.testing.assert_array_equal(_bits(got_dy), _bits(want_dy))
+        assert got_db.dtype == dtype and got_db.shape == b.shape
+        got_db = np.asarray(got_db.astype(jnp.float32)).astype(np.float64)
+        if dtype == jnp.float32:
+            np.testing.assert_array_equal(got_db, old_db)
+        else:  # one bfloat16 rounding (8 bits of significand) of the exact sum
+            assert np.all(np.abs(got_db - old_db) <= 2.0**-8 * np.abs(old_db))
 
 
 @pytest.mark.parametrize(
