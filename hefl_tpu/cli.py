@@ -11,6 +11,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import sys
 
 from hefl_tpu.experiment import ExperimentConfig, HEConfig, run_experiment
 from hefl_tpu.fl import (
@@ -98,8 +99,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "model on the whole dataset (train_server analog)")
     p.add_argument("--profile", default=None, metavar="DIR",
                    help="write a jax.profiler trace of the first round to DIR "
-                        "(obs.trace parses it into per-phase device-time "
-                        "attribution)")
+                        "and print its device seconds by scope, by kernel "
+                        "and by program (obs.trace reads them from the "
+                        ".xplane.pb of a TPU run)")
     p.add_argument("--events", default=None, metavar="PATH", dest="events",
                    help="structured run-event JSONL (obs.events). Default: "
                         "events.jsonl next to --checkpoint (else ./); "
@@ -598,7 +600,24 @@ def main(argv: list[str] | None = None) -> int:
     if args.json:
         for rec in out["history"]:
             print(json.dumps(rec))
+    if cfg.profile_dir is not None:
+        print_trace_attribution(cfg.profile_dir, as_json=args.json)
     return 0
+
+
+def print_trace_attribution(profile_dir: str, as_json: bool = False) -> None:
+    """The traced round's device seconds (`obs.trace.trace_attribution`), as
+    a table or as one JSON line. A CPU run's trace has no device plane to
+    read: that is said, and is no error of the run."""
+    from hefl_tpu.obs import trace as obs_trace
+
+    try:
+        rec = obs_trace.trace_attribution(profile_dir)
+    except (obs_trace.NoDevicePlane, obs_trace.NoScopeMetadata) as e:
+        print(f"no device seconds by scope: {e}", file=sys.stderr)
+        return
+    print(json.dumps({"trace_attribution": rec}) if as_json
+          else obs_trace.format_table(rec))
 
 
 if __name__ == "__main__":

@@ -932,8 +932,8 @@ def run_experiment(
         if profiling:
             jax.profiler.stop_trace()
             say(f"profiler trace written to {cfg.profile_dir}")
-            # The trace-viewer dump is obs.trace food (trace_attribution
-            # parses it into per-phase device-time rows).
+            # The .xplane.pb is obs.trace food (trace_attribution reads it
+            # into device seconds by scope; the CLI prints the table).
             obs_events.emit("profiler_trace", round=r, dir=cfg.profile_dir)
         phases = timer.summary()
         record = {

@@ -260,21 +260,24 @@ def _make_train_step(module, cfg: TrainConfig, global_params, sp: _TrainSplit):
         # the scan/while op at the call site stays scope-less on purpose
         # (obs.scopes docstring).
         with jax.named_scope(obs_scopes.SGD_CORE):
-            xb = rescale(sp.x_tr[idx])
+            with jax.named_scope(obs_scopes.BATCH):
+                xb = rescale(sp.x_tr[idx])
             if cfg.augment:
                 xb = random_augment(
                     k_aug, xb, shear=cfg.aug_shear, zoom=cfg.aug_zoom,
                     flip=cfg.aug_flip, backend=cfg.aug_backend,
                 )
-            oh = oh_tr[idx]
+            with jax.named_scope(obs_scopes.BATCH):
+                oh = oh_tr[idx]
             grads, (ce, acc) = jax.grad(
                 lambda p: loss_fn(module, p, xb, oh, global_params, cfg.prox_mu),
                 has_aux=True,
             )(params)
-            params, opt = adam_update(
-                grads, opt, params, cfg.lr, cfg.lr_decay, lr_scale,
-                warmup_steps=cfg.warmup_steps,
-            )
+            with jax.named_scope(obs_scopes.ADAM):
+                params, opt = adam_update(
+                    grads, opt, params, cfg.lr, cfg.lr_decay, lr_scale,
+                    warmup_steps=cfg.warmup_steps,
+                )
         return params, opt, (ce, acc)
 
     return train_step
@@ -289,15 +292,17 @@ def _make_token_train_step(module, cfg: TrainConfig, global_params, sp: _TrainSp
 
     def train_step(params, opt, lr_scale, idx, k_aug):
         with jax.named_scope(obs_scopes.SGD_CORE):
-            xb = sp.x_tr[idx]
+            with jax.named_scope(obs_scopes.BATCH):
+                xb = sp.x_tr[idx]
             grads, (ce, acc) = jax.grad(
                 lambda p: token_loss_fn(module, p, xb, global_params, cfg.prox_mu),
                 has_aux=True,
             )(params)
-            params, opt = adam_update(
-                grads, opt, params, cfg.lr, cfg.lr_decay, lr_scale,
-                warmup_steps=cfg.warmup_steps,
-            )
+            with jax.named_scope(obs_scopes.ADAM):
+                params, opt = adam_update(
+                    grads, opt, params, cfg.lr, cfg.lr_decay, lr_scale,
+                    warmup_steps=cfg.warmup_steps,
+                )
         return params, opt, (ce, acc)
 
     return train_step

@@ -189,7 +189,8 @@ def fused_train(
         # / hefl.augment / hefl.val buckets as the vmap reference, so trace
         # attribution is backend-independent. Leaf regions only — the scan
         # at the bottom of fused_train stays scope-less.
-        with jax.named_scope(obs_scopes.SGD_CORE):
+        with jax.named_scope(obs_scopes.SGD_CORE), jax.named_scope(
+                obs_scopes.BATCH):
             xb = jnp.take_along_axis(
                 x_tr, idx[:, :, None, None, None], axis=1
             )                                      # [cpd, grp, H, W, ch]
@@ -205,7 +206,9 @@ def fused_train(
                 xb, s.reshape(-1), zx.reshape(-1), zy.reshape(-1),
                 f.reshape(-1), bk,
             )
-        oh = jnp.take_along_axis(oh_tr, idx[:, :, None], axis=1)
+        with jax.named_scope(obs_scopes.SGD_CORE), jax.named_scope(
+                obs_scopes.BATCH):
+            oh = jnp.take_along_axis(oh_tr, idx[:, :, None], axis=1)
 
         def block_loss(p):
             # Sum of per-client mean losses: client c's params only touch
@@ -225,12 +228,13 @@ def fused_train(
 
         with jax.named_scope(obs_scopes.SGD_CORE):
             grads = jax.grad(block_loss)(params_run)
-            new_params, new_opt = jax.vmap(
-                lambda g, o, p, ls: adam_update(
-                    g, o, p, cfg.lr, cfg.lr_decay, ls,
-                    warmup_steps=cfg.warmup_steps,
-                )
-            )(grads, opt_run, params_run, st.lr_scale)
+            with jax.named_scope(obs_scopes.ADAM):
+                new_params, new_opt = jax.vmap(
+                    lambda g, o, p, ls: adam_update(
+                        g, o, p, cfg.lr, cfg.lr_decay, ls,
+                        warmup_steps=cfg.warmup_steps,
+                    )
+                )(grads, opt_run, params_run, st.lr_scale)
             if keep is not None:
                 # Scheduled-out clients flow through the GEMM but update
                 # nothing — the multiplicative update mask of the fused step.

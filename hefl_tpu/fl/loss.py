@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from hefl_tpu.obs.scopes import DENSE
+
 
 def cross_entropy(logits: jax.Array, onehot: jax.Array) -> jax.Array:
     return jnp.mean(optax.softmax_cross_entropy(logits, onehot))
@@ -50,8 +52,10 @@ def token_loss_fn(module, params, tokens, global_params=None, prox_mu: float = 0
 def loss_fn(module, params, x, onehot, global_params=None, prox_mu: float = 0.0):
     """-> (loss, (ce, acc)). `x` is float [B,H,W,C] in [0,1]."""
     logits = module.apply({"params": params}, x)
-    ce = cross_entropy(logits, onehot)
+    with jax.named_scope(DENSE):  # the head's loss, with the head
+        ce = cross_entropy(logits, onehot)
     loss = ce
     if prox_mu > 0.0 and global_params is not None:
         loss = loss + prox_term(params, global_params, prox_mu)
-    return loss, (ce, accuracy(logits, onehot))
+    with jax.named_scope(DENSE):
+        return loss, (ce, accuracy(logits, onehot))

@@ -519,9 +519,10 @@ def _decode_unpack(ntt, spec: PackSpec, res: jax.Array, coeffs: jax.Array):
     scale is data, so a round with another surviving-client count runs the
     same executable. Takes the residues' sharding as it finds it
     (`decrypt_sharded`'s ciphertext axis)."""
-    return unpack_blocks(
-        encoding.decode_with_coefficients(ntt, res, coeffs), spec
-    )
+    with jax.named_scope(obs_scopes.DECRYPT):
+        return unpack_blocks(
+            encoding.decode_with_coefficients(ntt, res, coeffs), spec
+        )
 
 
 def _decode_average(ntt, spec: PackSpec, res: jax.Array, scale: float):

@@ -49,6 +49,7 @@ from jax import lax
 
 from hefl_tpu.models.folded import folded_conv, folded_dense
 from hefl_tpu.obs import metrics as obs_metrics
+from hefl_tpu.obs import scopes as obs_scopes
 
 
 _LANES = 128  # the minor dimension of a TPU tile
@@ -249,18 +250,22 @@ def _conv_stages(conv, x, layers):
         s = _polyphase_block(hw, *kernel.shape[-2:])
         pooled = [(n - 2) // 2 for n in hw]
         if not s:
-            x = _to_depth(x, depth, hw, 1, *hw)
-            x = nn.max_pool(nn.relu(conv(x, kernel, bias)), (2, 2), strides=(2, 2))
+            with jax.named_scope(obs_scopes.CONV):
+                x = _to_depth(x, depth, hw, 1, *hw)
+                x = nn.max_pool(
+                    nn.relu(conv(x, kernel, bias)), (2, 2), strides=(2, 2))
             depth, hw = 1, pooled
             continue
         if i + 1 < len(layers):  # all the next stage reads of this one
             pooled = [min(n, 2 * ((n - 2) // 2) + 2) for n in pooled]
         nb, mb = (-(-n // (s // 2)) for n in pooled)
-        x = _to_depth(x, depth, hw, s, nb + 1, mb + 1)
-        x = _polyphase_stage(conv, x, kernel, bias, s)
+        with jax.named_scope(obs_scopes.CONV):
+            x = _to_depth(x, depth, hw, s, nb + 1, mb + 1)
+            x = _polyphase_stage(conv, x, kernel, bias, s)
         depth, hw, taken = s // 2, pooled, taken + 1
     obs_metrics.gauge("model.polyphase_stages").set(taken)
-    return _to_depth(x, depth, hw, 1, *hw)
+    with jax.named_scope(obs_scopes.CONV):
+        return _to_depth(x, depth, hw, 1, *hw)
 
 
 def _conv_bf16(x, kernel, bias):
@@ -317,13 +322,15 @@ class MedCNN(nn.Module):
             _ConvParams(f, name=f"Conv_{i}")(widths[i])
             for i, f in enumerate(self.features)
         ])
-        x = x.reshape((x.shape[0], -1))
-        for d in self.dense:
-            x = nn.Dense(d, dtype=jnp.bfloat16, param_dtype=jnp.float32)(x)
-            x = nn.relu(x)
-        x = nn.Dense(self.num_classes, dtype=jnp.bfloat16, param_dtype=jnp.float32)(x)
-        x = x.astype(jnp.float32)
-        return nn.softmax(x) if self.apply_softmax else x
+        with jax.named_scope(obs_scopes.DENSE):
+            x = x.reshape((x.shape[0], -1))
+            for d in self.dense:
+                x = nn.Dense(d, dtype=jnp.bfloat16, param_dtype=jnp.float32)(x)
+                x = nn.relu(x)
+            x = nn.Dense(
+                self.num_classes, dtype=jnp.bfloat16, param_dtype=jnp.float32)(x)
+            x = x.astype(jnp.float32)
+            return nn.softmax(x) if self.apply_softmax else x
 
     def folded_apply(self, stacked_params, x, *, num_clients: int):
         """The client-folded forward (`TrainConfig.client_fusion="fused"`):
@@ -344,14 +351,15 @@ class MedCNN(nn.Module):
             for i in range(len(self.features))
         ])
         b = x.shape[0] // c
-        x = x.reshape(c, b, -1)
-        for j in range(len(self.dense)):
-            lyr = stacked_params[f"Dense_{j}"]
-            x = nn.relu(folded_dense(x, lyr["kernel"], lyr["bias"]))
-        head = stacked_params[f"Dense_{len(self.dense)}"]
-        x = folded_dense(x, head["kernel"], head["bias"])
-        x = x.astype(jnp.float32).reshape(c * b, -1)
-        return nn.softmax(x) if self.apply_softmax else x
+        with jax.named_scope(obs_scopes.DENSE):
+            x = x.reshape(c, b, -1)
+            for j in range(len(self.dense)):
+                lyr = stacked_params[f"Dense_{j}"]
+                x = nn.relu(folded_dense(x, lyr["kernel"], lyr["bias"]))
+            head = stacked_params[f"Dense_{len(self.dense)}"]
+            x = folded_dense(x, head["kernel"], head["bias"])
+            x = x.astype(jnp.float32).reshape(c * b, -1)
+            return nn.softmax(x) if self.apply_softmax else x
 
 
 class SmallCNN(MedCNN):

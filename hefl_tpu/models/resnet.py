@@ -16,6 +16,7 @@ weight so the ciphertext packing covers the whole model.
 from __future__ import annotations
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from hefl_tpu.models.folded import (
@@ -23,6 +24,7 @@ from hefl_tpu.models.folded import (
     folded_dense,
     folded_group_norm,
 )
+from hefl_tpu.obs.scopes import CONV, DENSE, NORM
 
 
 class BasicBlock(nn.Module):
@@ -32,24 +34,30 @@ class BasicBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         residual = x
-        y = nn.Conv(
-            self.features, (3, 3), strides=(self.stride, self.stride),
-            padding="SAME", use_bias=False,
-            dtype=jnp.bfloat16, param_dtype=jnp.float32,
-        )(x)
-        y = nn.GroupNorm(num_groups=8, dtype=jnp.float32)(y)
+        with jax.named_scope(CONV):
+            y = nn.Conv(
+                self.features, (3, 3), strides=(self.stride, self.stride),
+                padding="SAME", use_bias=False,
+                dtype=jnp.bfloat16, param_dtype=jnp.float32,
+            )(x)
+        with jax.named_scope(NORM):
+            y = nn.GroupNorm(num_groups=8, dtype=jnp.float32)(y)
         y = nn.relu(y)
-        y = nn.Conv(
-            self.features, (3, 3), padding="SAME", use_bias=False,
-            dtype=jnp.bfloat16, param_dtype=jnp.float32,
-        )(y)
-        y = nn.GroupNorm(num_groups=8, dtype=jnp.float32)(y)
+        with jax.named_scope(CONV):
+            y = nn.Conv(
+                self.features, (3, 3), padding="SAME", use_bias=False,
+                dtype=jnp.bfloat16, param_dtype=jnp.float32,
+            )(y)
+        with jax.named_scope(NORM):
+            y = nn.GroupNorm(num_groups=8, dtype=jnp.float32)(y)
         if residual.shape != y.shape:
-            residual = nn.Conv(
-                self.features, (1, 1), strides=(self.stride, self.stride),
-                use_bias=False, dtype=jnp.bfloat16, param_dtype=jnp.float32,
-            )(residual)
-            residual = nn.GroupNorm(num_groups=8, dtype=jnp.float32)(residual)
+            with jax.named_scope(CONV):
+                residual = nn.Conv(
+                    self.features, (1, 1), strides=(self.stride, self.stride),
+                    use_bias=False, dtype=jnp.bfloat16, param_dtype=jnp.float32,
+                )(residual)
+            with jax.named_scope(NORM):
+                residual = nn.GroupNorm(num_groups=8, dtype=jnp.float32)(residual)
         return nn.relu(y + residual)
 
 
@@ -61,20 +69,24 @@ class ResNet20(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        x = nn.Conv(
-            self.widths[0], (3, 3), padding="SAME", use_bias=False,
-            dtype=jnp.bfloat16, param_dtype=jnp.float32,
-        )(x)
-        x = nn.GroupNorm(num_groups=8, dtype=jnp.float32)(x)
+        with jax.named_scope(CONV):
+            x = nn.Conv(
+                self.widths[0], (3, 3), padding="SAME", use_bias=False,
+                dtype=jnp.bfloat16, param_dtype=jnp.float32,
+            )(x)
+        with jax.named_scope(NORM):
+            x = nn.GroupNorm(num_groups=8, dtype=jnp.float32)(x)
         x = nn.relu(x)
         for stage, (blocks, width) in enumerate(zip(self.stage_sizes, self.widths)):
             for b in range(blocks):
                 stride = 2 if (stage > 0 and b == 0) else 1
                 x = BasicBlock(width, stride)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.bfloat16, param_dtype=jnp.float32)(x)
-        x = x.astype(jnp.float32)
-        return nn.softmax(x) if self.apply_softmax else x
+        with jax.named_scope(DENSE):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(
+                self.num_classes, dtype=jnp.bfloat16, param_dtype=jnp.float32)(x)
+            x = x.astype(jnp.float32)
+            return nn.softmax(x) if self.apply_softmax else x
 
     def folded_apply(self, stacked_params, x, *, num_clients: int):
         """Client-folded forward (`TrainConfig.client_fusion="fused"`; see
@@ -88,33 +100,28 @@ class ResNet20(nn.Module):
         c = num_clients
 
         def gn(p, h):
-            return folded_group_norm(
-                h, p["scale"], p["bias"], num_clients=c, num_groups=8
-            )
+            with jax.named_scope(NORM):
+                return folded_group_norm(
+                    h, p["scale"], p["bias"], num_clients=c, num_groups=8
+                )
+
+        def conv(h, kernel, **kw):
+            with jax.named_scope(CONV):
+                return folded_conv(
+                    h, kernel, None, num_clients=c, padding="SAME", **kw)
 
         def block(p, h, stride):
-            y = folded_conv(
-                h, p["Conv_0"]["kernel"], None, num_clients=c,
-                strides=(stride, stride), padding="SAME",
-            )
+            y = conv(h, p["Conv_0"]["kernel"], strides=(stride, stride))
             y = nn.relu(gn(p["GroupNorm_0"], y))
-            y = folded_conv(
-                y, p["Conv_1"]["kernel"], None, num_clients=c, padding="SAME"
-            )
+            y = conv(y, p["Conv_1"]["kernel"])
             y = gn(p["GroupNorm_1"], y)
             residual = h
             if "Conv_2" in p:  # projection shortcut (shape change)
-                residual = folded_conv(
-                    h, p["Conv_2"]["kernel"], None, num_clients=c,
-                    strides=(stride, stride), padding="SAME",
-                )
+                residual = conv(h, p["Conv_2"]["kernel"], strides=(stride, stride))
                 residual = gn(p["GroupNorm_2"], residual)
             return nn.relu(y + residual)
 
-        x = folded_conv(
-            x, stacked_params["Conv_0"]["kernel"], None, num_clients=c,
-            padding="SAME",
-        )
+        x = conv(x, stacked_params["Conv_0"]["kernel"])
         x = nn.relu(gn(stacked_params["GroupNorm_0"], x))
         i = 0
         for stage, blocks in enumerate(self.stage_sizes):
@@ -122,9 +129,10 @@ class ResNet20(nn.Module):
                 stride = 2 if (stage > 0 and b_idx == 0) else 1
                 x = block(stacked_params[f"BasicBlock_{i}"], x, stride)
                 i += 1
-        x = jnp.mean(x, axis=(1, 2))
-        b = x.shape[0] // c
-        head = stacked_params["Dense_0"]
-        x = folded_dense(x.reshape(c, b, -1), head["kernel"], head["bias"])
-        x = x.astype(jnp.float32).reshape(c * b, -1)
-        return nn.softmax(x) if self.apply_softmax else x
+        with jax.named_scope(DENSE):
+            x = jnp.mean(x, axis=(1, 2))
+            b = x.shape[0] // c
+            head = stacked_params["Dense_0"]
+            x = folded_dense(x.reshape(c, b, -1), head["kernel"], head["bias"])
+            x = x.astype(jnp.float32).reshape(c * b, -1)
+            return nn.softmax(x) if self.apply_softmax else x

@@ -4,12 +4,15 @@ Four legs, one subsystem (ISSUE 5):
 
   * `obs.scopes` — the canonical `jax.named_scope` names the round
     program's phases are annotated with (augment / sgd_core / val /
-    sanitize / encrypt / psum_aggregate / aggregate / decrypt / evaluate).
-    They survive jit into HLO metadata and profiler traces.
-  * `obs.trace` — parses a `jax.profiler.start_trace` trace-viewer dump and
-    joins its device-op events back to the scopes through the compiled
-    program's own HLO, yielding per-phase device time from ONE program —
-    the ground truth that replaces cross-program ablation subtraction.
+    sanitize / encrypt / psum_aggregate / aggregate / decrypt / evaluate,
+    and the models' own inside them). They survive jit into HLO metadata
+    and profiler traces.
+  * `obs.trace` — reads the `.xplane.pb` that `jax.profiler.start_trace`
+    writes (its wire format: the device ops' event metadata carries the HLO
+    `op_name` as the stat `tf_op`, which `ProfileData` does not show) and
+    yields a TPU run's device seconds by scope, by kernel family, forward
+    against backward and by program, as self time, from the programs that
+    ran: no HLO text, no second compile, no cache bypass.
   * `obs.events` / `obs.metrics` — a JSONL run-event log (events.jsonl
     next to checkpoints; HEFL_EVENTS=0 opt-out) and a process-wide
     counter/gauge registry (exclusions by cause, retries, resumes, XLA

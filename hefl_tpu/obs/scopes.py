@@ -7,20 +7,20 @@ dot_general"`), which means two independent consumers see the same names:
 
   * HLO text — the scopes survive jit/compile, so a test can assert the
     annotation didn't get lost in a refactor (tests/test_obs.py);
-  * profiler traces — device-op trace events carry the HLO instruction
-    name, and `obs.trace` joins them back to these scopes through the
-    compiled program's own metadata, giving per-phase device time from ONE
-    program instead of subtraction across separately-compiled ablations.
+  * profiler traces — on a TPU every device op's event metadata carries
+    that `op_name` as the stat `tf_op`, and `obs.trace` reads it from the
+    `.xplane.pb` itself: device seconds by scope and by kernel of the
+    programs that ran, with no HLO text and no second compile.
 
 Annotation rule (load-bearing): wrap only LEAF compute regions — never a
 region that CALLS `lax.scan` / `lax.while_loop`, because the loop op
 itself would then inherit the scope and its one trace event (spanning
 every iteration, including other phases' work) would swallow the
 attribution. A loop op deliberately left scope-less shows up as a
-container whose children are attributed individually; `obs.trace` counts
-only the time no attributed child covers. Wrapping a `lax.cond` call IS
-intended (e.g. the per-epoch validation cond): its per-iteration event is
-the executed branch only.
+container whose children are attributed individually (`obs.trace` counts
+an op's self time: its duration less the ops nested in it). Wrapping a
+`lax.cond` call IS intended (e.g. the per-epoch validation cond): its
+per-iteration event is the executed branch only.
 """
 
 from __future__ import annotations
@@ -53,6 +53,11 @@ GQA = "hefl.gqa"                      # grouped-query attention: projections, Ro
 SWA_ATTEND = "hefl.swa.attend"        # a window layer's fused attention calls
 MTP = "hefl.mtp"                      # the multi-token-prediction module
 LM_HEAD = "hefl.lm_head"              # head logits + cross-entropy, by slices
+CONV = "hefl.conv"                    # a convolution (medcnn: with bias, ReLU and pool)
+NORM = "hefl.norm"                    # GroupNorm
+DENSE = "hefl.dense"                  # the dense head and its loss
+ADAM = "hefl.adam"                    # the optimizer's update of a step
+BATCH = "hefl.batch"                  # a step's batch: the gather by index and its rescale
 
 # HOST-side spans (recorded through `obs.spans.span`, which opens a
 # jax.profiler.TraceAnnotation; not named_scope): driver work that owns
@@ -89,6 +94,11 @@ PHASES = (
     SWA_ATTEND,
     MTP,
     LM_HEAD,
+    CONV,
+    NORM,
+    DENSE,
+    ADAM,
+    BATCH,
 )
 
 
@@ -105,9 +115,15 @@ def is_phase_scope(component: str) -> bool:
     return component.startswith(PREFIX)
 
 
+def scopes_in(op_name: str) -> list[str]:
+    """Every hefl.* scope of an HLO `op_name` path, outer -> inner (a scope
+    a transformation repeats, `hefl.val/cond/.../hefl.val`, appears again)."""
+    return _SCOPE_RE.findall(op_name)
+
+
 def scope_of(op_name: str) -> str | None:
     """Deepest hefl.* scope in an HLO `op_name` path (scopes nest — e.g.
     augment inside sgd_core — and the innermost is the attribution). Path
     components run outer -> inner, so the last match wins."""
-    hits = _SCOPE_RE.findall(op_name)
+    hits = scopes_in(op_name)
     return hits[-1] if hits else None

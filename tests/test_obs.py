@@ -1,5 +1,5 @@
-"""Observability subsystem (hefl_tpu.obs): trace parser on the committed
-golden fixture, named-scope survival through jit for both client-fusion
+"""Observability subsystem (hefl_tpu.obs): the xplane reader on a fixture cut
+from a chip's trace, named-scope survival through jit for both client-fusion
 backends, the events JSONL log and the metrics registry."""
 
 import dataclasses
@@ -18,8 +18,6 @@ from hefl_tpu.obs import scopes as obs_scopes
 from hefl_tpu.obs import trace as obs_trace
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
-GOLDEN_TRACE = os.path.join(FIXTURES, "golden.trace.json.gz")
-GOLDEN_HLO = os.path.join(FIXTURES, "golden_hlo.txt")
 
 
 # ---------------------------------------------------------------- scopes
@@ -36,124 +34,300 @@ def test_scope_of_takes_deepest_and_handles_decoration():
     assert obs_scopes.scope_of("jit(f)/jit(main)/reduce_sum") is None
 
 
-# ----------------------------------------------------- golden-trace parse
+# ------------------------------------------- the chip's xplane, by the wire
+
+# Cut by `python tests/fixtures/cut_xplane.py <chip trace> <fixture> 700`
+# (then `gzip -9 -n`) from the `.xplane.pb` that `benchmarks/run.py
+# --workload medcnn.sync_e10 --seed 3600000051 --trace 1 --keep-trace` wrote
+# on a TPU v5e with an empty compile cache (PR 36): the head of the round
+# program's ops, one whole training step among them, and the decrypt
+# programs' ops, with their metadata; 315 KB, 56 KB as committed.
+CHIP_TRACE_GZ = os.path.join(FIXTURES, "chip_trace.xplane.pb.gz")
 
 
-def _golden_hlo() -> str:
-    with open(GOLDEN_HLO) as f:
-        return f.read()
+@pytest.fixture(scope="module")
+def chip_trace(tmp_path_factory):
+    """The fixture as the profiler wrote it, in a logdir's layout (for
+    `ProfileData` and the logdir search; the reader takes the `.gz` too)."""
+    run = tmp_path_factory.mktemp("logdir") / "plugins" / "profile" / "run"
+    run.mkdir(parents=True)
+    (run / "host.xplane.pb").write_bytes(gzip.open(CHIP_TRACE_GZ).read())
+    return str(run / "host.xplane.pb")
 
 
-def test_hlo_scope_map_covers_instructions_and_call_aliases():
-    sm = obs_trace.hlo_scope_map(_golden_hlo())
-    assert sm["dot.1"] == "hefl.sgd_core"       # vmap(hefl.sgd_core) decoration
-    assert sm["fusion.2"] == "hefl.encrypt"
-    assert sm["tanh.4.clone"] == "hefl.val"
-    # call.N carries no metadata; resolved through to_apply=%parallel_X.
-    assert sm["call.3"] == "hefl.val"
-    assert "mystery.9" not in sm
-    assert "while.5" not in sm
+@pytest.fixture(scope="module")
+def chip(chip_trace):
+    devices, host = obs_trace.read_xplane(chip_trace)
+    assert len(devices) == 1
+    return devices[0], host
 
 
-def test_golden_trace_bucketing():
-    rec = obs_trace.trace_attribution(GOLDEN_TRACE, [_golden_hlo()])
-    rows = rec["rows"]
-    # Same op on two overlapping worker threads: union, not sum.
-    assert rows["hefl.sgd_core"] == {"device_seconds": 150e-6, "op_events": 2}
-    assert rows["hefl.encrypt"]["device_seconds"] == pytest.approx(50e-6)
-    # The call wrapper and the inner op it spans merge into one val union.
-    assert rows["hefl.val"] == {"device_seconds": 40e-6, "op_events": 2}
-    # The scope-less mystery op and the scope-less container's uncovered
-    # remainder land in unattributed; attributed time is never re-counted.
-    assert rec["unattributed_s"] == pytest.approx(260e-6)
-    assert rec["device_total_s"] == pytest.approx(500e-6)
-    # Events of modules without supplied HLO are excluded entirely.
-    assert set(rec["modules"]) == {"jit_golden"}
-    assert rec["op_events"] == 7
-    assert obs_trace.attributed_sum_s(rec) == pytest.approx(500e-6)
+@pytest.fixture(scope="module")
+def chip_record(chip_trace):
+    return obs_trace.trace_attribution(chip_trace)
 
 
-def test_trace_rows_order_follows_canonical_phases():
-    rec = obs_trace.trace_attribution(GOLDEN_TRACE, [_golden_hlo()])
-    found = list(rec["rows"])
-    canon = [p for p in obs_scopes.PHASES if p in rec["rows"]]
-    assert found == canon
+def test_wire_reader_equals_profile_data(chip, chip_trace):
+    """Names and nanoseconds of every event as `jax.profiler.ProfileData`
+    reports them; `tf_op` and the rest are what it does not show."""
+    from jax.profiler import ProfileData
+
+    dev, host = chip
+    plane = next(p for p in ProfileData.from_file(chip_trace).planes
+                 if p.name == dev.plane)
+    theirs = next(list(ln.events) for ln in plane.lines
+                  if ln.name == obs_trace.OPS_LINE)
+    mine = list(dev.events())
+    assert len(mine) == len(theirs) > 400
+    for a, b in zip(mine, theirs):
+        assert (a["name"], a["start_ns"], a["dur_ns"]) == (
+            b.name, b.start_ns, b.duration_ns)
+        assert "tf_op" not in dict(b.stats)
+    assert sum(1 for e in mine if e["tf_op"]) > len(mine) // 2
+    assert {e["program"] for e in mine} == {
+        "jit_decrypt", "jit_outer", "jit__decode_unpack"}
+    # the recorder's host spans are on the same clock
+    spans = [e for p in ProfileData.from_file(chip_trace).planes
+             if p.name.startswith(obs_trace.HOST_PLANE)
+             for ln in p.lines for e in ln.events if e.name == "hefl.round"]
+    assert sorted((lo // 1000, (hi - lo) // 1000) for lo, hi in host["hefl.round"]
+                  ) == sorted((int(e.start_ns), int(e.duration_ns)) for e in spans)
 
 
-def test_corrupt_and_truncated_traces_fail_loud(tmp_path):
-    # Truncated gzip.
-    blob = open(GOLDEN_TRACE, "rb").read()
-    bad = tmp_path / "truncated.trace.json.gz"
-    bad.write_bytes(blob[: len(blob) // 2])
-    with pytest.raises(obs_trace.TraceParseError):
-        obs_trace.trace_attribution(str(bad), [_golden_hlo()])
-    # Valid gzip, malformed JSON.
-    bad2 = tmp_path / "garbage.trace.json.gz"
-    bad2.write_bytes(gzip.compress(b"{not json"))
-    with pytest.raises(obs_trace.TraceParseError):
-        obs_trace.trace_attribution(str(bad2), [_golden_hlo()])
-    # Valid JSON, no traceEvents.
-    bad3 = tmp_path / "empty.trace.json.gz"
-    bad3.write_bytes(gzip.compress(json.dumps({"traceEvents": []}).encode()))
-    with pytest.raises(obs_trace.TraceParseError):
-        obs_trace.trace_attribution(str(bad3), [_golden_hlo()])
-    # A logdir with no trace at all.
-    with pytest.raises(obs_trace.TraceParseError):
-        obs_trace.trace_attribution(str(tmp_path / "nothing"), [_golden_hlo()])
-    # Events present but none for the supplied modules.
-    with pytest.raises(obs_trace.TraceParseError):
-        obs_trace.trace_attribution(
-            GOLDEN_TRACE, ["HloModule jit_absent\nENTRY %m { ROOT %r = () tuple() }"]
-        )
-    # No HLO at all.
-    with pytest.raises(obs_trace.TraceParseError):
-        obs_trace.trace_attribution(GOLDEN_TRACE, [])
+def test_wire_reader_equals_tensorflows_xplane_pb2(chip, chip_trace):
+    """Against the generated module, where TensorFlow imports (in a process of
+    its own: 13 s, and not beside JAX)."""
+    code = (
+        "import json, sys\n"
+        "from tensorflow.tsl.profiler.protobuf import xplane_pb2\n"
+        "x = xplane_pb2.XSpace(); x.ParseFromString(open(sys.argv[1], 'rb').read())\n"
+        "p = next(p for p in x.planes if p.name.startswith('/device:TPU:'))\n"
+        "names = {k: v.name for k, v in p.stat_metadata.items()}\n"
+        "ln = next(l for l in p.lines if l.name == 'XLA Ops')\n"
+        "out = []\n"
+        "for e in ln.events:\n"
+        "    m = p.event_metadata[e.metadata_id]\n"
+        "    st = {names[s.metadata_id]: getattr(s, s.WhichOneof('value'))\n"
+        "          for s in m.stats}\n"
+        "    out.append([m.name, m.display_name, e.offset_ps, e.duration_ps,\n"
+        "        st.get('tf_op', ''), st.get('hlo_category', ''),\n"
+        "        st.get('flops', 0)])\n"
+        "print(json.dumps(out))\n")
+    import subprocess
+    import sys
+
+    run = subprocess.run([sys.executable, "-c", code, chip_trace],
+                         capture_output=True, text=True, timeout=180,
+                         env={**os.environ, "CUDA_VISIBLE_DEVICES": ""})
+    if run.returncode != 0:
+        pytest.skip("tensorflow's xplane_pb2 does not import here")
+    dev, _ = chip
+    mine = [[e["name"], e["display_name"], int(e["start_ns"]), int(e["dur_ns"]),
+             e["tf_op"], e["hlo_category"], e["flops"]] for e in dev.events()]
+    theirs = json.loads(run.stdout.strip().splitlines()[-1])
+    assert [[n, d, o // 1000, t // 1000, *rest] for n, d, o, t, *rest in theirs
+            ] == mine
 
 
-def test_host_trace_annotations_become_host_rows():
-    # Driver-side hefl.* TraceAnnotations (no hlo_module in args) must
-    # surface as first-class host_rows — e.g. the straggler wait — without
-    # perturbing the device rows or the wall-agreement quantity.
-    base = obs_trace.trace_attribution(GOLDEN_TRACE, [_golden_hlo()])
-    events = obs_trace.load_trace_events(GOLDEN_TRACE)
-    events = events + [
-        {"ph": "X", "name": "hefl.straggler_wait", "ts": 1000.0,
-         "dur": 250.0, "args": {}},
-        {"ph": "X", "name": "hefl.straggler_wait", "ts": 2000.0,
-         "dur": 150.0},
-        {"ph": "X", "name": "hefl.phase.decrypt", "ts": 0.0, "dur": 50.0},
-        # Non-hefl host events stay ignored.
-        {"ph": "X", "name": "SomeRuntimeThing", "ts": 0.0, "dur": 9999.0},
-    ]
-    rec = obs_trace.trace_attribution(events, [_golden_hlo()])
-    assert rec["host_rows"]["hefl.straggler_wait"] == {
-        "seconds": pytest.approx(400e-6), "spans": 2,
-    }
-    assert rec["host_rows"]["hefl.phase.decrypt"]["spans"] == 1
-    assert "SomeRuntimeThing" not in rec["host_rows"]
-    # Device-side attribution is untouched by host spans.
-    assert rec["rows"] == base["rows"]
-    assert rec["device_total_s"] == base["device_total_s"]
-    assert rec["unattributed_s"] == base["unattributed_s"]
+def test_self_time_under_a_while(chip):
+    # a `while` of 100 spans two ops of 30 and 20, the second holding one of 5
+    start = np.array([0, 10, 50, 55, 200], np.int64)
+    dur = np.array([100, 30, 20, 5, 7], np.int64)
+    self_ps, leaf = obs_trace._self_times(start, dur)
+    assert self_ps.tolist() == [50, 30, 15, 5, 7]
+    assert leaf.tolist() == [False, True, False, True, True]
+    # on the chip's line: the round program's `while` is there, self times
+    # add up to the union of the intervals, and no op counts twice
+    dev, _ = chip
+    whiles = [i for i, k in enumerate(dev.op_id.tolist())
+              if dev.ops[k].hlo_category == "while"]
+    assert whiles and not dev.leaf[whiles].any()
+    assert (dev.self_ps[whiles] < dev.dur_ps[whiles]).all()
+    union = obs_trace._union_s(
+        zip(dev.start_ps.tolist(), (dev.start_ps + dev.dur_ps).tolist()))
+    assert dev.self_ps.sum() * 1e-12 == pytest.approx(union, rel=1e-12)
+
+
+def test_deepest_scope_and_membership_at_any_depth(chip_record):
+    tf_op = ("jit(outer)/while/body/closed_call/hefl.sgd_core/"
+             "transpose(jvp(hefl.moe.experts))/while/body/hefl.moe_gmm/"
+             "jit(gmm)/select_n:")
+    assert obs_scopes.scope_of(tf_op) == "hefl.moe_gmm"
+    assert obs_scopes.scopes_in(tf_op) == [
+        "hefl.sgd_core", "hefl.moe.experts", "hefl.moe_gmm"]
+    assert obs_scopes.scopes_in(
+        "jit(f)/hefl.val/cond/jit(f)/hefl.val/cond/branch_1_fun/hefl.val/"
+        "checkpoint/hefl.conv/while:") == [
+        "hefl.val", "hefl.val", "hefl.val", "hefl.conv"]
+    rec = chip_record
+    assert "hefl.val/hefl.val" not in " ".join(rec["paths"])
+    for scope, row in rec["rows"].items():  # deepest: chains that END there
+        assert row["device_seconds"] == pytest.approx(sum(
+            r["device_seconds"] for chain, r in rec["paths"].items()
+            if chain.split("/")[-1] == scope))
+    for scope, row in rec["under"].items():  # any depth: chains that HOLD it
+        assert row["device_seconds"] == pytest.approx(sum(
+            r["device_seconds"] for chain, r in rec["paths"].items()
+            if scope in chain.split("/")))
+        assert row["device_seconds"] >= rec["rows"].get(
+            scope, {"device_seconds": 0.0})["device_seconds"]
+    core = rec["under"]["hefl.sgd_core"]
+    assert 0 < core["backward_seconds"] < core["device_seconds"]
+    assert rec["backward_s"] == pytest.approx(core["backward_seconds"])
+
+
+def test_kernel_families_are_custom_calls_less_their_number(chip, chip_record):
+    op = obs_trace.Op(name="%x", display_name="splash_mqa_fwd_residuals.90",
+                      hlo_category="custom-call")
+    assert op.family == "splash_mqa_fwd_residuals"
+    assert dataclasses.replace(op, display_name="hefl.encrypt.3").family == (
+        "hefl.encrypt")
+    assert dataclasses.replace(op, hlo_category="loop fusion").family is None
+    dev, _ = chip
+    calls = {dev.ops[k].family for k in dev.op_id.tolist()} - {None}
+    assert "hefl.decrypt" in calls
+    assert set(chip_record["families"]) == calls
+    assert chip_record["families"]["hefl.decrypt"]["op_events"] == 2
+
+
+def test_flops_and_bytes_count_leaf_ops_only(chip, chip_record):
+    dev, _ = chip
+    flops = sum(dev.ops[k].flops for k, leaf in
+                zip(dev.op_id.tolist(), dev.leaf.tolist()) if leaf)
+    every = sum(dev.ops[k].flops for k in dev.op_id.tolist())
+    assert chip_record["flops"] == flops > 0
+    assert every > flops  # the `while`s count their bodies again
+    assert chip_record["bytes_accessed"] == sum(
+        dev.ops[k].bytes_accessed for k, leaf in
+        zip(dev.op_id.tolist(), dev.leaf.tolist()) if leaf)
+
+
+def test_chip_trace_bucketing(chip, chip_record, chip_trace):
+    rec, (dev, _) = chip_record, chip
+    busy = dev.self_ps.sum() * 1e-12
+    assert rec["device_total_s"] == pytest.approx(busy)
+    assert sum(r["device_seconds"] for r in rec["rows"].values()
+               ) + rec["unattributed_s"] == pytest.approx(busy)
+    assert sum(rec["modules"].values()) == pytest.approx(busy)
+    assert sum(r["device_seconds"] for r in rec["paths"].values()
+               ) == pytest.approx(busy)
+    assert rec["unattributed_s"] == rec["paths"][""]["device_seconds"]
+    assert rec["op_events"] == len(dev.op_id) == sum(
+        r["op_events"] for r in rec["paths"].values())
+    assert rec["planes"] == 1 and rec["source"] == "xplane"
+    # the decrypt kernel's program and, since PR 36, the decode's are under
+    # `hefl.decrypt` whole, but for the decode's unscoped parameter copies
+    decrypt = sum(s for m, s in rec["modules"].items() if "dec" in m)
+    assert 0.95 * decrypt < rec["rows"]["hefl.decrypt"]["device_seconds"] <= decrypt
+    # a logdir is searched for its newest .xplane.pb; the .gz reads the same
+    logdir = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        chip_trace))))
+    assert obs_trace.trace_attribution(logdir)["paths"] == rec["paths"]
+    assert obs_trace.trace_attribution(CHIP_TRACE_GZ)["paths"] == rec["paths"]
+    assert "hefl.decrypt" in obs_trace.format_table(rec)
+
+
+def test_trace_rows_order_follows_canonical_phases(chip_record):
+    found = list(chip_record["rows"])
+    canon = [p for p in obs_scopes.PHASES if p in chip_record["rows"]]
+    assert found == canon and len(found) >= 4
+
+
+def _rewritten(tmp_path, old: bytes, new: bytes) -> str:
+    """The fixture with a name replaced by one as long (lengths hold)."""
+    assert len(old) == len(new)
+    blob = gzip.open(CHIP_TRACE_GZ).read()
+    assert old in blob
+    path = tmp_path / "rewritten.xplane.pb"
+    path.write_bytes(blob.replace(old, new))
+    return str(path)
+
+
+@pytest.mark.parametrize("fault", ["truncated", "no_device_plane",
+                                   "no_ops_line", "no_tf_op", "no_file"])
+def test_unusable_traces_fail_loud(tmp_path, fault):
+    if fault == "truncated":
+        blob = gzip.open(CHIP_TRACE_GZ).read()
+        path = tmp_path / "half.xplane.pb"
+        path.write_bytes(blob[: len(blob) // 2])
+        path, error = str(path), obs_trace.TraceParseError
+    elif fault == "no_device_plane":
+        path = _rewritten(tmp_path, b"/device:TPU:0", b"/device:XPU:0")
+        error = obs_trace.NoDevicePlane
+    elif fault == "no_ops_line":
+        path = _rewritten(tmp_path, b"XLA Ops", b"XLA Opz")
+        error = obs_trace.TraceParseError
+    elif fault == "no_tf_op":
+        path = _rewritten(tmp_path, b"tf_op", b"tf_oq")
+        error = obs_trace.NoScopeMetadata
+    else:
+        path, error = str(tmp_path / "nothing"), obs_trace.TraceParseError
+    with pytest.raises(error):
+        obs_trace.trace_attribution(path)
+
+
+@pytest.mark.parametrize("as_json", [False, True])
+def test_cli_prints_the_table_of_a_profiled_run(tmp_path, capsys, as_json,
+                                                chip_trace):
+    """`hefl-train --profile DIR` ends by printing what `DIR` holds."""
+    from hefl_tpu.cli import print_trace_attribution
+
+    print_trace_attribution(os.path.dirname(chip_trace), as_json=as_json)
+    out = capsys.readouterr().out
+    if as_json:
+        assert json.loads(out)["trace_attribution"]["source"] == "xplane"
+    else:
+        assert "a fusion counts under the scope of its root" in out
+        assert "hefl.decrypt" in out and "jit_decrypt" in out
+    # a CPU run's trace: said on standard error, and the run has not failed
+    cpu = os.path.dirname(_rewritten(tmp_path, b"/device:TPU:0", b"/device:XPU:0"))
+    print_trace_attribution(cpu, as_json=as_json)
+    said = capsys.readouterr()
+    assert said.out == "" and "no device seconds by scope" in said.err
+
+
+def test_host_trace_annotations_become_host_rows(chip, chip_record):
+    # Driver-side hefl.* TraceAnnotations (`obs.spans`) surface as
+    # first-class host_rows, beside and apart from the device rows.
+    _, host = chip
+    rows = chip_record["host_rows"]
+    assert set(rows) == set(host) and all(k.startswith("hefl.") for k in rows)
+    assert rows["hefl.round"]["spans"] == 2
+    wait = "hefl.phase.train+encrypt+aggregate.device_wait"
+    assert 0 < rows[wait]["seconds"] < rows["hefl.round"]["seconds"]
+    assert rows["hefl.phase.decrypt"]["seconds"] == pytest.approx(
+        sum(hi - lo for lo, hi in host["hefl.phase.decrypt"]) * 1e-12)
+    assert not any(k.startswith("hefl.phase") for k in chip_record["rows"])
 
 
 # --------------------------------------- scopes survive jit, both backends
 
 
-@pytest.mark.parametrize("backend", ["vmap", "fused"])
-def test_named_scopes_survive_jit(backend):
+def _op_name_scopes(hlo_text: str) -> set[str]:
+    """Every scope chain the compiled program's `op_name`s hold."""
+    import re
+
+    return {"/".join(dict.fromkeys(obs_scopes.scopes_in(name)))
+            for name in re.findall(r'op_name="([^"]*)"', hlo_text)}
+
+
+@pytest.mark.parametrize("backend,model,dataset", [
+    ("vmap", "smallcnn", "mnist"), ("fused", "smallcnn", "mnist"),
+    ("vmap", "resnet20", "cifar10"), ("fused", "resnet20", "cifar10"),
+])
+def test_named_scopes_survive_jit(backend, model, dataset):
     """The phase annotations must reach the compiled HLO for BOTH
-    cross-client training backends — lose them and trace attribution
-    silently degrades to one 'unattributed' bucket."""
+    cross-client training backends and both image models — lose them and
+    trace attribution silently degrades to one 'unattributed' bucket."""
     from hefl_tpu.data import iid_contiguous, make_dataset, stack_federated
     from hefl_tpu.fl import TrainConfig
     from hefl_tpu.fl.fedavg import _build_round_fn, replicate_on
     from hefl_tpu.models import create_model
     from hefl_tpu.parallel import make_mesh
 
-    (x, y), _, _ = make_dataset("mnist", seed=0, n_train=16, n_test=8)
+    (x, y), _, _ = make_dataset(dataset, seed=0, n_train=16, n_test=8)
     xs, ys = stack_federated(x, y, iid_contiguous(len(x), 2))
-    module, params = create_model("smallcnn", rng=jax.random.key(0))
+    module, params = create_model(model, rng=jax.random.key(0))
     cfg = TrainConfig(
         epochs=1, batch_size=4, num_classes=10, val_fraction=0.25,
         client_fusion=backend,
@@ -167,14 +341,18 @@ def test_named_scopes_survive_jit(backend):
     # make this test flaky across warm suite reruns.
     with obs_trace.metadata_preserving_compile():
         txt = fn.lower(gp, jnp.asarray(xs), jnp.asarray(ys), keys).compile().as_text()
-    for scope in (obs_scopes.SGD_CORE, obs_scopes.AUGMENT, obs_scopes.VAL,
-                  obs_scopes.AGGREGATE):
-        assert scope in txt, f"{scope} lost in jit under {backend} backend"
-    sm = obs_trace.hlo_scope_map(txt)
-    assert set(sm.values()) >= {
-        obs_scopes.SGD_CORE, obs_scopes.AUGMENT, obs_scopes.VAL,
-        obs_scopes.AGGREGATE,
-    }
+    chains = _op_name_scopes(txt)
+    held = {s for chain in chains for s in chain.split("/")}
+    model_scopes = {obs_scopes.CONV, obs_scopes.DENSE} | (
+        {obs_scopes.NORM} if model == "resnet20" else set())
+    wanted = {obs_scopes.SGD_CORE, obs_scopes.AUGMENT, obs_scopes.VAL,
+              obs_scopes.AGGREGATE, obs_scopes.ADAM, obs_scopes.BATCH
+              } | model_scopes
+    assert held >= wanted, f"{wanted - held} lost in jit under {backend}"
+    # the model's scopes sit INSIDE the step's and validation's
+    for scope in model_scopes:
+        assert f"{obs_scopes.SGD_CORE}/{scope}" in chains
+        assert f"{obs_scopes.VAL}/{scope}" in chains
 
 
 # ----------------------------------------------------------------- events
