@@ -63,8 +63,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="cross-client training backend: 'fused' folds the "
                         "client axis into every conv/dense GEMM batch "
                         "(fl.fusion), 'vmap' is the per-client reference, "
-                        "'auto' micro-times both once per device kind "
-                        "(winner persisted next to the XLA compile cache)")
+                        "'auto' is 'fused' for a model whose client-folded "
+                        "forward is lane-packed (resnet20), else 'vmap'")
     p.add_argument("--he-n", type=int, default=4096, help="CKKS ring degree")
     p.add_argument("--he-primes", type=int, default=3, help="RNS limb count")
     # --- quantized bit-interleaved packing (ckks.quantize / README

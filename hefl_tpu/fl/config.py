@@ -63,9 +63,11 @@ class TrainConfig:
     # client axis into the batch axis of every conv/dense (one GEMM stream
     # of effective batch C*B per layer, per-client weights via
     # batch-grouped convs / batched GEMMs), "vmap" is the per-client vmap
-    # reference and what "auto" (default) resolves to
-    # (fl.fusion.resolve_fusion_backend). Same math, same RNG streams, same
-    # callback semantics on both backends (tests/test_perf.py pins it).
+    # reference. "auto" (default) resolves to "fused" for a model whose
+    # client-folded forward packs the clients into the lanes (ResNet20) and
+    # to "vmap" otherwise (fl.fusion.resolve_fusion_backend). Same math,
+    # same RNG streams, same callback semantics on both backends
+    # (tests/test_perf.py pins it).
     # A token model over a frozen base (models/lm.py) is trained one client
     # after another whatever this says ("auto" only; fl.fusion says why).
     client_fusion: str = "auto"

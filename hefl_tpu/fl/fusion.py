@@ -35,9 +35,10 @@ Per-client semantics are preserved exactly:
     round's unchanged global weights.
 
 Backend selection (`TrainConfig.client_fusion`, `resolve_fusion_backend`):
-"fused" | "vmap" pin a backend; "auto" (default) is "vmap", the lowering
-every benchmark cell of an image model runs. "fused" runs only where a
-configuration asks for it.
+"fused" | "vmap" pin a backend; "auto" (default) is "fused" for a model
+whose client-folded forward packs the clients into the lanes (it says so:
+`folded_lane_packed`, ResNet20) and "vmap" for every other image model
+(MedCNN's polyphase stages were written for `vmap`).
 """
 
 from __future__ import annotations
@@ -196,16 +197,19 @@ def fused_train(
             )                                      # [cpd, grp, H, W, ch]
             xb = fold_clients(rescale(xb))         # [cpd*grp, H, W, ch]
         if cfg.augment:
-            with jax.named_scope(obs_scopes.AUGMENT):
-                s, zx, zy, f = jax.vmap(
-                    lambda k: draw_affine_params(
-                        k, grp, cfg.aug_shear, cfg.aug_zoom, cfg.aug_flip
-                    )
-                )(k_aug)                           # each [cpd, grp]
-            xb = apply_affine(
-                xb, s.reshape(-1), zx.reshape(-1), zy.reshape(-1),
-                f.reshape(-1), bk,
-            )
+            # inside hefl.sgd_core, as the vmap step's warp is: the step's
+            # device seconds mean the same under both lowerings
+            with jax.named_scope(obs_scopes.SGD_CORE):
+                with jax.named_scope(obs_scopes.AUGMENT):
+                    s, zx, zy, f = jax.vmap(
+                        lambda k: draw_affine_params(
+                            k, grp, cfg.aug_shear, cfg.aug_zoom, cfg.aug_flip
+                        )
+                    )(k_aug)                       # each [cpd, grp]
+                xb = apply_affine(
+                    xb, s.reshape(-1), zx.reshape(-1), zy.reshape(-1),
+                    f.reshape(-1), bk,
+                )
         with jax.named_scope(obs_scopes.SGD_CORE), jax.named_scope(
                 obs_scopes.BATCH):
             oh = jnp.take_along_axis(oh_tr, idx[:, :, None], axis=1)
@@ -273,10 +277,12 @@ def fused_train(
 def resolve_fusion_backend(setting: str | None, module) -> str:
     """The training backend a round program traces with.
 
-    "auto" (or None) is "vmap"; "fused" and "vmap" are taken as given, and
-    "fused" on a model without a `folded_apply` is an error. A token model
-    (models/lm.py) is trained one client after another, and any pin is an
-    error.
+    "auto" (or None) is "fused" for a model whose `folded_apply` packs the
+    clients into the lanes (the class says so beside the method:
+    `folded_lane_packed`) and "vmap" otherwise; "fused" and "vmap" are
+    taken as given, and "fused" on a model without a `folded_apply` is an
+    error. A token model (models/lm.py) is trained one client after
+    another, and any pin is an error.
     """
     from hefl_tpu.models.lm import is_token_model
 
@@ -300,7 +306,10 @@ def resolve_fusion_backend(setting: str | None, module) -> str:
             "folded_apply — implement the client-folded forward "
             "(models.folded) or use 'vmap'/'auto'"
         )
-    return "vmap" if requested == "auto" else requested
+    if requested == "auto":
+        packed = getattr(module, "folded_lane_packed", False)
+        return "fused" if packed else "vmap"
+    return requested
 
 
 def fusion_report(setting: str | None, module) -> dict:

@@ -65,6 +65,9 @@ def create_model(
     is made from `seed` on the device at first use and held once a process
     (`frozen_base(module)`), never returned here.
     """
+    # ResNet20's client-folded forward sets it as it is traced; 0 in every
+    # other model's record.
+    obs_metrics.gauge("model.packed_conv_layers").set(0)
     if name in TOKEN_MODELS:
         module = FrozenBaseLM(
             num_classes=num_classes or TOKEN_MODELS[name],

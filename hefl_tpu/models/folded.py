@@ -24,13 +24,27 @@ here, and the key decision is how per-client convolutions lower:
     (kh*kw*C * 2*M*N*K is exactly the conv's count), no grouped convs
     anywhere.
 
+  * What the `packed_*` primitives emit (ResNet20, PR 37): the clients in
+    the LANES. At 16 / 32 / 64 channels a client-leading array fills 32 of
+    128 lanes whichever of clients, images or channels the compiler makes
+    minor-most; with `g = pack_size(C, width)` clients a pack the
+    activations are `[C/g, B, H, W, g*width]`, each client's kernel sits on
+    the diagonal of a `[C/g, kh, kw, g*in, g*out]` kernel
+    (`block_diagonal`) and a convolution is one dense 128-wide convolution
+    a pack; `packed_group_norm` makes the statistics a lane. `g` times the
+    multiply-adds, a third of the step's time on the chip (PERF.md, PR 37).
+    `folded_conv`'s tap form is for wide channels; at K = N = 16 it reads
+    264 GB a ResNet-20 step by the compiler's count.
+
 All primitives are mathematically exact per client (block-structured:
 client c's outputs depend only on client c's inputs and weights — the
 batched GEMM never mixes batch groups), so fused-vs-vmap equivalence is a
 float-tolerance property, not an approximation (tests/test_perf.py pins
 it).
 
-Width stability (ISSUE 15): at any client count >= 2 these primitives —
+Width stability (ISSUE 15; `folded_conv` / `folded_dense`, not the
+`packed_*` primitives, whose pack size and so whose order of summation
+follow the client count): at any client count >= 2 these primitives —
 and the grouped-conv forms the vmap backend lowers to — produce BITWISE
 identical per-client floats regardless of how many clients share the
 batch (the per-group/per-batch-entry math is width-independent), while a
@@ -252,35 +266,109 @@ def folded_dense(
     return out
 
 
-def folded_group_norm(
+LANES = 128  # a vreg's and an MXU tile's minor dimension
+
+
+def pack_size(num_clients: int, width: int) -> int:
+    """Clients a lane pack holds at `width` channels: the largest divisor
+    of the client count not above 128 // width (1 where none divides: the
+    plain per-client convolution)."""
+    cap = max(LANES // width, 1)
+    return max(g for g in range(1, cap + 1) if num_clients % g == 0)
+
+
+def pack_clients(x: jax.Array, g: int) -> jax.Array:
+    """[C, B, H, W, w] -> [C/g, B, H, W, g*w]: client p*g + j of pack p in
+    lanes [j*w, (j+1)*w)."""
+    c, b, h, w, ch = x.shape
+    x = x.reshape(c // g, g, b, h, w, ch).transpose(0, 2, 3, 4, 1, 5)
+    return x.reshape(c // g, b, h, w, g * ch)
+
+
+def unpack_clients(x: jax.Array, g: int) -> jax.Array:
+    """[C/g, B, ..., g*w] -> [C, B, ..., w], `pack_clients`' inverse (any
+    number of axes between the batch and the lanes)."""
+    p, b, *mid, lanes = x.shape
+    x = x.reshape(p, b, *mid, g, lanes // g)
+    x = jnp.moveaxis(x, -2, 1)
+    return x.reshape(p * g, b, *mid, lanes // g)
+
+
+def repack_clients(x: jax.Array, g_from: int, g_to: int) -> jax.Array:
+    """Packs of `g_from` clients to packs of `g_to`. Where g_to divides
+    g_from a pack's lanes are cut into g_from // g_to runs, each a pack of
+    its own (the client order is kept, so no lane moves inside a run);
+    written out because through `unpack_clients` and `pack_clients` XLA
+    moves the lanes (17.95 against 14.35 GB a ResNet-20 step)."""
+    if g_from == g_to:
+        return x
+    if g_from % g_to:
+        return pack_clients(unpack_clients(x, g_from), g_to)
+    p, b, h, w, lanes = x.shape
+    k = g_from // g_to
+    x = x.reshape(p, b, h, w, k, lanes // k)
+    return jnp.moveaxis(x, 4, 1).reshape(p * k, b, h, w, lanes // k)
+
+
+def block_diagonal(kernel: jax.Array, g: int, dtype=jnp.bfloat16) -> jax.Array:
+    """[C, kh, kw, i, o] per-client kernels -> [C/g, kh, kw, g*i, g*o], each
+    client's on the diagonal of its pack and exact zeros elsewhere. A
+    select, so its transpose hands each client the gradient of its own
+    block and drops the rest; call it inside the differentiated function."""
+    c, kh, kw, i, o = kernel.shape
+    k = kernel.astype(dtype).reshape(c // g, g, kh, kw, i, o)
+    k = k.transpose(0, 2, 3, 1, 4, 5)[:, :, :, :, :, None, :]
+    own = jnp.eye(g, dtype=bool)[:, None, :, None]  # [g, 1, g, 1]
+    k = jnp.where(own, k, jnp.zeros((), dtype))     # [P, kh, kw, g, i, g, o]
+    return k.reshape(c // g, kh, kw, g * i, g * o)
+
+
+def packed_conv(
+    x: jax.Array,
+    kernel: jax.Array,
+    g: int,
+    *,
+    strides: tuple[int, int] = (1, 1),
+    dtype=jnp.bfloat16,
+) -> jax.Array:
+    """Per-client SAME convolution over lane packs: one dense convolution a
+    pack, `g*i` lanes to `g*o`. x: [C/g, B, H, W, g*i]; kernel:
+    [C, kh, kw, i, o] stacked per-client filters. -> [C/g, B, H', W', g*o]
+    in `dtype`, as flax.linen.Conv(dtype=bf16, param_dtype=f32) rounds it
+    (the product accumulates in float32 and is rounded once)."""
+    conv = partial(
+        lax.conv_general_dilated, window_strides=strides, padding="SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    )
+    return jax.vmap(conv)(x.astype(dtype), block_diagonal(kernel, g, dtype))
+
+
+def packed_group_norm(
     x: jax.Array,
     scale: jax.Array,
     bias: jax.Array,
+    g: int,
     *,
-    num_clients: int,
     num_groups: int,
     eps: float = 1e-6,
 ) -> jax.Array:
-    """flax.linen.GroupNorm on a client-folded batch with per-client
-    scale/bias. GroupNorm statistics are per-SAMPLE (mean/var over spatial
-    dims and the channels inside each group), so folding clients into the
-    batch leaves the normalization untouched; only the learned affine is
-    per-client. x: [C*B, H, W, f] (any float dtype; computed in f32, like
-    the models' GroupNorm(dtype=f32)); scale/bias: [C, f]. -> f32.
-    """
-    c = num_clients
-    n, h, w, f = x.shape
-    g = num_groups
-    xf = x.astype(jnp.float32).reshape(n, h, w, g, f // g)
-    # flax _compute_stats fast-variance form: var = E[x^2] - E[x]^2 —
-    # matched exactly so fused-vs-vmap ResNet parity is reduction-order
-    # noise, not a formula difference.
-    mean = jnp.mean(xf, axis=(1, 2, 4), keepdims=True)
-    mean2 = jnp.mean(jnp.square(xf), axis=(1, 2, 4), keepdims=True)
+    """flax.linen.GroupNorm(num_groups, dtype=float32) a client over lane
+    packs. x: [C/g, B, H, W, g*w] (computed in float32); scale, bias:
+    [C, w]. Statistics per (client, image, group), flax's fast-variance
+    form: the sums of x and x*x over H and W are made a lane first, the
+    w / num_groups lanes of a group are combined on that [C/g, B, g*w]
+    array, and mean and rsqrt(var + eps) go back a lane; no full-size
+    array is ever reshaped to groups. -> float32, x's shape."""
+    p, b, h, w, lanes = x.shape
+    per = lanes // g // num_groups  # lanes a group
+    xf = x.astype(jnp.float32)
+
+    def group_mean(t):  # [P, B, lanes] sums over H, W -> group means a lane
+        t = t.reshape(p, b, lanes // per, per).sum(-1) / (h * w * per)
+        return jnp.repeat(t, per, axis=-1)[:, :, None, None, :]
+
+    mean = group_mean(jnp.sum(xf, axis=(2, 3)))
+    mean2 = group_mean(jnp.sum(jnp.square(xf), axis=(2, 3)))
     var = jnp.maximum(mean2 - jnp.square(mean), 0.0)
-    xn = ((xf - mean) * lax.rsqrt(var + eps)).reshape(n, h, w, f)
-    # Per-client affine: client c's scale/bias applies to its contiguous
-    # rows [c*B:(c+1)*B] of the folded batch.
-    sc = jnp.repeat(scale.astype(jnp.float32), n // c, axis=0)[:, None, None, :]
-    bi = jnp.repeat(bias.astype(jnp.float32), n // c, axis=0)[:, None, None, :]
-    return xn * sc + bi
+    lane = lambda t: t.astype(jnp.float32).reshape(p, 1, 1, 1, lanes)  # noqa: E731
+    return (xf - mean) * (lax.rsqrt(var + eps) * lane(scale)) + lane(bias)

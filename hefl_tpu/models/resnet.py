@@ -20,10 +20,17 @@ import jax
 import jax.numpy as jnp
 
 from hefl_tpu.models.folded import (
-    folded_conv,
+    fold_clients,
     folded_dense,
-    folded_group_norm,
+    pack_clients,
+    pack_size,
+    packed_conv,
+    packed_group_norm,
+    repack_clients,
+    unfold_clients,
+    unpack_clients,
 )
+from hefl_tpu.obs import metrics as obs_metrics
 from hefl_tpu.obs.scopes import CONV, DENSE, NORM
 
 
@@ -88,51 +95,67 @@ class ResNet20(nn.Module):
             x = x.astype(jnp.float32)
             return nn.softmax(x) if self.apply_softmax else x
 
+    # The client-folded forward below fills the 128 lanes with clients:
+    # `fl/fusion.resolve_fusion_backend` sends such a model through `fused`.
+    folded_lane_packed = True
+
     def folded_apply(self, stacked_params, x, *, num_clients: int):
-        """Client-folded forward (`TrainConfig.client_fusion="fused"`; see
-        models.folded and MedCNN.folded_apply): the same depth-20 network
-        over a client-folded batch with per-client weights — every conv one
-        batch-grouped conv of batch C*B, GroupNorm per-sample (folding-
-        invariant) with per-client affines. x: [C*B, H, W, ch];
+        """Client-folded forward, clients packed into the lanes (the
+        `fused` lowering, which `auto` resolves to for this model): with
+        `g = pack_size(C, width)` clients a pack (8, 4, 2 at widths 16, 32,
+        64 where they divide C) activations live as [C/g, B, H, W, g*width]
+        through the whole network, every convolution is one dense bfloat16
+        convolution a pack over a block-diagonal kernel
+        (`models.folded.packed_conv`) and GroupNorm works a lane
+        (`packed_group_norm`). A stage's first block convolves at the old
+        `g` and cuts the doubled lanes into the new packs. The same
+        mathematics as `vmap` of `__call__` to the order of summation: the
+        off-diagonal blocks multiply exact zeros. x: [C*B, H, W, ch];
         stacked_params: this module's params with a leading client axis.
         -> [C*B, num_classes] float32.
         """
         c = num_clients
+        packed = 0
 
-        def gn(p, h):
+        def gn(p, h, g):
             with jax.named_scope(NORM):
-                return folded_group_norm(
-                    h, p["scale"], p["bias"], num_clients=c, num_groups=8
-                )
+                return packed_group_norm(
+                    h, p["scale"], p["bias"], g, num_groups=8)
 
-        def conv(h, kernel, **kw):
+        def conv(h, kernel, g, g_out, stride=1):
+            nonlocal packed
+            packed += g > 1
             with jax.named_scope(CONV):
-                return folded_conv(
-                    h, kernel, None, num_clients=c, padding="SAME", **kw)
+                y = packed_conv(h, kernel, g, strides=(stride, stride))
+                return repack_clients(y, g, g_out)
 
-        def block(p, h, stride):
-            y = conv(h, p["Conv_0"]["kernel"], strides=(stride, stride))
-            y = nn.relu(gn(p["GroupNorm_0"], y))
-            y = conv(y, p["Conv_1"]["kernel"])
-            y = gn(p["GroupNorm_1"], y)
+        def block(p, h, g_in, g, stride):
+            y = conv(h, p["Conv_0"]["kernel"], g_in, g, stride)
+            y = nn.relu(gn(p["GroupNorm_0"], y, g))
+            y = conv(y, p["Conv_1"]["kernel"], g, g)
+            y = gn(p["GroupNorm_1"], y, g)
             residual = h
             if "Conv_2" in p:  # projection shortcut (shape change)
-                residual = conv(h, p["Conv_2"]["kernel"], strides=(stride, stride))
-                residual = gn(p["GroupNorm_2"], residual)
+                residual = conv(h, p["Conv_2"]["kernel"], g_in, g, stride)
+                residual = gn(p["GroupNorm_2"], residual, g)
             return nn.relu(y + residual)
 
-        x = conv(x, stacked_params["Conv_0"]["kernel"])
-        x = nn.relu(gn(stacked_params["GroupNorm_0"], x))
+        g = pack_size(c, self.widths[0])
+        with jax.named_scope(CONV):
+            x = pack_clients(unfold_clients(x, c), g)
+        x = conv(x, stacked_params["Conv_0"]["kernel"], g, g)
+        x = nn.relu(gn(stacked_params["GroupNorm_0"], x, g))
         i = 0
-        for stage, blocks in enumerate(self.stage_sizes):
+        for stage, (blocks, width) in enumerate(zip(self.stage_sizes, self.widths)):
             for b_idx in range(blocks):
                 stride = 2 if (stage > 0 and b_idx == 0) else 1
-                x = block(stacked_params[f"BasicBlock_{i}"], x, stride)
+                g_in, g = g, pack_size(c, width)
+                x = block(stacked_params[f"BasicBlock_{i}"], x, g_in, g, stride)
                 i += 1
+        obs_metrics.gauge("model.packed_conv_layers").set(packed)
         with jax.named_scope(DENSE):
-            x = jnp.mean(x, axis=(1, 2))
-            b = x.shape[0] // c
+            x = unpack_clients(jnp.mean(x, axis=(2, 3)), g)  # [C, B, width]
             head = stacked_params["Dense_0"]
-            x = folded_dense(x.reshape(c, b, -1), head["kernel"], head["bias"])
-            x = x.astype(jnp.float32).reshape(c * b, -1)
+            x = folded_dense(x, head["kernel"], head["bias"])
+            x = fold_clients(x.astype(jnp.float32))
             return nn.softmax(x) if self.apply_softmax else x

@@ -18,7 +18,7 @@ from hefl_tpu.ckks import ntt as ntt_mod
 from hefl_tpu.ckks.keys import CkksContext
 from hefl_tpu.data import augment
 from hefl_tpu.fl import fusion
-from hefl_tpu.models import SmallCNN, lm
+from hefl_tpu.models import LogReg, MedCNN, ResNet20, SmallCNN, lm
 from hefl_tpu.obs import metrics as obs_metrics
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -61,6 +61,15 @@ RULE = [
     ("augment-unknown-name", False,
      lambda: augment.resolve_shift_backend("fancy"), ValueError),
     ("lowering-image-auto", True, _lowering("auto", _image_model), "vmap"),
+    ("lowering-medcnn-auto", True, _lowering("auto", MedCNN), "vmap"),
+    ("lowering-logreg-auto", True, _lowering("auto", LogReg), "vmap"),
+    ("lowering-without-folded-apply-auto", True, _lowering("auto", _NoFold),
+     "vmap"),
+    # its client-folded forward packs the clients into the lanes, and says so
+    ("lowering-resnet20-auto", True, _lowering("auto", ResNet20), "fused"),
+    ("lowering-resnet20-auto-cpu", False, _lowering(None, ResNet20), "fused"),
+    ("lowering-resnet20-pinned-vmap", True, _lowering("vmap", ResNet20),
+     "vmap"),
     ("lowering-image-fused", False, _lowering("fused", _image_model), "fused"),
     ("lowering-fused-without-folded-apply", False,
      _lowering("fused", _NoFold), ValueError),

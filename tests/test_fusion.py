@@ -14,6 +14,7 @@ masked round engine on the fused backend in tests/test_faults.py.
 """
 
 import dataclasses
+from functools import partial
 
 import numpy as np
 import jax
@@ -24,8 +25,12 @@ from hefl_tpu.models import LogReg, MedCNN, ResNet20, SmallCNN
 from hefl_tpu.models.folded import (
     fold_clients,
     folded_conv,
+    pack_clients,
+    pack_size,
+    packed_group_norm,
     stack_params,
     unfold_clients,
+    unpack_clients,
 )
 
 
@@ -141,6 +146,163 @@ def test_folded_polyphase_stage_matches_vmap_loss_and_every_gradient():
             err_msg=jax.tree_util.keystr(path))
 
 
+# ------------------------------------------- ResNet-20, clients in the lanes
+
+
+def _resnet_clients(c, b=2, hw=16, seed=0):
+    """ResNet-20 with `c` clients of distinct weights (every leaf moved, the
+    zero-started biases too), `b` images a client, labels."""
+    model = ResNet20(num_classes=10)
+    ps = _stacked(model, (hw, hw, 3), c, seed)
+    ps = jax.tree_util.tree_map(
+        lambda t: t + 0.05 * jax.random.normal(jax.random.key(t.size), t.shape), ps)
+    x = jax.random.uniform(jax.random.key(seed + 1), (c, b, hw, hw, 3))
+    labels = jax.random.randint(jax.random.key(seed + 2), (c, b), 0, 10)
+    return model, ps, x, labels
+
+
+def _vmapped_logits(model, ps, x):
+    return jax.vmap(lambda p, xx: model.apply({"params": p}, xx))(ps, x)
+
+
+def _packed_logits(model, ps, x):
+    c = x.shape[0]
+    return unfold_clients(
+        model.folded_apply(ps, fold_clients(x), num_clients=c), c)
+
+
+@pytest.mark.parametrize("c,packs", [
+    (8, (8, 4, 2)),    # one full pack at stage 1, cut in two twice
+    (16, (8, 4, 2)),   # two packs at stage 1
+    (6, (6, 3, 2)),    # 3 -> 2: no cut of the lanes, clients dealt anew
+    (7, (7, 1, 1)),    # a pack of 7, then no divisor but 1
+    (11, (1, 1, 1)),   # no divisor but 1: the plain convolution a client
+])
+def test_packed_resnet_forward_matches_vmap(c, packs):
+    assert tuple(pack_size(c, w) for w in (16, 32, 64)) == packs
+    model, ps, x, _ = _resnet_clients(c)
+    ref = jax.jit(partial(_vmapped_logits, model))(ps, x)
+    got = jax.jit(partial(_packed_logits, model))(ps, x)
+    assert got.dtype == jnp.float32 and got.shape == (c, 2, 10)
+    # bfloat16 logits: one rounding step is 0.0625 at a logit of 8
+    np.testing.assert_allclose(
+        np.asarray(ref), np.asarray(got), atol=5e-2, rtol=1e-2)
+
+
+@pytest.mark.parametrize("c", [8, 6])
+def test_packed_resnet_loss_and_every_gradient_match_vmap(c):
+    # The block-diagonal kernel is built inside the differentiated function:
+    # each client's kernel gradient is its own diagonal block of the dense
+    # convolution's, every leaf against `vmap(grad(model.apply))`.
+    import optax
+
+    model, ps, x, labels = _resnet_clients(c)
+
+    def xent(logits):  # [C, B, classes] -> the clients' mean losses, summed
+        return optax.softmax_cross_entropy_with_integer_labels(logits, labels).mean(-1).sum()
+
+    (l_ref, g_ref), (l_got, g_got) = (
+        jax.jit(jax.value_and_grad(lambda ps: xent(f(model, ps, x))))(ps)
+        for f in (_vmapped_logits, _packed_logits))
+    np.testing.assert_allclose(float(l_got), float(l_ref), rtol=2e-3)
+    flat_ref = jax.tree_util.tree_flatten_with_path(g_ref)[0]
+    assert len(flat_ref) == 65
+    # Two lowerings of 20 bfloat16 layers drift apart: `vmap` against one
+    # `apply` a client reads up to 0.31 a leaf on these inputs, the packed
+    # form 0.15; another client's gradient in a client's place reads 1.3.
+    gap = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(a))  # noqa: E731
+    for (path, ga), gb in zip(flat_ref, jax.tree_util.tree_leaves(g_got)):
+        assert ga.shape == gb.shape and gb.dtype == jnp.float32
+        assert gap(ga, gb) < 0.3, (jax.tree_util.keystr(path), gap(ga, gb))
+        assert gap(ga, jnp.roll(gb, 1, axis=0)) > 1.0, jax.tree_util.keystr(path)
+    whole = lambda g: jnp.concatenate(  # noqa: E731
+        [t.ravel() for t in jax.tree_util.tree_leaves(g)])
+    assert gap(whole(g_ref), whole(g_got)) < 0.2
+
+
+@pytest.mark.parametrize("what", ["images", "weights"])
+def test_packed_resnet_clients_are_independent(what):
+    # The off-diagonal blocks multiply exact zeros: changing one client's
+    # images or weights leaves every other client's logits and gradients
+    # BITWISE unchanged, in the same pack (clients 0-7) and outside it.
+    c, moved = 16, 3
+    model, ps, x, labels = _resnet_clients(c)
+
+    @jax.jit
+    def run(ps, x):
+        def loss(ps):
+            logits = _packed_logits(model, ps, x)
+            picked = jnp.take_along_axis(
+                jax.nn.log_softmax(logits), labels[..., None], -1)
+            return -picked.mean(), logits
+        return jax.grad(loss, has_aux=True)(ps)
+
+    base = run(ps, x)
+    if what == "images":
+        pert = run(ps, x.at[moved].multiply(0.5))
+    else:
+        pert = run(jax.tree_util.tree_map(lambda t: t.at[moved].multiply(1.1), ps), x)
+    others = np.arange(c) != moved
+    for a, b in zip(jax.tree_util.tree_leaves(base), jax.tree_util.tree_leaves(pert)):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_array_equal(a[others], b[others])
+        assert not np.array_equal(a[moved], b[moved])
+
+
+@pytest.mark.parametrize("width", [16, 32, 64])
+def test_packed_group_norm_matches_flax_a_client(width):
+    import flax.linen as nn
+
+    c, b, hw = 8, 3, 6
+    g = pack_size(c, width)
+    assert g * width == 128
+    x = (2.0 * jax.random.normal(jax.random.key(0), (c, b, hw, hw, width)) + 0.5
+         ).astype(jnp.bfloat16)
+    scale = 1.0 + 0.3 * jax.random.normal(jax.random.key(1), (c, width))
+    bias = 0.3 * jax.random.normal(jax.random.key(2), (c, width))
+    norm = nn.GroupNorm(num_groups=8, dtype=jnp.float32)
+
+    def ref(x, scale, bias):
+        return jax.vmap(lambda xx, s, bb: norm.apply(
+            {"params": {"scale": s, "bias": bb}}, xx))(x, scale, bias)
+
+    def got(x, scale, bias):
+        return unpack_clients(
+            packed_group_norm(pack_clients(x, g), scale, bias, g, num_groups=8), g)
+
+    want = ref(x, scale, bias)
+    have = got(x, scale, bias)
+    assert have.dtype == jnp.float32 and have.shape == want.shape
+    np.testing.assert_allclose(np.asarray(want), np.asarray(have), atol=2e-5)
+    cot = jax.random.normal(jax.random.key(3), want.shape)
+    for ga, gb in zip(*(jax.grad(lambda *a: jnp.sum(f(*a) * cot), argnums=(0, 1, 2))(
+            x.astype(jnp.float32), scale, bias) for f in (ref, got))):
+        np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), atol=2e-4)
+
+
+def test_packed_conv_layer_count_is_recorded():
+    # `model.packed_conv_layers`: the convolutions of the last traced
+    # client-folded forward that ran lane-packed, in every run's record.
+    from hefl_tpu.models import create_model
+    from hefl_tpu.obs import metrics as obs_metrics
+
+    def layers():
+        return obs_metrics.snapshot()["model.packed_conv_layers"]
+
+    module, params = create_model("resnet20")
+    assert layers() == 0
+    for c, want in ((8, 21), (7, 9), (11, 0)):
+        jax.eval_shape(
+            lambda p, x: module.folded_apply(p, x, num_clients=c),
+            stack_params(params, c), jnp.zeros((2 * c, 32, 32, 3)))
+        assert layers() == want
+    create_model("medcnn", input_shape=(256, 256, 3))
+    assert layers() == 0
+    obs_metrics.gauge("model.packed_conv_layers").set(21)
+    create_model("joyai_llm_flash_tiny", num_classes=64)  # a token model too
+    assert layers() == 0
+
+
 @pytest.mark.parametrize("strides,padding", [((1, 1), "VALID"), ((2, 2), "SAME")])
 def test_folded_conv_matches_flax_forward_and_grad(strides, padding):
     import flax.linen as nn
@@ -206,6 +368,16 @@ def test_resolve_fusion_backend_pins_and_errors():
     model = SmallCNN(num_classes=10)
     assert fusion.resolve_fusion_backend("vmap", model) == "vmap"
     assert fusion.resolve_fusion_backend(None, model) == "vmap"
+    # a model whose client-folded forward is lane-packed says so, and a pin
+    # is still taken as given
+    packed = ResNet20(num_classes=10)
+    assert fusion.resolve_fusion_backend(None, packed) == "fused"
+    assert fusion.resolve_fusion_backend("vmap", packed) == "vmap"
+    assert fusion.resolve_fusion_backend("fused", packed) == "fused"
+    assert fusion.fusion_report(None, packed) == {
+        "requested": "auto", "backend": "fused"}
+    assert fusion.fusion_report("auto", MedCNN()) == {
+        "requested": "auto", "backend": "vmap"}
     with pytest.raises(ValueError):
         fusion.resolve_fusion_backend("fancy", model)
 

@@ -349,6 +349,8 @@ def test_named_scopes_survive_jit(backend, model, dataset):
               obs_scopes.AGGREGATE, obs_scopes.ADAM, obs_scopes.BATCH
               } | model_scopes
     assert held >= wanted, f"{wanted - held} lost in jit under {backend}"
+    # the augment warp sits inside the step under both lowerings
+    assert f"{obs_scopes.SGD_CORE}/{obs_scopes.AUGMENT}" in chains
     # the model's scopes sit INSIDE the step's and validation's
     for scope in model_scopes:
         assert f"{obs_scopes.SGD_CORE}/{scope}" in chains
