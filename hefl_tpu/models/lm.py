@@ -837,18 +837,8 @@ def held_experts(arch: LMArch, w, x, idx, weights):
             if front:
                 y = y + _held_front(w, xf, order, load, pair_w, k, front)
             return y, load
-        xs = x.astype(BF16)[order // k]                       # [T*k, D]
-        with jax.named_scope(obs_scopes.MOE_GMM):
-            gu = grouped_matmul(xs, w["gate_up"], load)
-        f = gu.shape[-1] // 2
-        hid = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(BF16)
-        with jax.named_scope(obs_scopes.MOE_GMM):
-            ys = grouped_matmul(hid, w["down"], load)
-        # (rows behind the last group, the absent experts', read 0)
-        pair_w = jnp.where(here, weights, 0.0).reshape(t * k)
-        back = jnp.argsort(order)                              # the inverse
-        y = (ys[back] * pair_w[:, None]).reshape(t, k, -1).sum(1)
-        return y, load
+        return _held_whole(w, x.astype(F32), order, load,
+                           jnp.where(here, weights, 0.0)), load
 
 
 def _pair_block(w, x, order, load, pair_w, k: int, rows: int, i):
@@ -1013,6 +1003,74 @@ def _held_front_bwd(k, front, res, dy):
 
 
 _held_front.defvjp(_held_front_fwd, _held_front_bwd)
+
+
+def _sum_held(rows, pos, ws=None):
+    """out[t] = sum over token t's held pairs of (their weight ws [T, k]
+    times, if given) the row of `rows` they sorted to (pos [T, k]; the row
+    count, behind every row, for a pair of an absent expert): the un-sort as
+    one gather a (token, slot). A slot of an absent expert is masked here,
+    behind the gather: no row behind the last group, which the grouped
+    product never wrote, is read into the sum."""
+    live = pos < rows.shape[0]
+    got = rows[jnp.where(live, pos, 0)]                      # [T, k, D]
+    if ws is not None:
+        got = got * ws[..., None]
+    return jnp.sum(jnp.where(live[..., None], got, 0.0), 1)
+
+
+@jax.custom_vjp
+def _held_whole(w, x, order, load, pair_w):
+    """`held_experts` with every sorted pair in one grouped product a matrix
+    (a chip that holds half of the layer's experts or more; pair_w [T, k],
+    0 for a pair of an absent expert). Rows move between token order and
+    expert order once each way, as gathers: the tokens' rows by `order`, the
+    pairs' outputs back by the place each held pair sorted to (`_sum_held`).
+    The rows behind the last group, the absent experts' pairs, are never
+    written, never masked and never read. Its gradient, with respect to x
+    and the pairs' weights, keeps the gate and up products and gathers the
+    same way. -> y f32[T, D]."""
+    return _held_whole_fwd(w, x, order, load, pair_w)[0]
+
+
+def _held_whole_fwd(w, x, order, load, pair_w):
+    tokens = order // pair_w.shape[1]
+    xs = x.astype(BF16)[tokens]                              # [T * k, D]
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        gu = _gmm_call(xs, w["gate_up"], load, False)
+    f = gu.shape[-1] // 2
+    hid = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(BF16)
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        ys = _gmm_call(hid, w["down"], load, False)
+    place = jnp.argsort(order).astype(jnp.int32)             # the inverse
+    pos = jnp.where(place < jnp.sum(load), place,            # held sort first
+                    place.shape[0]).reshape(pair_w.shape)
+    return _sum_held(ys, pos, pair_w), (
+        w, gu, tokens, load, pair_w.reshape(-1)[order], pos)
+
+
+def _held_whole_bwd(res, dy):
+    w, gu, tokens, load, ws, pos = res
+    dys = dy.astype(BF16)[tokens]
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        # dy @ down^T a row; the pair's weight comes in behind it
+        dh = _gmm_call(dys, w["down"], load, True)
+    f = gu.shape[-1] // 2
+    g, u = gu[:, :f], gu[:, f:]
+    s = jax.nn.sigmoid(g)
+    act = g * s
+    dws = jnp.sum((act * u).astype(BF16).astype(F32) * dh, -1)
+    dh = dh * ws[:, None]
+    dgu = jnp.concatenate([dh * u * (s + act * (1.0 - s)), dh * act],
+                          -1).astype(BF16)
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        dxs = _gmm_call(dgu, w["gate_up"], load, True)
+    live = pos < dws.shape[0]
+    return (None, _sum_held(dxs, pos), None, None,
+            jnp.where(live, dws[jnp.where(live, pos, 0)], 0.0))
+
+
+_held_whole.defvjp(_held_whole_fwd, _held_whole_bwd)
 
 
 def expert_layer(arch: LMArch, w, router, x):

@@ -341,6 +341,111 @@ def test_planted_imbalance_drops_no_pair(case):
         assert float(jnp.max(jnp.abs(a - b))) < 0.05 * float(jnp.max(jnp.abs(b)))
 
 
+# joyai's shares at the tests' widths: 8 selections a token, half of the
+# experts held, 4 held pairs a token on average
+WIDE = dataclasses.replace(TINY, n_experts=16, held_experts=8, experts_per_tok=8)
+
+
+def _planted_selections(kind: str, tokens: int = 40):
+    """idx int32[tokens, 8], distinct in a token: how many of a token's
+    eight experts are held (0-7 of 16) is planted, the slots shuffled."""
+    rng = np.random.default_rng(12)
+    held = {"uniform": None,
+            "all_or_none": np.where(np.arange(tokens) % 2, 8, 0),
+            "none_held": np.zeros(tokens, int),
+            "all_held": np.full(tokens, 8),
+            "most_held": rng.integers(3, 9, tokens)}[kind]
+    rows = []
+    for t in range(tokens):
+        if held is None:
+            rows.append(rng.permutation(16)[:8])
+        else:
+            rows.append(rng.permutation(np.concatenate(
+                [rng.permutation(8)[:held[t]], 8 + rng.permutation(8)[:8 - held[t]]])))
+    return jnp.asarray(np.stack(rows), jnp.int32)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """The joyai reference's expert layer at `WIDE` and seeded weights."""
+    ref = _load(os.path.join(ROOT, "benchmarks", "reference",
+                             REFERENCES["joyai_llm_flash_tiny"]))
+    conf = _conf(WIDE)
+    return types.SimpleNamespace(
+        ref=ref, z=ref._sizes(conf),
+        w=ref.init(5, conf)["base"]["blocks"][1]["experts"],
+        mm=lambda a, b: a @ b.astype(jnp.float32),
+        router=0.5 * jax.random.normal(jax.random.key(9), (16, WIDE.hidden)),
+        x=jax.random.normal(jax.random.key(4), (40, WIDE.hidden), jnp.float32))
+
+
+def _routed_weights(arch, router, x, idx):
+    """`route`'s weights for planted selections: differentiable in x and the
+    router."""
+    s = jnp.take_along_axis(jax.nn.sigmoid(x @ router.T), idx, axis=-1)
+    return arch.routed_scaling * s / jnp.sum(s, -1, keepdims=True)
+
+
+def _one_block_against_reference(wide, idx):
+    """(outputs, gradients by x and the router) of the one-block path and
+    of the reference's expert layer."""
+    ct = jax.random.normal(jax.random.key(8), wide.x.shape)
+    sys_fn = lambda x, r: lm.held_experts(  # noqa: E731
+        WIDE, wide.w, x, idx, _routed_weights(WIDE, r, x, idx))[0]
+    ref_fn = lambda x, r: wide.ref._experts(  # noqa: E731
+        wide.z, wide.w, x, idx, _routed_weights(WIDE, r, x, idx), wide.mm,
+        None, None)[0]
+    got = (sys_fn(wide.x, wide.router),) + jax.grad(
+        lambda x, r: jnp.sum(sys_fn(x, r) * ct), (0, 1))(wide.x, wide.router)
+    want = _highest(lambda: (ref_fn(wide.x, wide.router),) + jax.grad(
+        lambda x, r: jnp.sum(ref_fn(x, r) * ct), (0, 1))(wide.x, wide.router))
+    return got, want
+
+
+@pytest.mark.parametrize("kind", ["uniform", "all_or_none", "none_held",
+                                  "all_held", "most_held"])
+def test_one_block_path_matches_reference_under_planted_routing(kind, wide):
+    """`_held_whole` (a chip that holds half of the experts: one grouped
+    product, the un-sort and both gradients as gathers of the held rows)
+    against the reference's expert layer: value, gradient by x and by the
+    router, from no held pair at all to every pair held."""
+    assert lm.pair_blocks(WIDE, 320) == (1, 320)
+    idx = _planted_selections(kind)
+    held = int(np.sum(np.asarray(idx) < 8))
+    assert {"none_held": held == 0, "all_held": held == 320,
+            "most_held": held > 200}.get(kind, 120 < held < 200)
+    got, want = _one_block_against_reference(wide, idx)
+    for a, b, tol in zip(got, want, (0.02, 0.05, 0.05)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert float(jnp.max(jnp.abs(a - b))) <= tol * float(jnp.max(jnp.abs(b)))
+    if kind == "none_held":
+        assert not any(np.any(np.asarray(a)) for a in got)
+
+
+def test_rows_behind_the_last_group_are_never_read(wide, monkeypatch):
+    """The grouped product leaves the rows behind its last group unwritten
+    (on the chip: whatever the buffer held). Poisoned with NaN, in both
+    directions, they change no output and no gradient of the one-block
+    path: no select pass cleans them, so nothing may read them."""
+    idx = _planted_selections("uniform")
+    clean, _ = _one_block_against_reference(wide, idx)
+    call, poisoned = lm._gmm_call, []
+
+    def poison(x, w, sizes, transpose, rows=lm.GMM_ROWS):
+        out = call(x, w, sizes, transpose, rows)
+        behind = jnp.arange(out.shape[0]) >= jnp.sum(sizes)
+        poisoned.append(int(jnp.sum(behind)))
+        return jnp.where(behind[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(lm, "_gmm_call", poison)
+    got, _ = _one_block_against_reference(wide, idx)
+    # the value's two products, then the gradient's two each way
+    assert len(poisoned) == 6 and min(poisoned) > 100
+    for a, b in zip(got, clean):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_no_held_pair_and_every_pair_held_cost_their_blocks():
     """Blocks of the held pairs alone: none when no token is routed here,
     all of them when every token is (nothing dropped either way)."""
@@ -504,11 +609,16 @@ def test_attention_over_a_selection_and_its_gradients_match_plain_attention():
 def test_loss_with_a_lean_tail_is_the_loss(case, monkeypatch):
     """Where a [tokens, hidden] float32 array is large the heads' last norms
     and the prediction module's input are made again for the gradient:
-    the same loss, the same gradient."""
+    the same loss, the same gradient. Each form compiled whole, as a round
+    program holds it: op by op the lean heads' backward (fused inside its
+    checkpoint) differs from the kept one in a float32's last bits, and a
+    bfloat16 rounding in the attention below that falls the other way for it
+    moves a gain's gradient by 0.3% (the expert layer's gradient by x is
+    float32 since PR 40; rounded to bfloat16 it used to hide those bits)."""
     v, tokens = case.variables, case.tokens
     module = lm.FrozenBaseLM(num_classes=VOCAB, arch=case.arch, seed=3)
-    vg = lambda: jax.value_and_grad(lambda q: module.loss(  # noqa: E731
-        {"base": v["base"], "params": q}, tokens)[0])(v["params"])
+    vg = lambda: jax.jit(jax.value_and_grad(lambda q: module.loss(  # noqa: E731
+        {"base": v["base"], "params": q}, tokens)[0]))(v["params"])
     l_kept, g_kept = vg()
     monkeypatch.setattr(lm, "STREAM_BYTES", 0)
     l_lean, g_lean = vg()

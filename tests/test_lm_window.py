@@ -647,14 +647,16 @@ def _program_digest(closed) -> str:
 
 
 @pytest.mark.parametrize("name,positions,digest", [
-    ("joyai_llm_flash_tiny", 24, "3a6633ee86b7eed0"),
+    ("joyai_llm_flash_tiny", 24, "cf307f770ca7df44"),
     ("deepseek_v32_tiny", 40, "9181a7b43929e507"),
 ])
 def test_the_older_models_traced_programs_are_unchanged(name, positions, digest):
     """The loss and its gradient of the two DeepSeek-V3-shaped presets,
-    traced: the digests are those of commit c298e53 (PR 32), before a third
-    model came out of the same class. A change of the installed JAX moves
-    them too: then read them again from that commit."""
+    traced: deepseek's digest is that of commit c298e53 (PR 32), before a
+    third model came out of the same class; joyai's is PR 40's, whose
+    one-block expert layer (`_held_whole`) is the one change to its program
+    since (3a6633ee86b7eed0 before). A change of the installed JAX moves
+    them too: then read them again from those commits."""
     module = lm.FrozenBaseLM(num_classes=50, arch=lm.PRESETS[name], seed=3)
     p, base = jax.eval_shape(module.init_trained), jax.eval_shape(module.init_base)
     tokens = jax.ShapeDtypeStruct((2, positions + 2), jnp.int32)

@@ -115,6 +115,64 @@ def test_grouped_expert_product_compiles_for_described_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
+@pytest.mark.parametrize("gradient", [False, True], ids=["forward", "gradient"])
+def test_one_block_expert_layer_moves_its_rows_by_gathers_on_a_described_v5e(
+        gradient, one_chip, monkeypatch):
+    """`joyai-flash.sync_s4k`'s expert layer alone (`models/lm.held_experts`
+    where a chip holds half of the experts: 8,192 tokens x 8 selections, 128
+    of 256 experts of 2048 x 768 held), forward and with its gradient by x
+    and the pairs' weights, compiled for a described chip keeps the form PR
+    40 gave it (PERF.md section 6): rows move between token order and expert
+    order as gathers. No scatter takes 65,536 rows of updates (autodiff's
+    un-sort and gather transposes did: into `f32[65536,2048]` and
+    `bf16[8192,2048]`), no `select` passes over an `f32[65536, ...]` array
+    (`_masked_rows` did, twice a pass), and the 65,536 keys are sorted twice
+    and nothing else is (four times with autodiff's gradient). The forward
+    reads and writes fewer bytes than the parent's branch by the compiler's
+    count (13.81 GB against 15.67). With the gradient the count is no
+    yardstick: it reads 32.68 GB here against the parent's 25.94, which the
+    HBM could not move in the 21.4 ms the chip takes (36.9 for the parent's:
+    PERF.md section 6, PR 40)."""
+    from hefl_tpu.models import lm
+
+    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    arch = lm.PRESETS["joyai_llm_flash"]
+    t, k, d, f, held = (8192, arch.experts_per_tok, arch.hidden,
+                        arch.moe_intermediate, arch.held_experts)
+    pairs = t * k
+    assert lm.pair_blocks(arch, pairs) == (1, 65536)
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    w = {"gate_up": on_chip((held, d, 2 * f), jnp.bfloat16),
+         "down": on_chip((held, f, d), jnp.bfloat16)}
+    x, idx, weights = (on_chip((t, d), jnp.float32), on_chip((t, k), jnp.int32),
+                       on_chip((t, k), jnp.float32))
+    layer = lambda x, p, w, i: lm.held_experts(arch, w, x, i, p)[0]  # noqa: E731
+    if gradient:
+        layer = jax.grad(lambda x, p, w, i: jnp.sum(lm.held_experts(
+            arch, w, x, i, p)[0] ** 2), argnums=(0, 1))
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(layer).lower(x, weights, w, idx).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= (4 if gradient else 2)
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    updates = [shape_of[m.group(1)] for m in re.finditer(
+        r" scatter\(%[\w.\-]+, %[\w.\-]+, %([\w.\-]+)\)", text)]
+    # (what is left: the grouped product's own group bookkeeping, and the
+    # experts' loads, a `bincount` of 65,536 scalars into s32[129])
+    assert updates and not [u for u in updates if "," in u], updates
+    assert not re.findall(rf"= f32\[{pairs},\d+\]\S* select\(", text)
+    sorts = re.findall(r"= \((\w+\[[\d,]*\])\{.*\) sort\(", text)
+    assert sorts == [f"s32[{pairs}]"] * 2, sorts
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert gradient or cost["bytes accessed"] < 15.67e9, cost
+
+
 def test_fused_attention_gradient_compiles_for_described_v5e(
         one_chip, monkeypatch):
     """`jax.grad` of `models/lm.causal_attention` at JoyAI-LLM-Flash's widths
