@@ -46,6 +46,8 @@ TOKEN_MODELS: dict[str, int] = {
     "deepseek_v32_tiny": 64,
     "mimo_v2_flash": 19072,
     "mimo_v2_flash_tiny": 64,
+    "ling_3_flash": 19648,
+    "ling_3_flash_tiny": 64,
 }
 
 
@@ -89,6 +91,8 @@ def create_model(
     obs_metrics.gauge("model.fused_attention_layers").set(0)
     obs_metrics.gauge("model.sparse_attention_layers").set(0)
     obs_metrics.gauge("model.window_attention_layers").set(0)
+    obs_metrics.gauge("model.linear_attention_layers").set(0)
+    obs_metrics.gauge("model.gated_attention_layers").set(0)
     dummy = jnp.zeros(
         (1, *(input_shape if input_shape is not None else default_shape)), jnp.float32
     )

@@ -1,8 +1,9 @@
 """Expert language models as a frozen base and a trained subset.
 
-One module class, `FrozenBaseLM`, over an `LMArch`; three published models,
+One module class, `FrozenBaseLM`, over an `LMArch`; four published models,
 two of them DeepSeek-V3-shaped (latent attention, one shared expert, a
-prediction module) and one with grouped-query attention of two kinds:
+prediction module), one with grouped-query attention of two kinds and one
+whose layers carry a state along the sequence:
 
   * **JoyAI-LLM-Flash** (`PRESETS["joyai_llm_flash"]`; huggingface.co/
     jdopensource/JoyAI-LLM-Flash, config.json; every key a DeepSeek-V3 key,
@@ -41,11 +42,37 @@ prediction module) and one with grouped-query attention of two kinds:
     number of rows with gathers around it (`_held_front`: the same work
     whatever the routers do) and only what is behind `pair_front` in blocks.
     `benchmarks/configs/mimo-v2-flash-l7e16.json` lists what is assumed.
+  * **Ling-3.0-flash** (`PRESETS["ling_3_flash"]`; huggingface.co/inclusionAI/
+    Ling-3.0-flash, `model_type` `bailing_hybrid`): **five delta-rule
+    linear-attention layers to one latent layer** (`layer_pattern`: `LINEAR`
+    where (i + 1) % 6 != 0). A linear layer is Kimi Delta Attention
+    (arXiv:2510.26692; `kda_layer`): q, k, v = SiLU(conv4(x W)) (a depthwise
+    causal convolution over the 4 last positions), q and k L2-normalised a
+    head, a decay a channel g_t = -5 sigmoid(exp(A_log) (x_t W_f + dt_bias))
+    and beta_t = sigmoid(x_t W_beta) a head, then a head's 128 x 128 float32
+    state: S' = Diag(exp g_t) S_{t-1}; S_t = S' + beta_t k_t (v_t - S'^T
+    k_t)^T; o_t = S_t^T q_t; y = (RMSNorm_head(o_t) * sigmoid(x_t W_g)) W_o.
+    It runs in chunks of 64 positions whose triangular system is solved in
+    sub-blocks of 16, the state carried from chunk to chunk by a `lax.scan`
+    (`kda_recurrence`: XLA operations, its gradient by differentiation of
+    the checkpointed chunk body; no kernel written here). The latent layer
+    is DeepSeek-V3's **without a query low-rank and with a sigmoid gate a
+    head on attention's output** (`_attend` with `q_lora_rank` 0 and
+    `attn_gate`). 512 routed experts in 8 groups of which 4 stay, 128 held,
+    one shared expert, no prediction module (its published loss weight is
+    0). **What its cold run forced** (`hybrid_layers`): the base stacked by
+    kind of layer and the expert layers steps of one `lax.scan`, so that an
+    expert layer and each kind of attention compile once whatever the
+    depth; and in a step of that scan, where `held_experts` is told its
+    layer's place among the stacked experts (`at`), an expert layer with no
+    sort in it (`_held_counted`).
+    `benchmarks/configs/ling-3-flash-l6e128.json` lists what is assumed.
 
 Which attention a layer runs follows from its `arch` (`kv_heads` empty:
-latent). With `index_topk` 0, `n_group` 1 and no `rope_scaling` the traced
-program is the first model's, operation for operation; with `kv_heads`
-empty, one shared expert and one prediction module it is the first two's.
+latent, or by `layer_pattern` linear where `kda_head_dim` is set). With
+`index_topk` 0, `n_group` 1 and no `rope_scaling` the traced program is the
+first model's, operation for operation; with `kv_heads` empty, one shared
+expert and one prediction module it is the first two's.
 
 What a federation can afford of such a model (PERF.md, PR 26-27: a trained
 parameter costs a client 16 bytes for its step and 24 for its ciphertext, a
@@ -57,7 +84,8 @@ frozen one 2) decides the layout. The parameters are two pytrees:
     optimizer, no `PackSpec` and no ciphertext, and no gradient with respect
     to it is ever formed.
   * the **trained subset** (`init_trained`): every router matrix, every
-    RMSNorm gain and every sink, float32. It is what `create_model` returns
+    RMSNorm gain, every sink and every linear layer's `A_log` and `dt_bias`,
+    float32. It is what `create_model` returns
     as `params`: what is stepped, encrypted, summed and decrypted.
 
 The expert layer is told which experts it holds (`held_start`,
@@ -155,6 +183,22 @@ class LMArch:
     value_scale: float = 1.0          # v is scaled by it
     shared_experts: int = 1           # 0: an expert layer is its routed part
     mtp_modules: int = 1              # 0: one head, next-token loss alone
+    # delta-rule linear attention (`kda_head_dim` 0: none): a layer is of
+    # kind `layer_pattern[layer]`, `LATENT` or `LINEAR`; a linear layer has
+    # `heads` heads whose keys and values are `kda_head_dim` wide, a
+    # depthwise causal convolution over the `kda_conv` last positions and a
+    # decay a channel in (`kda_lower_bound`, 0); its recurrence runs in
+    # chunks of `kda_chunk` positions whose triangular system is solved in
+    # sub-blocks of `kda_block` (`kda_recurrence`)
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0
+    kda_chunk: int = 64
+    kda_block: int = 16
+    # latent attention with `q_lora_rank` 0 makes its queries straight from
+    # x (`q`, no `q_norm`); with `attn_gate` a head's output is scaled by
+    # sigmoid(x W_gate) of that head before `o`
+    attn_gate: bool = False
 
 
 PRESETS = {
@@ -202,7 +246,27 @@ PRESETS = {
         sinks=(False, True), layer_pattern=(0, 1, 1, 0), window=8,
         value_scale=0.707, shared_experts=0, mtp_modules=0,
         pair_block=32, q_block=128, loss_chunk=16),
+    # the benchmark's `ling-3-flash-l6e128`: every width as published, the
+    # published layers 1-6 (one leading dense layer and a whole period: five
+    # linear layers to one latent layer), a chip's share of 4 that divide
+    # every expert layer (experts 0-127: groups 0 and 1 of 8)
+    "ling_3_flash": LMArch(
+        hidden=2560, heads=32, q_lora_rank=0, intermediate=6144,
+        n_experts=512, rope_theta=6_000_000.0, expert_layers=5, n_group=8,
+        topk_group=4, layer_pattern=(1, 1, 1, 1, 0, 1), kda_head_dim=128,
+        attn_gate=True, mtp_modules=0, pair_front=32768),
+    "ling_3_flash_tiny": LMArch(
+        hidden=64, heads=4, q_lora_rank=0, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        intermediate=128, moe_intermediate=32, n_experts=16,
+        experts_per_tok=2, rope_theta=6_000_000.0, expert_layers=3,
+        held_experts=4, n_group=4, topk_group=2, layer_pattern=(1, 1, 0, 1),
+        kda_head_dim=16, kda_chunk=16, kda_block=4, attn_gate=True,
+        mtp_modules=0, pair_block=32, pair_front=64, q_block=128,
+        loss_chunk=16),
 }
+
+LATENT, LINEAR = 0, 1   # `layer_pattern`'s kinds where `kda_head_dim` is set
 
 
 def is_token_model(module) -> bool:
@@ -458,8 +522,14 @@ def _kept(arch: LMArch):
     heads of 8,192 positions a layer: 0.27 GB that the step has no room
     for) and nothing where attention is grouped (64 heads of 8,192
     positions in each of seven layers held 1.7 GB of the step's temporaries
-    by the compiler's count, and put the round over 14 GB)."""
-    if arch.index_topk or arch.kv_heads:
+    by the compiler's count, and put the round over 14 GB). Nothing either
+    where a model has linear layers: its layers are steps of one scan, and a
+    name kept in a step is kept for every step. Of a linear layer's
+    recurrence the state at each chunk's edge is kept while that layer's
+    gradient is made, by the scan over its chunks itself (128 x [32, 128,
+    128] float32, 0.27 GB, for the one layer), and nothing from layer to
+    layer: with the base at 8.6 GB the step has 6.4 GB."""
+    if arch.index_topk or arch.kv_heads or arch.kda_head_dim:
         return jax.checkpoint_policies.nothing_saveable
     return jax.checkpoint_policies.save_only_these_names(ATTN_SAVED)
 
@@ -616,10 +686,14 @@ def _attend(arch: LMArch, w, g, x):
         b, s, _ = x.shape
         h, dn, dr, dv = (arch.heads, arch.qk_nope_head_dim,
                          arch.qk_rope_head_dim, arch.v_head_dim)
-        c_q = rms_norm(_mm(x, w["q_a"]), g["q_norm"], arch.eps)
-        if arch.index_topk:
-            return _attend_selected(arch, w, g, x, c_q)
-        q = _mm(c_q, w["q_b"]).reshape(b, s, h, dn + dr)
+        if arch.q_lora_rank:
+            c_q = rms_norm(_mm(x, w["q_a"]), g["q_norm"], arch.eps)
+            if arch.index_topk:
+                return _attend_selected(arch, w, g, x, c_q)
+            q = _mm(c_q, w["q_b"])
+        else:                             # no query low-rank, no `q_norm`
+            q = _mm(x, w["q"])
+        q = q.reshape(b, s, h, dn + dr)
         kv_a = _mm(x, w["kv_a"])
         c_kv, k_r = kv_a[..., :arch.kv_lora_rank], kv_a[..., arch.kv_lora_rank:]
         kv = _mm(rms_norm(c_kv, g["kv_norm"], arch.eps), w["kv_b"]).reshape(
@@ -632,6 +706,8 @@ def _attend(arch: LMArch, w, g, x):
             [kv[..., :dn], jnp.broadcast_to(k_r, (b, s, h, dr))], -1)
         o = causal_attention(q, k, kv[..., dn:], arch.q_block,
                              softmax_scale(arch))
+        if arch.attn_gate:                # one scalar a head, from x
+            o = o * jax.nn.sigmoid(_mm(x, w["gate"]))[..., None]
         return _mm(o.reshape(b, s, h * dv), w["o"]), None
 
 
@@ -666,6 +742,206 @@ def _attend_selected(arch: LMArch, w, g, x, c_q):
                                picked, arch.q_block)
     return (_mm(o.reshape(b, s, h * dv), w["o"]),
             jnp.sum(picked, dtype=jnp.int32))
+
+
+# --------------------------------------------------------------------------
+# delta-rule linear attention (Kimi Delta Attention, arXiv:2510.26692)
+# --------------------------------------------------------------------------
+
+
+def short_conv(z, w):
+    """Depthwise causal convolution over the last K positions: y[t, c] =
+    sum_j w[c, j] z[t - (K - 1) + j, c] (w[:, K - 1] weighs position t
+    itself; positions before the sequence read 0). z: f32[B, S, C], w:
+    [C, K] -> f32[B, S, C], as K shifted multiply-adds."""
+    k = w.shape[1]
+    w = w.astype(F32)
+    padded = jnp.pad(z, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + z.shape[1]] * w[:, j] for j in range(k))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _unit_lower_inverse(n, block: int):
+    """(I + n)^-1 for n f32[..., C, C] strictly lower triangular, solved in
+    sub-blocks of `block`, every step a [C, C] product in float32 at the
+    highest precision: with n = d + l, d its diagonal sub-blocks and l what
+    is below them, (I + d)^-1 is the product (I - d)(I + d^2)(I + d^4)... of
+    the nilpotent d (d^block = 0: exact, and block-diagonal like d); then
+    I + n = (I + d)(I + m), m = (I + d)^-1 l, and m is nilpotent by blocks
+    (m^(C / block) = 0), so (I + m)^-1 is the same product over m: the
+    substitution down the block rows, as products. Its gradient is the
+    inverse's own: -T^T dT T^T."""
+    c = n.shape[-1]
+    mm = functools.partial(jnp.matmul, precision=HIGHEST)
+    eye = jnp.eye(c, dtype=F32)
+
+    def nilpotent_inverse(x, order: int):     # (I + x)^-1, x^order = 0
+        inv, power = eye - x, x
+        for _ in range(max((order - 1).bit_length() - 1, 0)):
+            power = mm(power, power)
+            inv = mm(inv, eye + power)
+        return inv
+
+    at = jnp.arange(c) // block
+    d = jnp.where(at[:, None] == at[None, :], n, 0.0)
+    d_inv = nilpotent_inverse(d, block)
+    return mm(nilpotent_inverse(mm(d_inv, n - d), c // block), d_inv)
+
+
+def _unit_lower_inverse_fwd(n, block):
+    inv = _unit_lower_inverse(n, block)
+    return inv, inv
+
+
+def _unit_lower_inverse_bwd(block, inv, d_inv):
+    mm = functools.partial(jnp.matmul, precision=HIGHEST)
+    t = jnp.swapaxes(inv, -1, -2)
+    return (-mm(t, mm(d_inv, t)),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+KDA_GROUP = 16   # chunks whose inside is made together (and again for the gradient)
+
+
+def kda_recurrence(q, k, v, g, beta, chunk: int = 64, block: int = 16,
+                   bound: float = 5.0, carry=True, operands=BF16):
+    """The gated delta rule, a head at a time: S' = Diag(exp(g_t)) S_{t-1};
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T; o_t = S_t^T q_t, S_0 = 0.
+    q, k, g: f32[B, S, H, dk] (0 >= g >= -`bound`), v: [B, S, H, dv], beta:
+    [B, S, H] -> o f32[B, S, H, dv].
+
+    The chunked form. Inside a chunk of `chunk` positions, with G the
+    running sum of g from the chunk's first position and S the state the
+    chunk starts from, the deltas u_t = v_t - S'^T k_t solve the unit lower
+    triangular system (I + A Diag(beta)) U = V - (K exp G) S, A[t, r] = sum_c
+    k_t[c] k_r[c] exp(G_t[c] - G_r[c]) for r < t, and O = (Q exp G) S + (B
+    Diag(beta)) U with B the same product of q_t and k_r for r <= t. A and B
+    are made a sub-block of `block` rows at a time with the exponent split at
+    the sub-block's first position, exp(G_t - G_b) exp(G_b - G_r): the first
+    factor's exponent lies in [-block * bound, 0] and the second's below 0
+    for an earlier sub-block, and in (0, block * bound] inside the row's own
+    (16 x 5 = 80 < 88: float32 and bfloat16 hold it; a later sub-block's
+    positions, masked for every row, are given no exponent). The system is
+    solved in the same sub-blocks (`_unit_lower_inverse`). Chunks follow one
+    another in a `lax.scan` that
+    carries S f32[B, H, dk, dv]: U = U^ - W S, O, then S <- Diag(exp G_C) S
+    + (K exp(G_C - G))^T (beta U). Matrix products take bfloat16 operands
+    and accumulate in float32 (the inverse's small ones: float32); g, its
+    sums, every decay and S are float32. What does not depend on S is made
+    `KDA_GROUP` chunks at a time ahead of the scan; it and the chunk body
+    are made again for the gradient (`jax.checkpoint`): the scan keeps the
+    state at each chunk's edge and nothing else of a chunk (`_kept`). A sequence that is no
+    multiple of `chunk` is padded at its end with positions that write
+    nothing (k, v, beta, g = 0). Without `carry` every chunk starts from S =
+    0 (what a form that drops the state at a chunk's edge computes: the
+    tests' and the check's control). With `operands` float32 every product
+    takes float32 operands at the highest precision (the tests' witness of
+    what the bfloat16 operands cost the decay's gradient; no model runs
+    it)."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = (-s) % chunk
+    n, m = (s + pad) // chunk, chunk // block
+    group = next(c for c in range(min(n, KDA_GROUP), 0, -1) if n % c == 0)
+
+    def by_chunk(t):          # [B, S, H, ...] -> [n / group, group, B, H, chunk, ...]
+        t = jnp.pad(t.astype(F32), ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        t = jnp.moveaxis(t.reshape(b, n, chunk, h, *t.shape[3:]), (1, 3), (0, 2))
+        return t.reshape(n // group, group, *t.shape[1:])
+
+    bmm = lambda eq, x, y: jnp.einsum(  # noqa: E731
+        eq, x.astype(operands), y.astype(operands), preferred_element_type=F32,
+        precision=HIGHEST if jnp.dtype(operands) == jnp.dtype(F32) else None)
+
+    @jax.checkpoint
+    def within(part):                 # `group` chunks, each by itself
+        with jax.named_scope(obs_scopes.KDA_SCAN):
+            q, k, v, g, beta = part                        # [group, B, H, chunk, .]
+            run = jnp.cumsum(g, axis=-2)                   # G, inclusive
+            by_block = lambda t: t.reshape(  # noqa: E731
+                *t.shape[:-2], m, block, t.shape[-1])
+            # G just before each sub-block's first position
+            start = jnp.concatenate(
+                [jnp.zeros_like(run[..., :1, :]),
+                 run[..., block - 1:chunk - 1:block, :]], -2)   # [..., m, dk]
+            near = jnp.exp(by_block(run) - start[..., None, :])
+            # (a later sub-block's positions are masked for all of this
+            # one's rows, and their exponent, up to chunk * bound, is left out)
+            ahead = (jnp.arange(chunk) // block)[None, :] > jnp.arange(m)[:, None]
+            far = jnp.exp(jnp.where(
+                ahead[:, :, None], 0.0,
+                start[..., :, None, :] - run[..., None, :, :]))
+            k_far = k[..., None, :, :] * far               # [..., m, chunk, dk]
+            pairs = lambda rows: bmm(  # noqa: E731
+                "...mic,...mjc->...mij", by_block(rows) * near, k_far).reshape(
+                    *rows.shape[:-2], chunk, chunk)
+            t_pos = jnp.arange(chunk)
+            a = jnp.where(t_pos[:, None] > t_pos[None, :], pairs(k), 0.0)
+            b_qk = jnp.where(t_pos[:, None] >= t_pos[None, :], pairs(q), 0.0)
+            solve = _unit_lower_inverse(a * beta[..., None, :], block)
+            solve = solve * beta[..., :, None]             # beta U, not U
+            last = run[..., -1:, :]
+            # (what only a product reads is kept as the product takes it)
+            return (bmm("...tr,...rd->...td", solve, v),
+                    bmm("...tr,...rc->...tc", solve,
+                        k * jnp.exp(run)).astype(operands),
+                    (q * jnp.exp(run)).astype(operands),
+                    (k * jnp.exp(last - run)).astype(operands),
+                    b_qk.astype(operands),
+                    jnp.exp(last[..., 0, :]))              # [group, B, H, dk]
+
+    @jax.checkpoint
+    def one(state, part):             # a chunk, from the state before it
+        u_hat, w_in, q_in, k_out, b_qk, decay = part
+        with jax.named_scope(obs_scopes.KDA_SCAN):
+            u = u_hat - bmm("bhtc,bhcd->bhtd", w_in, state)
+            o = bmm("bhtc,bhcd->bhtd", q_in, state) + bmm(
+                "bhtr,bhrd->bhtd", b_qk, u)
+            new = decay[..., None] * state + bmm("bhtc,bhtd->bhcd", k_out, u)
+        return (new if carry else state), o
+
+    parts = jax.lax.map(within, tuple(by_chunk(t) for t in (q, k, v, g, beta)))
+    _, o = jax.lax.scan(one, jnp.zeros((b, h, dk, dv), F32), tuple(
+        t.reshape(n, *t.shape[2:]) for t in parts))
+    # [n, B, H, chunk, dv] -> [B, S, H, dv]
+    return o.transpose(1, 0, 3, 2, 4).reshape(b, n * chunk, h, dv)[:, :s]
+
+
+def kda_layer(arch: LMArch, w, g, x):
+    """One linear-attention layer. w: the block's frozen matrices: `in` [D,
+    5 H d], the projections W_q, W_k, W_v, W_f (the decay's) and W_g (the
+    output gate's) side by side as `gate_up` keeps two (one product makes all
+    five: one to compile, in each direction), `beta` [D, H], `conv` [3 H d,
+    K] the short convolutions of q, k and v, `o` [H d, D]; g: its trained
+    leaves (`A_log` [H], `dt_bias` [H d], `o_norm` [d]); x: [B, S, D]
+    (already normed).
+    q, k, v = silu(conv(x W)); q and k L2-normalised a head, q scaled by
+    d^-1/2; the decay a channel g_t = lower_bound * sigmoid(exp(A_log) *
+    (x W_f + dt_bias)); beta = sigmoid(x W_beta) a head; the recurrence
+    (`kda_recurrence`); y = (RMSNorm_head(o) * sigmoid(x W_g)) W_o."""
+    with jax.named_scope(obs_scopes.KDA):
+        b, s, _ = x.shape
+        h, d = arch.heads, arch.kda_head_dim
+        n = h * d
+        heads = lambda t: t.reshape(b, s, h, d)  # noqa: E731
+        made = _mm(x, w["in"])
+        q, k, v = (heads(jax.nn.silu(short_conv(
+            made[..., i * n:(i + 1) * n], w["conv"][i * n:(i + 1) * n])))
+            for i in range(3))
+        unit = lambda t: t * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(t * t, -1, keepdims=True) + arch.eps)
+        q, k = unit(q) * d ** -0.5, unit(k)
+        decay = arch.kda_lower_bound * jax.nn.sigmoid(
+            jnp.exp(g["A_log"])[:, None]
+            * heads(made[..., 3 * n:4 * n] + g["dt_bias"]))
+        beta = jax.nn.sigmoid(_mm(x, w["beta"]))
+        o = kda_recurrence(q, k, v, decay, beta, arch.kda_chunk, arch.kda_block,
+                           -arch.kda_lower_bound)
+        o = rms_norm(o, g["o_norm"], arch.eps) * jax.nn.sigmoid(
+            heads(made[..., 4 * n:5 * n]))
+        return _mm(o.reshape(b, s, n), w["o"])
 
 
 def glu(w, x):
@@ -807,7 +1083,7 @@ def front_pairs(arch: LMArch, pairs: int) -> int:
     return min(arch.pair_front, pairs) // rows * rows if blocks > 1 else 0
 
 
-def held_experts(arch: LMArch, w, x, idx, weights):
+def held_experts(arch: LMArch, w, x, idx, weights, at=None):
     """The held experts' part of the layer: sum over the selected experts
     that live here of weight * E(x). Every (token, held expert) pair is
     computed, sorted by expert into a grouped matrix product; pairs of
@@ -816,7 +1092,12 @@ def held_experts(arch: LMArch, w, x, idx, weights):
     held pairs' blocks alone, as many as hold them (`_held_blocks`), behind
     a front of `pair_front` sorted pairs in one product of that many rows
     (`_held_front`: a fixed capacity, the same work whatever the routers
-    do): still every pair, whatever the imbalance.
+    do): still every pair, whatever the imbalance. With `at` (a step of
+    `hybrid_layers`' scan), w's matrices hold several layers' experts,
+    layer-major ([layers * held, ...]), this layer's are those from `at *
+    held` on, and the pairs are ordered by counting (`_held_counted`: the
+    form that knows of other layers' groups, and the one a scan's step, made
+    in both directions, compiles quickly).
     -> (y f32[T, D], load int32[held]: pairs a held expert computed)."""
     with jax.named_scope(obs_scopes.MOE_EXPERTS):
         t, k = idx.shape
@@ -824,6 +1105,9 @@ def held_experts(arch: LMArch, w, x, idx, weights):
         local = idx - arch.held_start
         here = (local >= 0) & (local < held)
         key = jnp.where(here, local, held).reshape(t * k)
+        if at is not None:
+            return _held_counted(arch, w, x, key, jnp.where(here, weights, 0.0),
+                                 at)
         order = jnp.argsort(key, stable=True)
         load = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
         blocks, rows = pair_blocks(arch, t * k)
@@ -1073,12 +1357,131 @@ def _held_whole_bwd(res, dy):
 _held_whole.defvjp(_held_whole_fwd, _held_whole_bwd)
 
 
-def expert_layer(arch: LMArch, w, router, x):
-    """x: [B, S, D] (already normed) -> (y, load, the selections [T, k])."""
+COUNT_BLOCK = 512   # pairs a block of `_counted_order`'s running counts
+
+
+def _counted_order(key, buckets: int):
+    """The stable order of `key` int32[P] (values in [0, buckets)) without a
+    sort -> (pos int32[P]: the place pair p sorts to, order int32[P]: its
+    inverse, what `argsort(key, stable=True)` gives, counts int32[buckets]).
+    A pair's place is the pairs of smaller keys plus the pairs of its own
+    key before it: the running count a key, inside a block of `COUNT_BLOCK`
+    pairs as one product with a triangle of ones (0 / 1 operands, float32
+    sums: exact), across blocks as a running sum of the blocks' counts."""
+    pairs = key.shape[0]
+    blk = next(c for c in range(min(pairs, COUNT_BLOCK), 0, -1) if pairs % c == 0)
+    hot = (key[:, None] == jnp.arange(buckets)).astype(BF16).reshape(
+        pairs // blk, blk, buckets)
+    inside = jnp.einsum("ij,bjc->bic", jnp.tril(jnp.ones((blk, blk), BF16)), hot,
+                        preferred_element_type=F32)            # inclusive
+    per_block = inside[:, -1]
+    ran = (inside + (jnp.cumsum(per_block, 0) - per_block)[:, None]).reshape(
+        pairs, buckets).astype(jnp.int32)
+    counts = ran[-1]
+    pos = ((jnp.cumsum(counts) - counts)[key]
+           + jnp.take_along_axis(ran, key[:, None], 1)[:, 0] - 1)
+    order = jnp.zeros(pairs, jnp.int32).at[pos].set(
+        jnp.arange(pairs, dtype=jnp.int32), unique_indices=True)
+    return pos, order, counts
+
+
+def _held_counted(arch: LMArch, w, x, key, pair_w, at):
+    """`held_experts` with no sort in it (a step of `hybrid_layers`' scan;
+    the chip's compiler takes 15 s over a sort of 65,536 keys wherever one
+    stands, and `_held_front` has four): the pairs'
+    order by counting (`_counted_order`), the first `pair_front` sorted
+    pairs in one grouped product of that many rows, every row computed and
+    the un-sort a gather a (token, slot) (`_held_rows`), what is behind them
+    in `_held_blocks`. key int32[T * k]: a pair's held expert, or `held` for
+    an absent one; pair_w [T, k]: 0 for a pair of an absent expert. This
+    layer's experts are w's groups from `at * held` on: the groups of the
+    other layers are given no row, and no matrix is sliced out of w."""
+    t, k = pair_w.shape
+    held, pairs = arch.held_experts, t * k
+    pos, order, counts = _counted_order(key, held + 1)
+    load = counts[:held]
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros(w["down"].shape[0], jnp.int32), load, (at * held,))
+    xf, pair_w = x.astype(F32), pair_w.reshape(pairs)
+    front = min(arch.pair_front or pairs, pairs)
+    rows = min(arch.pair_block, pairs)
+    front = front if front == pairs else front // rows * rows
+    y = _held_rows(w, xf, order, pos, groups, pair_w, k, front)
+    if front < pairs:
+        blocks = -(-pairs // rows)
+        y = y + _held_blocks(w, xf, jnp.pad(order, (0, blocks * rows - pairs)),
+                             groups, pair_w, k, rows, front // rows)
+    return y, load
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _held_rows(w, x, order, pos, load, pair_w, k: int, front: int):
+    """`_held_front` without its sorts: the first `front` sorted pairs
+    (`order`; `pos` its inverse) in one grouped product a matrix, every row
+    computed (the rows behind the last held pair, pairs of absent experts
+    with weight 0, are given to the last group: a fixed capacity that costs
+    the same whatever the routers do), and the un-sort as one gather a
+    (token, slot) of the row the pair sorted to (`_sum_held`). Its gradient,
+    with respect to x and the pairs' weights, makes the rows again.
+    -> y f32[T, D]."""
+    return _held_rows_fwd(w, x, order, pos, load, pair_w, k, front)[0]
+
+
+def _held_rows_fwd(w, x, order, pos, load, pair_w, k, front):
+    mine = order[:front]
+    ends = jnp.cumsum(load)
+    sizes = (jnp.clip(ends, 0, front)
+             - jnp.clip(ends - load, 0, front)).astype(jnp.int32)
+    n = jnp.sum(sizes)
+    tokens, ws, live = mine // k, pair_w[mine], jnp.arange(front) < n
+    sizes = sizes.at[-1].add(front - n)
+    at = jnp.where(pos < n, pos, front).reshape(-1, k)   # held sort first
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        gu = _gmm_call(x.astype(BF16)[tokens], w["gate_up"], sizes, False,
+                       FRONT_ROWS)
+    f = gu.shape[-1] // 2
+    hid = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(BF16)
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        ys = _gmm_call(hid, w["down"], sizes, False, FRONT_ROWS)
+    ys = jnp.where(live[:, None], ys * ws[:, None], 0.0)
+    return _sum_held(ys, at), (w, x, tokens, ws, sizes, live, at)
+
+
+def _held_rows_bwd(k, front, res, dy):
+    w, x, tokens, ws, sizes, live, at = res
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        gu = _gmm_call(x.astype(BF16)[tokens], w["gate_up"], sizes, False,
+                       FRONT_ROWS)
+        # dy @ down^T a row; the pair's weight comes in behind it
+        dh = _gmm_call(dy.astype(BF16)[tokens], w["down"], sizes, True,
+                       FRONT_ROWS)
+    f = gu.shape[-1] // 2
+    g, u = gu[:, :f], gu[:, f:]
+    s = jax.nn.sigmoid(g)
+    act = g * s
+    dws = jnp.where(live, jnp.sum((act * u).astype(BF16).astype(F32) * dh, -1),
+                    0.0)
+    dh = dh * ws[:, None]
+    dgu = jnp.concatenate([dh * u * (s + act * (1.0 - s)), dh * act],
+                          -1).astype(BF16)
+    with jax.named_scope(obs_scopes.MOE_GMM):
+        dxs = _gmm_call(dgu, w["gate_up"], sizes, True, FRONT_ROWS)
+    dx = _sum_held(jnp.where(live[:, None], dxs, 0.0), at)
+    among = at < front
+    return (None, dx, None, None, None,
+            jnp.where(among, dws[jnp.where(among, at, 0)], 0.0).reshape(-1))
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
+def expert_layer(arch: LMArch, w, router, x, at=None):
+    """x: [B, S, D] (already normed) -> (y, load, the selections [T, k]).
+    `at`: `held_experts`'s (the other leaves of w are this layer's own)."""
     b, s, d = x.shape
     flat = x.reshape(b * s, d)
     idx, weights = route(arch, router, w["bias"], flat)
-    y, load = held_experts(arch, w["experts"], flat, idx, weights)
+    y, load = held_experts(arch, w["experts"], flat, idx, weights, at)
     if arch.shared_experts:
         y = y + glu(w["shared"], flat)
     return y.reshape(b, s, d), load, idx
@@ -1115,6 +1518,8 @@ def _leaf_shapes(arch: LMArch, vocab: int):
     gains = {"ln_attn": (d,), "ln_mlp": (d,)}
     if arch.kv_heads:
         return _grouped_leaf_shapes(arch, vocab, gains)
+    if arch.kda_head_dim:
+        return _hybrid_leaf_shapes(arch, vocab, gains)
     attn = {"q_a": (d, arch.q_lora_rank),
             "q_b": (arch.q_lora_rank, h * (dn + dr)),
             "kv_a": (d, arch.kv_lora_rank + dr),
@@ -1169,7 +1574,109 @@ def _grouped_leaf_shapes(arch: LMArch, vocab: int, gains):
             {"blocks": blocks_g, "final_norm": (d,)})
 
 
+def _hybrid_leaf_shapes(arch: LMArch, vocab: int, gains):
+    """`_leaf_shapes` of a model with linear layers. The trained subset is a
+    list a layer as the others' (a linear layer trains the decay's `A_log`
+    and `dt_bias` and the output norm's gain, a latent one `kv_norm`; there
+    is no prediction module). The base is **stacked by kind**, because the
+    expert layers run as steps of one `lax.scan` (`hybrid_layers`): `linear`
+    (`kda_layer`'s leaves) and `latent` (`_attend`'s with no query low-rank
+    and a gate a head) with a leading axis over the layers of that kind,
+    `mlp` over the dense layers, `shared` and `bias` over the expert layers,
+    and `experts` with every expert layer's held experts along one axis,
+    layer-major ([expert layers * held, ...]: the grouped product finds a
+    layer's through its group sizes and nothing is sliced)."""
+    d, h, dk = arch.hidden, arch.heads, arch.kda_head_dim
+    dn, dr, dv = arch.qk_nope_head_dim, arch.qk_rope_head_dim, arch.v_head_dim
+    f, e = arch.moe_intermediate, arch.held_experts
+    n_lin = sum(arch.layer_pattern)
+    n_lat, n_exp = len(arch.layer_pattern) - n_lin, arch.expert_layers
+    linear = {"in": (d, 5 * h * dk), "beta": (d, h),
+              "conv": (3 * h * dk, arch.kda_conv), "o": (h * dk, d)}
+    latent = {"q": (d, h * (dn + dr)), "kv_a": (d, arch.kv_lora_rank + dr),
+              "kv_b": (arch.kv_lora_rank, h * (dn + dv)), "o": (h * dv, d),
+              "gate": (d, h)}
+    over = lambda n, tree: {k: (n, *v) for k, v in tree.items()}  # noqa: E731
+    base = {"embed": (vocab, d), "head": (d, vocab),
+            "linear": over(n_lin, linear), "latent": over(n_lat, latent),
+            "mlp": over(arch.dense_layers, {
+                "gate_up": (d, 2 * arch.intermediate),
+                "down": (arch.intermediate, d)}),
+            "experts": {"gate_up": (n_exp * e, d, 2 * f),
+                        "down": (n_exp * e, f, d)},
+            "shared": over(n_exp, {"gate_up": (d, 2 * f), "down": (f, d)}),
+            "bias": (n_exp, arch.n_experts)}
+    blocks_g = []
+    for layer, kind in enumerate(arch.layer_pattern):
+        g = dict(gains, A_log=(h,), dt_bias=(h * dk,), o_norm=(dk,)) if (
+            kind == LINEAR) else dict(gains, kv_norm=(arch.kv_lora_rank,))
+        if layer >= arch.dense_layers:
+            g["router"] = (arch.n_experts, d)
+        blocks_g.append(g)
+    return base, {"blocks": blocks_g, "final_norm": (d,)}
+
+
+def hybrid_layers(arch: LMArch, base, blocks_g, h):
+    """The residual stream h [B, S, D] through every layer of a model with
+    linear layers -> (h, load int32[expert layers, held], selections
+    int32[expert layers, T, k]). The leading dense layers one after another;
+    the expert layers as one `lax.scan`, so that an expert layer and each
+    kind of attention are compiled once in each direction whatever the depth
+    (the cold run's budget): a step picks its attention (`kda_layer` or the
+    gated `_attend`) by `lax.cond` and its frozen matrices out of the base's
+    stacks by its place among its kind. The expert layer stands in the
+    step itself, under no `lax.cond`: a `custom_vjp` gives each of its
+    arguments a tangent, zeros of its own size where it has none, and a
+    branch would have to write out 7 GB of them for the held experts. A
+    layer is made again for the gradient; nothing of it is kept (`_kept`)."""
+    kinds, first = arch.layer_pattern, arch.dense_layers
+    linear = [kind == LINEAR for kind in kinds]
+    place = [sum(f == ok for f in linear[:i]) for i, ok in enumerate(linear)]
+    stack = lambda name, layers: jnp.stack(  # noqa: E731
+        [blocks_g[i][name] for i in layers])
+    g_linear = {n: stack(n, [i for i, ok in enumerate(linear) if ok])
+                for n in ("A_log", "dt_bias", "o_norm")}
+    kv_norm = stack("kv_norm", [i for i, ok in enumerate(linear) if not ok])
+    at = lambda tree, i: jax.tree_util.tree_map(lambda t: t[i], tree)  # noqa: E731
+
+    def attention(is_linear, ia, x):  # Python values, or a scan step's
+        run = (lambda: kda_layer(arch, at(base["linear"], ia), at(g_linear, ia), x),
+               lambda: _attend(arch, at(base["latent"], ia),
+                               {"kv_norm": kv_norm[ia]}, x)[0])
+        if isinstance(is_linear, bool):
+            return run[0]() if is_linear else run[1]()
+        return jax.lax.cond(is_linear, *run)
+
+    @functools.partial(jax.checkpoint, policy=_kept(arch), static_argnums=(0,))
+    def dense(i: int, ln_attn, ln_mlp, h):
+        h = h + attention(linear[i], place[i], rms_norm(h, ln_attn, arch.eps))
+        return h + glu_by_parts(at(base["mlp"], i),
+                                rms_norm(h, ln_mlp, arch.eps))
+
+    @functools.partial(jax.checkpoint, policy=_kept(arch))
+    def routed(h, step):
+        is_linear, ia, im, ln_attn, ln_mlp, router = step
+        h = h + attention(is_linear, ia, rms_norm(h, ln_attn, arch.eps))
+        w = {"experts": base["experts"], "bias": base["bias"][im],
+             "shared": at(base["shared"], im)}
+        y, load, idx = expert_layer(arch, w, router,
+                                    rms_norm(h, ln_mlp, arch.eps), at=im)
+        return h + y, (load, idx)
+
+    for i in range(first):
+        h = dense(i, blocks_g[i]["ln_attn"], blocks_g[i]["ln_mlp"], h)
+    rest = range(first, len(kinds))
+    h, seen = jax.lax.scan(routed, h, (
+        jnp.asarray(linear[first:]), jnp.asarray(place[first:], jnp.int32),
+        jnp.arange(len(rest)), stack("ln_attn", rest), stack("ln_mlp", rest),
+        stack("router", rest)))
+    return (h, *seen)
+
+
 _is_shape = lambda t: isinstance(t, tuple)  # noqa: E731
+_names = lambda path, name: jax.tree_util.keystr(path).endswith(  # noqa: E731
+    f"['{name}']")
+STARTS_AT_0 = ("sink", "A_log", "dt_bias")   # trained leaves that start at 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1188,32 +1695,62 @@ class FrozenBaseLM:
     # ---- parameters --------------------------------------------------------
 
     def init_trained(self, key=None):
-        """The trained subset at its start: gains 1, sinks 0, routers
-        normal(std)."""
+        """The trained subset at its start: gains 1, sinks and the decay's
+        `A_log` and `dt_bias` 0, routers normal(std)."""
         key = jax.random.key(self.seed) if key is None else key
         shapes = _leaf_shapes(self.arch, self.num_classes)[1]
         leaves, tree = jax.tree_util.tree_flatten_with_path(
             shapes, is_leaf=_is_shape)
         out = [
-            jnp.zeros(s, F32) if "sink" in jax.tree_util.keystr(path)
+            jnp.zeros(s, F32) if any(
+                name in jax.tree_util.keystr(path) for name in STARTS_AT_0)
             else jnp.ones(s, F32) if len(s) == 1 else self.arch.init_std
             * jax.random.normal(jax.random.fold_in(key, i), s, F32)
             for i, (path, s) in enumerate(leaves)]
         return jax.tree_util.tree_unflatten(tree, out)
 
-    def init_base(self, key=None):
-        """The frozen base, made on the default device leaf by leaf in
-        bfloat16 (the router's bias buffer in float32): normal(std)."""
-        key = jax.random.key(self.seed) if key is None else key
-        shapes = _leaf_shapes(self.arch, self.num_classes)[0]
+    def _base_leaves(self):
+        """-> ([(shape, dtype: None for a gain that starts at 1)], the tree)
+        of the base's leaves."""
         leaves, tree = jax.tree_util.tree_flatten_with_path(
-            shapes, is_leaf=_is_shape)
-        out = [jnp.ones(s, F32) if "k_gain" in jax.tree_util.keystr(path)
-               else _normal_leaf(jax.random.fold_in(key, 1000 + i),
-                                 self.arch.init_std, shape=s,
-                                 dtype="float32" if len(s) == 1 else "bfloat16")
-               for i, (path, s) in enumerate(leaves)]   # (LayerNorm's gain: 1)
-        return jax.tree_util.tree_unflatten(tree, out)
+            _leaf_shapes(self.arch, self.num_classes)[0], is_leaf=_is_shape)
+        return [(s, None if "k_gain" in jax.tree_util.keystr(path)  # LayerNorm's
+                 else "float32" if len(s) == 1 or _names(path, "bias")
+                 else "bfloat16") for path, s in leaves], tree
+
+    def base_generators(self) -> dict:
+        """{(shape, dtype): `_normal_leaf` compiled for it}: the distinct
+        generators of `init_base`, lowered and compiled side by side on the
+        host's cores (one after another the hybrid base's 18 take the chip's
+        compiler 24 s of a cold run, an older base's stacks 9 to 17 s each).
+        For `init_base`'s `made`."""
+        import concurrent.futures
+
+        distinct = list(dict.fromkeys(
+            leaf for leaf in self._base_leaves()[0] if leaf[1]))
+        with concurrent.futures.ThreadPoolExecutor(8) as pool:
+            return dict(zip(distinct, pool.map(
+                lambda leaf: _leaf_generator(
+                    *leaf, stacked=bool(self.arch.kda_head_dim)), distinct)))
+
+    def init_base(self, key=None, made=None):
+        """The frozen base, made on the default device leaf by leaf in
+        bfloat16 (the router's bias buffer in float32, stacked or not):
+        normal(std). `made`: `base_generators`'s programs, called in place
+        of `_normal_leaf` (the same programs, compiled ahead)."""
+        key = jax.random.key(self.seed) if key is None else key
+        leaves, tree = self._base_leaves()
+
+        def normal(i, s, dtype):
+            args = (jax.random.fold_in(key, 1000 + i), self.arch.init_std)
+            if made is not None:
+                return made[s, dtype](*args)
+            return _normal_leaf(*args, shape=s, dtype=dtype,
+                                stacked=bool(self.arch.kda_head_dim))
+
+        return jax.tree_util.tree_unflatten(tree, [
+            normal(i, s, dtype) if dtype else jnp.ones(s, F32)
+            for i, (s, dtype) in enumerate(leaves)])
 
     def bind(self, base):
         return BoundLM(self, base)
@@ -1234,6 +1771,16 @@ class FrozenBaseLM:
         arch, p, base = self.arch, variables["params"], variables["base"]
         s = tokens.shape[1] - 2
         emb = lambda t: base["embed"][t].astype(F32)  # noqa: E731
+        if arch.kda_head_dim:
+            h, loads, idx = hybrid_layers(arch, base, p["blocks"],
+                                          emb(tokens[:, :s]))
+            linear = sum(arch.layer_pattern)
+            for name, value in (("fused", len(p["blocks"]) - linear),
+                                ("sparse", 0), ("window", 0), ("linear", linear),
+                                ("gated", len(p["blocks"]) - linear)):
+                obs_metrics.gauge(f"model.{name}_attention_layers").set(value)
+            return (rms_norm(h, p["final_norm"], arch.eps) if normed else h,
+                    None, (loads, idx), None)
         kinds = arch.layer_pattern or (None,) * len(base["blocks"])
         blk = {kind: jax.checkpoint(
             lambda w, g, h, kind=kind: block(arch, w, g, h, kind),
@@ -1267,6 +1814,8 @@ class FrozenBaseLM:
             len(picked) if arch.index_topk else 0)
         obs_metrics.gauge("model.window_attention_layers").set(
             sum(arch.layer_pattern))
+        obs_metrics.gauge("model.linear_attention_layers").set(0)
+        obs_metrics.gauge("model.gated_attention_layers").set(0)
         return (h_main, h_mtp, (jnp.stack([r[0] for r in routed]),
                                 jnp.stack([r[1] for r in routed])),
                 jnp.stack(picked) if arch.index_topk else None)
@@ -1288,8 +1837,8 @@ class FrozenBaseLM:
         for a model without a prediction module. The logits are
         made a slice of `loss_chunk` tokens at a time and made again for the
         gradient: no [tokens, vocab] array outlives its slice. A model with
-        an indexer or with grouped attention appends four columns to `loads`
-        (`COUNTED`)."""
+        an indexer, grouped attention or linear layers appends four columns
+        to `loads` (`COUNTED`)."""
         arch, p = self.arch, variables["params"]
         s = tokens.shape[1] - 2
         # a float32 [tokens, hidden] array over `STREAM_BYTES` is not kept
@@ -1298,7 +1847,7 @@ class FrozenBaseLM:
         lean = tokens.shape[0] * s * arch.hidden * 4 > STREAM_BYTES
         h_main, h_mtp, (loads, _), picked = self.hidden(variables, tokens,
                                                         normed=not lean)
-        if picked is not None or arch.kv_heads:
+        if picked is not None or arch.kv_heads or arch.kda_head_dim:
             loads = _with_counts(arch, loads, picked, *tokens.shape)
         head = variables["base"]["head"]
         if lean:
@@ -1336,8 +1885,9 @@ def _with_counts(arch: LMArch, loads, picked, sequences: int, length: int):
     front = front_pairs(arch, pairs) // rows      # blocks `_held_front` takes
     held = jnp.sum(loads, -1)
     given = rows * jnp.clip(-(-held // rows), 0 if blocks > 1 else 1, blocks)
-    if front:
-        given = (jnp.maximum(given, front * rows) + FRONT_ROWS).astype(given.dtype)
+    if front:   # (`_held_front` gives one tile more, `_held_rows` none)
+        given = (jnp.maximum(given, front * rows) + (
+            0 if arch.kda_head_dim else FRONT_ROWS)).astype(given.dtype)
     if picked is None:
         mine = causal = jnp.zeros((n,), jnp.int32)
     else:
@@ -1397,10 +1947,26 @@ def _head_ce(h, head, targets, chunk: int):
         return jnp.sum(ces) / n, jnp.sum(hits) / n
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
-def _normal_leaf(key, std, shape, dtype):
-    # compiled once a shape; made in float32 and narrowed on the device
-    return (std * jax.random.normal(key, shape, F32)).astype(dtype)
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "stacked"))
+def _normal_leaf(key, std, shape, dtype, stacked=False):
+    # compiled once a shape; made in float32 and narrowed on the device. A
+    # `stacked` leaf (`_hybrid_leaf_shapes`: matrices along leading axes) is
+    # made a matrix at a time: no float32 form of the whole (10 GB of the
+    # held experts), and the chip's compiler takes 1 s over the generator of
+    # a matrix where it takes 9 to 17 over that of a stack
+    if not stacked or len(shape) < 3:
+        return (std * jax.random.normal(key, shape, F32)).astype(dtype)
+    return jax.lax.map(
+        lambda k: (std * jax.random.normal(k, shape[-2:], F32)).astype(dtype),
+        jax.random.split(key, math.prod(shape[:-2]))).reshape(shape)
+
+
+@functools.lru_cache(maxsize=None)
+def _leaf_generator(shape, dtype, stacked):
+    """`_normal_leaf` compiled for a shape, once a process (its key and the
+    deviation are arguments)."""
+    return _normal_leaf.lower(jax.eval_shape(jax.random.key, 0), 0.02,
+                              shape=shape, dtype=dtype, stacked=stacked).compile()
 
 
 # --------------------------------------------------------------------------
@@ -1420,7 +1986,8 @@ def frozen_base(module):
         return None
     if module not in _BASES:
         _BASES.clear()
-        _BASES[module] = jax.block_until_ready(module.init_base())
+        _BASES[module] = jax.block_until_ready(
+            module.init_base(made=module.base_generators()))
     return _BASES[module]
 
 

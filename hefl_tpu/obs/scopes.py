@@ -51,6 +51,8 @@ DSA_INDEX = "hefl.dsa.index"          # the indexer's scores and its selection
 DSA_ATTEND = "hefl.dsa.attend"        # attention over the selected keys alone
 GQA = "hefl.gqa"                      # grouped-query attention: projections, RoPE, softmax
 SWA_ATTEND = "hefl.swa.attend"        # a window layer's fused attention calls
+KDA = "hefl.kda"                      # a linear-attention layer whole: projections to output gate
+KDA_SCAN = "hefl.kda.scan"            # its chunked delta-rule recurrence alone
 MTP = "hefl.mtp"                      # the multi-token-prediction module
 LM_HEAD = "hefl.lm_head"              # head logits + cross-entropy, by slices
 CONV = "hefl.conv"                    # a convolution (medcnn: with bias, ReLU and pool)
@@ -92,6 +94,8 @@ PHASES = (
     DSA_ATTEND,
     GQA,
     SWA_ATTEND,
+    KDA,
+    KDA_SCAN,
     MTP,
     LM_HEAD,
     CONV,

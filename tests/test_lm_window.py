@@ -649,14 +649,18 @@ def _program_digest(closed) -> str:
 @pytest.mark.parametrize("name,positions,digest", [
     ("joyai_llm_flash_tiny", 24, "cf307f770ca7df44"),
     ("deepseek_v32_tiny", 40, "9181a7b43929e507"),
+    ("mimo_v2_flash_tiny", 64, "8b45fb77abb684e0"),
+    ("ling_3_flash_tiny", 40, "d7e896098760a5ce"),
 ])
 def test_the_older_models_traced_programs_are_unchanged(name, positions, digest):
     """The loss and its gradient of the two DeepSeek-V3-shaped presets,
     traced: deepseek's digest is that of commit c298e53 (PR 32), before a
     third model came out of the same class; joyai's is PR 40's, whose
     one-block expert layer (`_held_whole`) is the one change to its program
-    since (3a6633ee86b7eed0 before). A change of the installed JAX moves
-    them too: then read them again from those commits."""
+    since (3a6633ee86b7eed0 before). mimo's is that of commit 0cbd328 (PR
+    40), read there before a fourth model came out of the class (PR 41), and
+    ling's is PR 41's own. A change of the installed JAX moves them too:
+    then read them again from those commits."""
     module = lm.FrozenBaseLM(num_classes=50, arch=lm.PRESETS[name], seed=3)
     p, base = jax.eval_shape(module.init_trained), jax.eval_shape(module.init_base)
     tokens = jax.ShapeDtypeStruct((2, positions + 2), jnp.int32)
@@ -664,4 +668,5 @@ def test_the_older_models_traced_programs_are_unchanged(name, positions, digest)
         lambda p, base, t: module.loss({"params": p, "base": base}, t),
         has_aux=True)
     assert _program_digest(jax.make_jaxpr(vg)(p, base, tokens)) == digest
-    assert "mtp" in base and "shared" in base["blocks"][1]
+    if lm.PRESETS[name].mtp_modules:
+        assert "mtp" in base and "shared" in base["blocks"][1]

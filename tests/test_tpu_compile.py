@@ -203,6 +203,49 @@ def test_fused_attention_gradient_compiles_for_described_v5e(
     assert compiled.cost_analysis()["bytes accessed"] < 4e9
 
 
+def test_linear_recurrence_and_sort_free_experts_compile_for_described_v5e(
+        one_chip, monkeypatch):
+    """`jax.grad` of `models/lm.kda_recurrence` at Ling-3.0-flash's widths
+    (32 heads of 128 x 128 over 8,192 positions, chunks of 64 solved in
+    sub-blocks of 16): one scan over the chunks in each direction, no kernel
+    of this repo's in it, and what is made ahead of the scan stays under
+    1.5 GB of temporaries (a chunk group at a time). And the held experts of
+    the same preset (`_held_counted`, five layers' experts along one axis,
+    the layer at 2): grouped products through Mosaic and **no sort** in
+    either direction (a sort of 65,536 keys takes the chip's compiler 15 s
+    wherever one stands)."""
+    from hefl_tpu.models import lm
+
+    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    arch = lm.PRESETS["ling_3_flash"]
+    shape = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    q = shape(1, 8192, arch.heads, arch.kda_head_dim)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        scan = jax.jit(jax.grad(
+            lambda q, k, v, g, b: jnp.sum(lm.kda_recurrence(q, k, v, g, b) ** 2),
+            (0, 1, 2, 3, 4))).lower(q, q, q, q, shape(1, 8192, arch.heads)).compile()
+        t, k, d, f = 8192, arch.experts_per_tok, arch.hidden, arch.moe_intermediate
+        n = arch.expert_layers * arch.held_experts
+        w = {"gate_up": shape(n, d, 2 * f, dtype=jnp.bfloat16),
+             "down": shape(n, f, d, dtype=jnp.bfloat16)}
+        experts = jax.jit(jax.grad(
+            lambda w, x, key, pw: jnp.sum(
+                lm._held_counted(arch, w, x, key, pw, at=2)[0] ** 2),
+            (1, 3))).lower(w, shape(t, d), shape(t * k, dtype=jnp.int32),
+                           shape(t, k)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    text = scan.as_text()
+    assert "while(" in text and "tpu_custom_call" not in text
+    assert scan.memory_analysis().temp_size_in_bytes < 1.5e9
+    text = experts.as_text()
+    assert "gmm" in text and " sort(" not in text and "sort." not in text
+    assert experts.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 @pytest.mark.parametrize("kind", [0, 1], ids=["global", "window"])
 def test_grouped_attention_gradient_compiles_for_described_v5e(
         kind, one_chip, monkeypatch):
