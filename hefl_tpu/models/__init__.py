@@ -93,6 +93,8 @@ def create_model(
     obs_metrics.gauge("model.window_attention_layers").set(0)
     obs_metrics.gauge("model.linear_attention_layers").set(0)
     obs_metrics.gauge("model.gated_attention_layers").set(0)
+    obs_metrics.gauge("dsa.kept_selection_layers").set(0)
+    obs_metrics.gauge("dsa.kept_attention_layers").set(0)
     dummy = jnp.zeros(
         (1, *(input_shape if input_shape is not None else default_shape)), jnp.float32
     )

@@ -118,6 +118,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from hefl_tpu.obs import metrics as obs_metrics
 from hefl_tpu.obs import scopes as obs_scopes
@@ -515,12 +516,36 @@ def grouped_attention(arch: LMArch, kind: int, w, g, x):
         return _mm(o.reshape(b, s, h * dv), w["o"])
 
 
+DSA_PICKED = "dsa_picked"   # an indexer's selection, packed (`pack_selection`)
+
+
+def _kept_names(arch: LMArch) -> tuple:
+    """The checkpoint names a layer's `jax.checkpoint` keeps for the gradient
+    besides the layer's input (`_kept`), from what the architecture has."""
+    if arch.kv_heads or arch.kda_head_dim:
+        return ()
+    return (DSA_PICKED, ATTN_SAVED) if arch.index_topk else (ATTN_SAVED,)
+
+
 def _kept(arch: LMArch):
     """What a layer's checkpoint keeps for the gradient besides the layer's
     input: attention's output and log-sum-exp (`ATTN_SAVED`), so the forward
-    kernel does not run again; nothing where an indexer picks the keys (128
-    heads of 8,192 positions a layer: 0.27 GB that the step has no room
-    for) and nothing where attention is grouped (64 heads of 8,192
+    kernel does not run again. Where an indexer picks the keys, its
+    selection too (`DSA_PICKED`), packed: `select_keys` has no gradient and
+    its inputs are detached, so the gradient's copy of the layer unpacks the
+    kept bits and runs neither the indexer's scores nor the 32 counting
+    passes. At 8,192 positions and 128 heads that is, a layer, 8.4 MB of
+    selection (uint32[1, 256, 8192]) and 0.272 GB of attention (the kernel's
+    output bf16[128, 8192, 128] and log-sum-exp f32[128, 8192], as the
+    groups' calls write them and as the gradient kernel reads them: no other
+    form of either is kept), 1.68 GB over the six layers. Sized against the
+    15.75 GiB = 16.91e9 bytes a v5e lets a program use, by the described
+    compile of `loss`'s gradient at the published widths (PERF.md, PR 42):
+    temporaries 5.30e9 bytes with nothing kept, 5.83e9 with the selection,
+    8.51e9 with both by `memory_analysis()`, which holds a kept array twice
+    where a `lax.map` stacked it; the round program 14.9e9 in all by the
+    compiler's own total (13.5e9 with nothing kept), and it runs.
+    Nothing where attention is grouped (64 heads of 8,192
     positions in each of seven layers held 1.7 GB of the step's temporaries
     by the compiler's count, and put the round over 14 GB). Nothing either
     where a model has linear layers: its layers are steps of one scan, and a
@@ -529,9 +554,10 @@ def _kept(arch: LMArch):
     gradient is made, by the scan over its chunks itself (128 x [32, 128,
     128] float32, 0.27 GB, for the one layer), and nothing from layer to
     layer: with the base at 8.6 GB the step has 6.4 GB."""
-    if arch.index_topk or arch.kv_heads or arch.kda_head_dim:
+    names = _kept_names(arch)
+    if not names:
         return jax.checkpoint_policies.nothing_saveable
-    return jax.checkpoint_policies.save_only_these_names(ATTN_SAVED)
+    return jax.checkpoint_policies.save_only_these_names(*names)
 
 
 HEADS_A_CALL = 16   # the fused gradient keeps a float32 dq a key block a head
@@ -549,7 +575,14 @@ def selected_attention(heads_of, xs, picked, q_block: int):
     query of which picks any key is skipped (above the diagonal, all of
     them). No score block reaches HBM, and no array of all heads' queries,
     keys or values exists: a group's are made from `xs` when it runs and
-    made again for its gradient. Query blocks are half the key blocks (a
+    made again for its gradient. What the gradient is given instead of a
+    second forward kernel is each group call's output bf16[heads, 1, S, dv]
+    and log-sum-exp f32[heads, 1, S], named `ATTN_SAVED` by the kernel and
+    stacked over the groups by the `lax.map` as the calls write them
+    (0.268 GB + 4 MB a layer at 128 heads of 8,192 positions): kept through
+    the group's own checkpoint here and, where the layer's says so
+    (`_kept`), from the layer's forward to its gradient, which then runs
+    the `dkv` kernel alone. Query blocks are half the key blocks (a
     mask block lies in the chip's fast memory as 32-bit words); a padded
     query picks key 0, a padded key is picked by none. No gradient reaches
     `picked`."""
@@ -588,6 +621,33 @@ def selected_attention(heads_of, xs, picked, q_block: int):
     o = jax.lax.map(group, xs)                      # [groups, B, S, heads, dv]
     g, _, _, n, dv = o.shape
     return o.transpose(1, 2, 0, 3, 4).reshape(b, s, g * n, dv)
+
+
+def pack_selection(picked):
+    """bool[..., Q, K] -> uint32[..., W, K], W = ceil(Q / 32): bit j of word
+    (w, k) is query j * W + w's pick of key k (a query behind Q reads 0).
+    The words run along the queries, whole rows of keys at a time: both
+    directions are shifts of [W, K] planes that lie one behind the other in
+    memory, and the keys stay in the lanes as they were (packed along the
+    keys, 32 to a lane, the unpack fused into what reads the selection and
+    cost 21 ms a layer pass on the chip: PERF.md, PR 42)."""
+    q, k = picked.shape[-2:]
+    w = -(-q // 32)
+    planes = jnp.pad(picked, ((0, 0),) * (picked.ndim - 2)
+                     + ((0, 32 * w - q), (0, 0))).reshape(
+                         *picked.shape[:-2], 32, w, k).astype(jnp.uint32)
+    return jnp.sum(planes << jnp.arange(32, dtype=jnp.uint32)[:, None, None],
+                   -3, dtype=jnp.uint32)
+
+
+def unpack_selection(bits, q: int):
+    """`pack_selection`'s inverse: uint32[..., W, K] -> bool[..., q, K], as
+    an array of its own (what reads a selection reads it as `select_keys`
+    wrote it, and does not make it again from the words)."""
+    planes = (bits[..., None, :, :]
+              >> jnp.arange(32, dtype=jnp.uint32)[:, None, None]) & 1
+    return jax.lax.optimization_barrier(planes.reshape(
+        *bits.shape[:-2], -1, bits.shape[-1])[..., :q, :].astype(bool))
 
 
 def kth_largest_mask(scores, k: int):
@@ -720,6 +780,9 @@ def _attend_selected(arch: LMArch, w, g, x, c_q):
     h, dn, dr, dv = (arch.heads, arch.qk_nope_head_dim,
                      arch.qk_rope_head_dim, arch.v_head_dim)
     picked = select_keys(arch, w["index"], x, c_q)
+    with jax.named_scope(obs_scopes.DSA_INDEX):   # kept packed (`_kept`)
+        picked = unpack_selection(
+            checkpoint_name(pack_selection(picked), DSA_PICKED), s)
     kv_a = _mm(x, w["kv_a"])
     c_kv = rms_norm(kv_a[..., :arch.kv_lora_rank], g["kv_norm"], arch.eps)
     k_r = rope(kv_a[..., arch.kv_lora_rank:][:, :, None, :], arch.rope_theta,
@@ -1779,6 +1842,8 @@ class FrozenBaseLM:
                                 ("sparse", 0), ("window", 0), ("linear", linear),
                                 ("gated", len(p["blocks"]) - linear)):
                 obs_metrics.gauge(f"model.{name}_attention_layers").set(value)
+            for name in ("selection", "attention"):   # no indexer
+                obs_metrics.gauge(f"dsa.kept_{name}_layers").set(0)
             return (rms_norm(h, p["final_norm"], arch.eps) if normed else h,
                     None, (loads, idx), None)
         kinds = arch.layer_pattern or (None,) * len(base["blocks"])
@@ -1812,6 +1877,10 @@ class FrozenBaseLM:
             len(base["blocks"]) + arch.mtp_modules)
         obs_metrics.gauge("model.sparse_attention_layers").set(
             len(picked) if arch.index_topk else 0)
+        for name, kept in (("selection", DSA_PICKED), ("attention", ATTN_SAVED)):
+            obs_metrics.gauge(f"dsa.kept_{name}_layers").set(
+                len(picked) if arch.index_topk and kept in _kept_names(arch)
+                else 0)
         obs_metrics.gauge("model.window_attention_layers").set(
             sum(arch.layer_pattern))
         obs_metrics.gauge("model.linear_attention_layers").set(0)

@@ -214,8 +214,13 @@ def test_gauge_counts_the_attention_layers_that_took_the_kernel(case):
     # ... all of them over an indexer's selection, where the model has one
     assert obs_metrics.gauge("model.sparse_attention_layers").value == (
         4 if arch.index_topk else 0)
+    # ... whose selection and whose kernel's output a gradient would keep
+    for kept in ("selection", "attention"):
+        assert obs_metrics.gauge(f"dsa.kept_{kept}_layers").value == (
+            4 if arch.index_topk else 0)
     create_model("smallcnn", num_classes=2, input_shape=(16, 16, 3))
     assert obs_metrics.snapshot()["model.fused_attention_layers"] == 0
+    assert obs_metrics.snapshot()["dsa.kept_selection_layers"] == 0
 
 
 def test_expert_block_matches_reference(case):
@@ -604,6 +609,104 @@ def test_attention_over_a_selection_and_its_gradients_match_plain_attention():
     g_got = jax.grad(lambda *a: jnp.sum(ours(*a) * ct), (0, 1, 2))(q, k, v)
     for a, b in zip(g_got, g_want):
         assert float(jnp.max(jnp.abs(a - b))) < 0.02 * float(jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize("shape", [(2, 40, 40), (3, 77, 5), (1, 256, 4)])
+def test_a_packed_selection_unpacks_to_itself(shape):
+    """32 queries a word, a number of queries that is no multiple of 32
+    padded with queries that pick nothing."""
+    picked = jax.random.uniform(jax.random.key(sum(shape)), shape) < 0.4
+    bits = lm.pack_selection(picked)
+    words = -(-shape[-2] // 32)
+    assert bits.dtype == jnp.uint32 and bits.shape == (
+        shape[0], words, shape[-1])
+    assert np.array_equal(np.asarray(lm.unpack_selection(bits, shape[-2])),
+                          np.asarray(picked))
+    # a query behind the last reads 0, whatever the words hold
+    assert not np.asarray(lm.unpack_selection(
+        lm.pack_selection(jnp.ones(shape, bool)), 32 * words)
+    )[:, shape[-2]:].any()
+
+
+def _sparse_loss_and_gradient(seed: int):
+    """(module, variables, tokens) of the tiny model with an indexer, its
+    weights and tokens from `seed`, the gains moved off 1."""
+    module = lm.FrozenBaseLM(num_classes=VOCAB, arch=SPARSE, seed=seed)
+    p = jax.tree_util.tree_map(
+        lambda a: a * (1 + 0.1 * jax.random.normal(jax.random.key(a.size + seed),
+                                                   a.shape))
+        if a.ndim == 1 else a, module.init_trained())
+    tokens = jax.random.randint(jax.random.key(seed), (2, 42), 0, VOCAB)
+    return module, {"params": p, "base": module.init_base()}, tokens
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_what_the_checkpoint_keeps_moves_no_bit_of_loss_or_gradient(
+        seed, monkeypatch):
+    """The gradient that keeps the selection and the kernel's output and
+    log-sum-exp (`_kept`) against the one that keeps nothing and makes both
+    again from the layer's input (the form before PR 42): the loss and the
+    gradient by every trained leaf, each form compiled whole, equal to the
+    last bit (the kept arrays are the ones the second forward would make)."""
+    module, v, tokens = _sparse_loss_and_gradient(seed)
+    vg = lambda: jax.jit(jax.value_and_grad(lambda q: module.loss(  # noqa: E731
+        {"base": v["base"], "params": q}, tokens)[0]))(v["params"])
+    assert lm._kept_names(SPARSE) == (lm.DSA_PICKED, lm.ATTN_SAVED)
+    l_kept, g_kept = vg()
+    monkeypatch.setattr(lm, "_kept_names", lambda arch: ())
+    l_none, g_none = vg()
+    assert float(l_kept) == float(l_none)
+    flat = jax.tree_util.tree_leaves_with_path
+    for (path, a), (_, b) in zip(flat(g_kept), flat(g_none)):
+        assert float(jnp.max(jnp.abs(a))) > 0, path
+        assert np.array_equal(np.asarray(a), np.asarray(b)), path
+
+
+def _executions(jaxpr, wanted, times: int = 1) -> int:
+    """How often the equations `wanted(eqn)` picks run in `jaxpr`: a scan's
+    body counts once a step."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if wanted(eqn):
+            n += times
+            continue
+        inner = times * eqn.params["length"] if eqn.primitive.name == "scan" else times
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    n += _executions(getattr(sub, "jaxpr", sub), wanted, inner)
+    return n
+
+
+@pytest.mark.parametrize("kept,twice", [(None, 1), ((), 2)],
+                         ids=["kept", "nothing_kept"])
+def test_the_gradient_runs_the_indexer_and_the_forward_kernel_once(
+        kept, twice, monkeypatch):
+    """In the traced loss and gradient, heads two a call (two group calls a
+    layer, four attention layers): `kth_largest_mask`'s loop of 32 counting
+    passes once a layer and slice of queries, and the forward kernel once a
+    group call; with nothing kept (the form before PR 42) each twice. The
+    gradient kernel once a group call either way."""
+    if kept is not None:
+        monkeypatch.setattr(lm, "_kept_names", lambda arch: kept)
+    monkeypatch.setattr(lm, "HEADS_A_CALL", 2)
+    module, v, tokens = _sparse_loss_and_gradient(1)
+    closed = jax.make_jaxpr(jax.value_and_grad(lambda q: module.loss(
+        {"base": v["base"], "params": q}, tokens)[0]))(v["params"])
+    kernel = lambda name: lambda eqn: (  # noqa: E731
+        eqn.primitive.name == "pallas_call"
+        and name in str(eqn.params["name"]))
+    counting = lambda eqn: (  # noqa: E731
+        eqn.primitive.name == "scan" and eqn.params["length"] == 32
+        and any(e.primitive.name == "ge"             # key >= candidate
+                for e in eqn.params["jaxpr"].jaxpr.eqns))
+    layers, groups = 4, SPARSE.heads // 2
+    slices = 2 * -(-40 // SPARSE.index_block)        # two sequences' queries
+    assert _executions(closed.jaxpr, counting) == twice * layers * slices
+    assert _executions(closed.jaxpr, kernel("splash_mha_fwd")) == (
+        twice * layers * groups * 2)
+    assert _executions(closed.jaxpr, kernel("splash_mha_dkv")) == (
+        layers * groups * 2)
 
 
 def test_loss_with_a_lean_tail_is_the_loss(case, monkeypatch):

@@ -648,14 +648,19 @@ def _program_digest(closed) -> str:
 
 @pytest.mark.parametrize("name,positions,digest", [
     ("joyai_llm_flash_tiny", 24, "cf307f770ca7df44"),
-    ("deepseek_v32_tiny", 40, "9181a7b43929e507"),
+    ("deepseek_v32_tiny", 40, "5d6083e2be9bfcf7"),
     ("mimo_v2_flash_tiny", 64, "8b45fb77abb684e0"),
     ("ling_3_flash_tiny", 40, "d7e896098760a5ce"),
 ])
 def test_the_older_models_traced_programs_are_unchanged(name, positions, digest):
     """The loss and its gradient of the two DeepSeek-V3-shaped presets,
-    traced: deepseek's digest is that of commit c298e53 (PR 32), before a
-    third model came out of the same class; joyai's is PR 40's, whose
+    traced: deepseek's digest is PR 42's own, read from its tree (its
+    attention layers pack and name their selection and their checkpoint
+    keeps it and the kernel's output and log-sum-exp, `lm._kept`: the one
+    change to its program since commit c298e53, PR 32: 9181a7b43929e507
+    before); the three other digests stood through that change, which is
+    the proof that `_kept` answers those models as before. joyai's is PR
+    40's, whose
     one-block expert layer (`_held_whole`) is the one change to its program
     since (3a6633ee86b7eed0 before). mimo's is that of commit 0cbd328 (PR
     40), read there before a fourth model came out of the class (PR 41), and
