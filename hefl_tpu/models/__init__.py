@@ -92,6 +92,7 @@ def create_model(
     obs_metrics.gauge("model.sparse_attention_layers").set(0)
     obs_metrics.gauge("model.window_attention_layers").set(0)
     obs_metrics.gauge("model.linear_attention_layers").set(0)
+    obs_metrics.gauge("model.kda_front_kernel_layers").set(0)
     obs_metrics.gauge("model.gated_attention_layers").set(0)
     obs_metrics.gauge("dsa.kept_selection_layers").set(0)
     obs_metrics.gauge("dsa.kept_attention_layers").set(0)

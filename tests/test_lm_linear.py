@@ -6,7 +6,8 @@ groups of which 4 held) against its plain reference
 weights: logits, selections, loss, every trained leaf's gradient, the chunked
 recurrence and its gradient at lengths that are and are not whole chunks with
 the decays at the bound and near 0, causality exactly, the state at a
-chunk's edge, the gate a head, the four expert shares adding up, the model's
+chunk's edge, the front's kernel pair (interpreted here) against XLA's
+operations at heads of 128, the gate a head, the four expert shares adding up, the model's
 own counts, the published preset against the configuration's file and the
 catalog's row, and the encrypted round with a ragged last row. No device or
 topology call at import time."""
@@ -172,14 +173,34 @@ def test_the_decays_gradient_gap_is_the_operands_precision(case, monkeypatch):
     assert gap(exact) < 2e-3
 
 
-def test_gauges_count_the_layers_by_kind(case):
+# a model whose heads fill whole lanes: two layers, a linear and a latent one
+WIDE = dataclasses.replace(TINY, heads=2, kda_head_dim=128, kda_chunk=64,
+                           kda_block=16, layer_pattern=(1, 0), expert_layers=1)
+
+
+@pytest.mark.parametrize("arch,linear,kernels,fused", [
+    ("tiny", 3, 0, 1), ("wide", 1, 1, 1)])
+def test_gauges_count_the_layers_by_kind(case, arch, linear, kernels, fused):
+    """By kind, and of the linear layers those whose front is the kernel
+    pair: none at the tests' preset (heads of 16), every one where a head
+    is 128 wide."""
     value = lambda name: obs_metrics.gauge(name).value  # noqa: E731
-    assert value("model.linear_attention_layers") == 3
-    assert value("model.gated_attention_layers") == 1
-    assert value("model.fused_attention_layers") == 1
+    if arch == "tiny":
+        jax.eval_shape(case["module"].apply, case["v"], case["tokens"])
+    else:
+        module = lm.FrozenBaseLM(num_classes=VOCAB, arch=WIDE, seed=0)
+        jax.eval_shape(
+            lambda tokens: module.apply(
+                {"params": module.init_trained(), "base": module.init_base()},
+                tokens), case["tokens"])
+    assert value("model.linear_attention_layers") == linear
+    assert value("model.kda_front_kernel_layers") == kernels
+    assert value("model.gated_attention_layers") == fused
+    assert value("model.fused_attention_layers") == fused
     assert value("model.window_attention_layers") == 0
     create_model("smallcnn")
     assert value("model.linear_attention_layers") == 0
+    assert value("model.kda_front_kernel_layers") == 0
     assert value("model.gated_attention_layers") == 0
 
 
@@ -335,6 +356,152 @@ def test_the_linear_layer_matches_the_references(case, ref, conf):
         gap = float(jnp.linalg.norm(got - aux["attn_out"][i])
                     / jnp.linalg.norm(aux["attn_out"][i]))
         assert gap < 0.01, (i, kind, gap)
+
+
+# --------------------------------------------------------------------------
+# the front's kernel pair against XLA's operations (interpreted here)
+# --------------------------------------------------------------------------
+
+
+def _front_inputs(length: int, seed: int = 5):
+    """`made` for two sequences, taps as the base keeps them, decays away
+    from their start."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    n = WIDE.heads * WIDE.kda_head_dim
+    return (jax.random.normal(ks[0], (2, length, 5 * n)),
+            (0.5 * jax.random.normal(ks[1], (3 * n, WIDE.kda_conv))).astype(
+                jnp.bfloat16),
+            0.3 * jax.random.normal(ks[2], (WIDE.heads,)),
+            -1.0 + jax.random.normal(ks[3], (n,)))
+
+
+def _by_chunk(t, chunk: int = WIDE.kda_chunk):
+    """[B, S, H, d] -> [n, B, H, chunk, d], zeros behind the end."""
+    b, s, h, d = t.shape
+    t = jnp.pad(t, ((0, 0), (0, (-s) % chunk), (0, 0), (0, 0)))
+    return jnp.moveaxis(t.reshape(b, -1, chunk, h, d), (1, 3), (0, 2))
+
+
+@functools.lru_cache(maxsize=None)
+def _fronts():
+    def xla(made, *leaves):
+        front = lm._kda_front_xla(WIDE, made, *leaves)
+        return (*(_by_chunk(t) for t in front),
+                made[..., 4 * WIDE.heads * WIDE.kda_head_dim:])
+
+    def weighed(front):
+        def run(made, conv, a_log, bias, weights):
+            return sum(jnp.sum(o * w) for o, w in zip(
+                front(made, conv, a_log, bias), weights))
+        return jax.jit(jax.grad(run, argnums=(0, 2, 3)))
+    kernel = functools.partial(lm._kda_front, WIDE)
+    return jax.jit(xla), jax.jit(kernel), weighed(xla), weighed(kernel)
+
+
+@pytest.mark.parametrize("length", [256, 300, 320])
+@pytest.mark.parametrize("what", ["values", "gradient"])
+def test_the_fronts_kernels_are_the_xla_form(length, what):
+    """Two heads of 128 over two sequences of 256 positions (whole steps of
+    two chunks), 320 (five chunks: a step is one) and 300 (the last chunk
+    ragged: its rows behind the end are zeros, and write nothing back): q,
+    k, v, g by chunk and the gate's columns to 1e-6; the gradient by `made`,
+    `A_log` and `dt_bias`, every output weighed, to 1e-5 of its norm."""
+    xla, kernel, d_xla, d_kernel = _fronts()
+    args = _front_inputs(length)
+    want = xla(*args)
+    if what == "values":
+        got = kernel(*args)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape
+            assert float(jnp.max(jnp.abs(a - b))) < 1e-6
+        behind = np.asarray(got[0]).shape[0] * WIDE.kda_chunk - length
+        if behind:
+            for a in got[:4]:
+                assert not np.any(np.asarray(a[-1, :, :, -behind:]))
+        return
+    weights = tuple(jax.random.normal(jax.random.key(20 + i), o.shape)
+                    for i, o in enumerate(want))
+    for a, b in zip(d_kernel(*args, weights), d_xla(*args, weights)):
+        assert np.all(np.isfinite(np.asarray(a)))
+        assert float(jnp.linalg.norm(a - b)) < 1e-5 * float(jnp.linalg.norm(b))
+
+
+def test_the_first_positions_read_zeros_in_front_of_the_sequence():
+    """The first step's halo is a block of the sequence itself (rows 0-7,
+    the index clamped): positions 0-2 must read zeros there, so what rows
+    3-7 hold moves nothing at positions 0-2, and the XLA form, which pads
+    with zeros, agrees."""
+    xla, kernel = _fronts()[:2]
+    made, *rest = _front_inputs(256)
+    rows = jnp.arange(256)[None, :, None]
+    other = jnp.where((rows >= 3) & (rows < 8), 7.0 - made, made)
+    got, moved, want = kernel(made, *rest), kernel(other, *rest), xla(made, *rest)
+    for a, b, c in zip(got[:3], moved[:3], want[:3]):       # [n, B, H, chunk, d]
+        assert np.array_equal(np.asarray(a[0, ..., :3, :]),
+                              np.asarray(b[0, ..., :3, :]))
+        assert not np.array_equal(np.asarray(a[0, ..., 3:8, :]),
+                                  np.asarray(b[0, ..., 3:8, :]))
+        assert float(jnp.max(jnp.abs(a[0, ..., :3, :] - c[0, ..., :3, :]))) < 1e-6
+
+
+@pytest.mark.parametrize("t", [62, 127, 200])
+def test_a_position_moves_the_next_three_and_nowhere_else(t):
+    """A change of `made` at position t moves q, k, v at t to t + 3 (the
+    convolution's reach) and g at t, nowhere else, bit for bit: across a
+    chunk's edge (62 -> 65), across a step's of two chunks (127 -> 130) and
+    inside a chunk; and a cotangent at position t reaches back to t - 3 in
+    d_made's q, k, v blocks, to t alone in the decay's."""
+    _, kernel, _, d_kernel = _fronts()
+    made, *rest = _front_inputs(256)
+    here = (jnp.arange(256) == t)[None, :, None]
+    got, moved = kernel(made, *rest), kernel(jnp.where(here, made + 1.0, made), *rest)
+    flat = lambda a: np.asarray(  # noqa: E731
+        a.transpose(1, 0, 3, 2, 4).reshape(2, 256, -1))     # [B, S, H d]
+    for i, (a, b) in enumerate(zip(got[:4], moved[:4])):
+        a, b = flat(a), flat(b)
+        reach = range(t, t + (4 if i < 3 else 1))
+        for pos in reach:
+            assert not np.array_equal(a[:, pos], b[:, pos])
+        still = np.ones(256, bool)
+        still[list(reach)] = False
+        assert np.array_equal(a[:, still], b[:, still])
+    weights = tuple(
+        jnp.zeros_like(o).at[t // 64, :, :, t % 64].set(1.0) if i < 4
+        else jnp.zeros_like(o) for i, o in enumerate(got))
+    d_made = np.asarray(d_kernel(made, *rest, weights)[0])
+    n = WIDE.heads * WIDE.kda_head_dim
+    for i in range(5):
+        touched = np.flatnonzero(np.any(
+            d_made[..., i * n:(i + 1) * n] != 0, axis=(0, 2)))
+        assert list(touched) == {0: list(range(t - 3, t + 1)), 3: [t],
+                                 4: []}.get(i, list(range(t - 3, t + 1)))
+
+
+def test_the_layer_with_the_kernels_is_the_layer_without(monkeypatch):
+    """`kda_layer` whole at heads of 128, its front the kernel pair and the
+    recurrence given operands by chunk, against the same layer through the
+    XLA front and the recurrence's own move into chunks: output, and the
+    gradient by the input and by every trained leaf."""
+    h, d, width = WIDE.heads, WIDE.kda_head_dim, WIDE.hidden
+    ks = jax.random.split(jax.random.key(8), 6)
+    w = {"in": 0.1 * jax.random.normal(ks[0], (width, 5 * h * d)),
+         "beta": 0.1 * jax.random.normal(ks[1], (width, h)),
+         "conv": 0.5 * jax.random.normal(ks[2], (3 * h * d, WIDE.kda_conv)),
+         "o": 0.1 * jax.random.normal(ks[3], (h * d, width))}
+    w = {name: t.astype(jnp.bfloat16) for name, t in w.items()}
+    g = {"A_log": 0.3 * jax.random.normal(ks[4], (h,)),
+         "dt_bias": -2.0 + jax.random.normal(ks[5], (h * d,)),
+         "o_norm": jnp.ones((d,))}
+    x = jax.random.normal(jax.random.key(9), (2, 150, width))
+    both = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+        lambda g, x: jnp.sum(lm.kda_layer(WIDE, w, g, x) ** 2), (0, 1)))(g, x)
+    assert lm.kda_front_kernel(WIDE) and not lm.kda_front_kernel(TINY)
+    got = both()
+    monkeypatch.setattr(lm, "kda_front_kernel", lambda arch: False)
+    want = both()
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert float(jnp.linalg.norm(a - b)) < 1e-4 * float(jnp.linalg.norm(b))
 
 
 @pytest.mark.parametrize("gate,differs", [("head", False), ("channel", True),

@@ -246,6 +246,55 @@ def test_linear_recurrence_and_sort_free_experts_compile_for_described_v5e(
     assert experts.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+def test_linear_front_kernels_compile_for_described_v5e(one_chip, monkeypatch):
+    """A linear layer's front at Ling-3.0-flash's widths (`lm._kda_front`
+    over `made` f32[1, 8192, 20480]: 32 heads of 128, chunks of 64, taps of
+    4): the forward kernel and the gradient's lower through Mosaic
+    (sublane rolls of 136- and 144-row tiles, a halo block of 8 rows on
+    either side), and a whole layer's gradient through its checkpoint has
+    the forward kernel twice and the gradient's once, with no array of
+    `made`'s q, k, v or decay columns' size written between the
+    projections' product and the kernels, nor between the kernels and the
+    recurrence's loops: the kernels' operands are the product's fusion and
+    bitcasts of the loops' results."""
+    from hefl_tpu.models import lm
+
+    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    arch = lm.PRESETS["ling_3_flash"]
+    assert lm.kda_front_kernel(arch)
+    h, d, width, s = arch.heads, arch.kda_head_dim, arch.hidden, 8192
+    shape = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    w = {"in": shape(width, 5 * h * d, dtype=jnp.bfloat16),
+         "beta": shape(width, h, dtype=jnp.bfloat16),
+         "conv": shape(3 * h * d, arch.kda_conv, dtype=jnp.bfloat16),
+         "o": shape(h * d, width, dtype=jnp.bfloat16)}
+    g = {"A_log": shape(h), "dt_bias": shape(h * d), "o_norm": shape(d)}
+    layer = jax.checkpoint(lambda g, x, w: lm.kda_layer(arch, w, g, x),
+                           policy=lm._kept(arch))
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        front = jax.jit(lambda *a: lm._kda_front(arch, *a)).lower(
+            shape(1, s, 5 * h * d), w["conv"], g["A_log"], g["dt_bias"]).compile()
+        grad = jax.jit(jax.grad(lambda g, x, w: jnp.sum(layer(g, x, w) ** 2),
+                                (0, 1))).lower(g, shape(1, s, width), w).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert len(re.findall(r"^ *%?kda_front_fwd[.0-9]* = ", front.as_text(), re.M)) == 1
+    text = grad.as_text()
+    sites = lambda kernel: re.findall(  # noqa: E731
+        rf"^ *%?{kernel}[.0-9]* = .*? custom-call\(([^)]*)\)", text, re.M)
+    assert len(sites("kda_front_fwd")) == 2 and len(sites("kda_front_bwd")) == 1
+    for operands in sites("kda_front_fwd") + sites("kda_front_bwd"):
+        made = operands.split(",")[0].strip()
+        assert re.match(r"%?convolution_bitcast_fusion", made), made
+    by_chunk = [re.sub(r"/\*.*?\*/", "", name).strip()
+                for name in sites("kda_front_bwd")[0].split(",")[12:19]]
+    assert all(re.match(r"%?bitcast", name) for name in by_chunk), by_chunk
+    assert grad.memory_analysis().temp_size_in_bytes < 4.0e9
+
+
 @pytest.mark.parametrize("kind", [0, 1], ids=["global", "window"])
 def test_grouped_attention_gradient_compiles_for_described_v5e(
         kind, one_chip, monkeypatch):
