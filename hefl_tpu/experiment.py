@@ -508,7 +508,7 @@ def run_experiment(
             input_shape=tuple(int(d) for d in x.shape[1:]),
             seed=cfg.seed,
         )
-    # A model with a frozen base (models/lm.py) makes it here, on the device,
+    # A model with a frozen base (models/lm/) makes it here, on the device,
     # once a process and seed; any other model has none and this is a no-op.
     with obs_spans.span("hefl.setup.base"):
         frozen_base(module)

@@ -68,7 +68,7 @@ class TrainConfig:
     # to "vmap" otherwise (fl.fusion.resolve_fusion_backend). Same math,
     # same RNG streams, same callback semantics on both backends
     # (tests/test_perf.py pins it).
-    # A token model over a frozen base (models/lm.py) is trained one client
+    # A token model over a frozen base (models/lm/) is trained one client
     # after another whatever this says ("auto" only; fl.fusion says why).
     client_fusion: str = "auto"
     # --- update sanitization (fl.faults / the participation-masked round

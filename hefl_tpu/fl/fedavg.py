@@ -237,7 +237,7 @@ def _build_round_fn(
 
 def with_frozen_base(module, program):
     """The round program as its callers call it. A model trained whole gets
-    `program` itself. A model with a frozen base (models/lm.py) gets the
+    `program` itself. A model with a frozen base (models/lm/) gets the
     program with the base of the moment as its last argument: an argument,
     so it is no constant of the HLO; never donated; looked up at each call,
     so a check that plants its own seeded base reaches the next round."""

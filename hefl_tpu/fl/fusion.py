@@ -68,7 +68,7 @@ from hefl_tpu.obs import scopes as obs_scopes
 
 FUSION_BACKENDS = ("fused", "vmap")
 # One client after another (fedavg.serial_train): no setting selects it. It
-# is what a token model over a frozen base gets (models/lm.py): one client's
+# is what a token model over a frozen base gets (models/lm/): one client's
 # step of thousands of tokens fills the MXU by itself, and its grouped
 # expert product (a Pallas call with scalar prefetch) has no batching over
 # clients, so neither other backend can lower it.
@@ -281,7 +281,7 @@ def resolve_fusion_backend(setting: str | None, module) -> str:
     clients into the lanes (the class says so beside the method:
     `folded_lane_packed`) and "vmap" otherwise; "fused" and "vmap" are
     taken as given, and "fused" on a model without a `folded_apply` is an
-    error. A token model (models/lm.py) is trained one client after
+    error. A token model (models/lm/) is trained one client after
     another, and any pin is an error.
     """
     from hefl_tpu.models.lm import is_token_model

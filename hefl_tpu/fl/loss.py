@@ -38,7 +38,7 @@ def prox_term(params, global_params, mu: float) -> jax.Array:
 
 def token_loss_fn(module, params, tokens, global_params=None, prox_mu: float = 0.0):
     """-> (loss, (loss without the proximal term, next-token accuracy)) of a
-    token model (`models/lm.py`): `tokens` int[B, S + 2] label themselves at
+    token model (`models/lm/`): `tokens` int[B, S + 2] label themselves at
     every position (token i + 1 for the main head, i + 2 for the prediction
     module), so there is no one-hot and no label argument. `params` is the
     trained subset; the module holds its frozen base."""

@@ -20,6 +20,7 @@ from hefl_tpu.models.lm import (
     PRESETS as LM_PRESETS,
     FrozenBaseLM,
     JoyAIFlash,
+    _set_layer_gauges,
     frozen_base,
     is_token_model,
     set_frozen_base,
@@ -36,7 +37,7 @@ MODEL_REGISTRY: dict[str, tuple[type, int, tuple[int, int, int]]] = {
     "logreg": (LogReg, 10, (28, 28, 1)),
     "resnet20": (ResNet20, 10, (32, 32, 3)),
 }
-# Token models (models/lm.py): a frozen bfloat16 base that stays on the
+# Token models (models/lm/): a frozen bfloat16 base that stays on the
 # client and a trained subset, which is what `create_model` returns as
 # `params`. name -> (widths and the share held, default vocabulary held).
 TOKEN_MODELS: dict[str, int] = {
@@ -87,15 +88,8 @@ def create_model(
         rng = jax.random.key(0)
     # MedCNN's stages set it as they are traced; no other model has any.
     obs_metrics.gauge("model.polyphase_stages").set(0)
-    # a token model's forward sets them as it is traced (models/lm.py)
-    obs_metrics.gauge("model.fused_attention_layers").set(0)
-    obs_metrics.gauge("model.sparse_attention_layers").set(0)
-    obs_metrics.gauge("model.window_attention_layers").set(0)
-    obs_metrics.gauge("model.linear_attention_layers").set(0)
-    obs_metrics.gauge("model.kda_front_kernel_layers").set(0)
-    obs_metrics.gauge("model.gated_attention_layers").set(0)
-    obs_metrics.gauge("dsa.kept_selection_layers").set(0)
-    obs_metrics.gauge("dsa.kept_attention_layers").set(0)
+    # a token model's forward sets them as it is traced (models/lm/model.py)
+    _set_layer_gauges()
     dummy = jnp.zeros(
         (1, *(input_shape if input_shape is not None else default_shape)), jnp.float32
     )

@@ -1,4 +1,4 @@
-"""The token models with a frozen base (hefl_tpu/models/lm.py) against their
+"""The token models with a frozen base (hefl_tpu/models/lm/) against their
 plain references (benchmarks/reference/joyai_llm_flash.py, deepseek_v32.py)
 on seeded weights at a small size, the share of a stated deployment tied to
 the uncut layer, routing under a planted imbalance, the indexer's selection
@@ -129,7 +129,7 @@ def test_latent_attention_block_matches_reference(case):
     if arch.index_topk:
         # compared where both indexers select the same 8 keys (bfloat16
         # products against float32 rank a pair at the edge differently)
-        c_q = lm.rms_norm(lm._mm(x, w["q_a"]), g["q_norm"], arch.eps)
+        c_q = lm.common.rms_norm(lm.common._mm(x, w["q_a"]), g["q_norm"], arch.eps)
         ours = lm.select_keys(arch, w["index"], x, c_q)
         theirs = _highest(case.ref._attention, case.z, w, g, x, case.mm,
                           yarn=case.z["yarn"])[1]
@@ -195,9 +195,9 @@ def test_padded_positions_move_no_output_and_receive_no_gradient():
         assert jnp.array_equal(a, b[:, :300])
         assert not np.any(np.asarray(b[:, 300:]))
     # one kernel object a (padded length, heads, block): built once
-    before = lm._attention_kernel.cache_info().misses
+    before = lm.attention._attention_kernel.cache_info().misses
     lm.causal_attention(*cut(q, k, v), 128)
-    assert lm._attention_kernel.cache_info().misses == before
+    assert lm.attention._attention_kernel.cache_info().misses == before
 
 
 def test_gauge_counts_the_attention_layers_that_took_the_kernel(case):
@@ -229,7 +229,7 @@ def test_expert_block_matches_reference(case):
     h = jax.random.normal(jax.random.key(2), (2, case.positions, arch.hidden),
                           jnp.float32)
     want, aux = _highest(case.block, w, g, h)
-    got, (load, idx), picked = lm.block(arch, w, g, h)
+    got, (load, idx), picked = lm.model.block(arch, w, g, h)
     # the float32 router agrees (but for a token whose attention read a key
     # the two indexers rank differently)
     assert float(jnp.mean((idx == aux["experts"]).astype(jnp.float32))) >= (
@@ -312,7 +312,7 @@ def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer(case):
         pairs += int(jnp.sum(load))
     assert len(parts) == n // held
     assert pairs == 40 * arch.experts_per_tok       # every pair, once
-    total = sum(parts) + lm.glu(w["shared"], x)
+    total = sum(parts) + lm.experts.glu(w["shared"], x)
     assert float(jnp.max(jnp.abs(total - uncut))) < 0.02 * float(jnp.std(uncut))
 
 
@@ -329,7 +329,7 @@ def test_planted_imbalance_drops_no_pair(case):
     x = jax.random.normal(jax.random.key(7), (64, arch.hidden), jnp.float32)
     idx, weights = lm.route(arch, router, bias, x)
     assert set(np.asarray(idx).ravel().tolist()) == {1, 2}
-    blocks, rows = lm.pair_blocks(arch, idx.size)
+    blocks, rows = lm.experts.pair_blocks(arch, idx.size)
     assert (blocks, rows) == ((4, 32) if arch.index_topk else (1, 128))
     y, load = lm.held_experts(arch, w["experts"], x, idx, weights)
     assert np.asarray(load).tolist() == [0, 64, 64, 0]
@@ -414,7 +414,7 @@ def test_one_block_path_matches_reference_under_planted_routing(kind, wide):
     product, the un-sort and both gradients as gathers of the held rows)
     against the reference's expert layer: value, gradient by x and by the
     router, from no held pair at all to every pair held."""
-    assert lm.pair_blocks(WIDE, 320) == (1, 320)
+    assert lm.experts.pair_blocks(WIDE, 320) == (1, 320)
     idx = _planted_selections(kind)
     held = int(np.sum(np.asarray(idx) < 8))
     assert {"none_held": held == 0, "all_held": held == 320,
@@ -434,15 +434,15 @@ def test_rows_behind_the_last_group_are_never_read(wide, monkeypatch):
     path: no select pass cleans them, so nothing may read them."""
     idx = _planted_selections("uniform")
     clean, _ = _one_block_against_reference(wide, idx)
-    call, poisoned = lm._gmm_call, []
+    call, poisoned = lm.experts._gmm_call, []
 
-    def poison(x, w, sizes, transpose, rows=lm.GMM_ROWS):
+    def poison(x, w, sizes, transpose, rows=lm.experts.GMM_ROWS):
         out = call(x, w, sizes, transpose, rows)
         behind = jnp.arange(out.shape[0]) >= jnp.sum(sizes)
         poisoned.append(int(jnp.sum(behind)))
         return jnp.where(behind[:, None], jnp.nan, out)
 
-    monkeypatch.setattr(lm, "_gmm_call", poison)
+    monkeypatch.setattr(lm.experts, "_gmm_call", poison)
     got, _ = _one_block_against_reference(wide, idx)
     # the value's two products, then the gradient's two each way
     assert len(poisoned) == 6 and min(poisoned) > 100
@@ -501,7 +501,7 @@ def test_yarn_frequencies_against_the_closed_form():
     keep theta^(-2i/64), pairs past 23 are divided by 40, a linear ramp
     between; the softmax scale carries m^2, m = 0.1 ln 40 + 1."""
     arch = lm.PRESETS["deepseek_v32"]
-    ramp = lm.yarn_ramp(64, arch.rope_theta, arch.rope_scaling)
+    ramp = lm.common.yarn_ramp(64, arch.rope_theta, arch.rope_scaling)
     i = np.arange(32)
     cd = lambda r: 64 * np.log(4096 / (2 * np.pi * r)) / (2 * np.log(1e4))  # noqa: E731
     low, high = int(np.floor(cd(32))), int(np.ceil(cd(1)))
@@ -509,18 +509,18 @@ def test_yarn_frequencies_against_the_closed_form():
     assert np.allclose(ramp, np.clip((i - 10) / 13, 0, 1))
     # rope() turns position 1 by the scaled frequencies
     x = jnp.zeros((1, 2, 1, 64)).at[..., 0::2].set(1.0)
-    got = lm.rope(x, arch.rope_theta, arch.rope_scaling)[0, 1, 0]
+    got = lm.common.rope(x, arch.rope_theta, arch.rope_scaling)[0, 1, 0]
     f = 1e4 ** (-2 * i / 64)
     f = f / 40 * ramp + f * (1 - ramp)
     assert np.allclose(got[0::2], np.cos(f), atol=1e-6)
     assert np.allclose(got[1::2], np.sin(f), atol=1e-6)
-    half = lm.rope(x[..., :2].repeat(32, -1), arch.rope_theta,
+    half = lm.common.rope(x[..., :2].repeat(32, -1), arch.rope_theta,
                    arch.rope_scaling, interleaved=False)[0, 1, 0]
     assert np.allclose(half[:32], np.cos(f), atol=1e-6)     # pair i: (x_i, x_i+32)
     assert np.allclose(half[32:], np.sin(f), atol=1e-6)
     m = 0.1 * np.log(40) + 1
-    assert lm.softmax_scale(arch) == pytest.approx(m * m / np.sqrt(192))
-    assert lm.softmax_scale(lm.PRESETS["joyai_llm_flash"]) == 1 / np.sqrt(192)
+    assert lm.common.softmax_scale(arch) == pytest.approx(m * m / np.sqrt(192))
+    assert lm.common.softmax_scale(lm.PRESETS["joyai_llm_flash"]) == 1 / np.sqrt(192)
 
 
 def test_kth_largest_mask_is_top_k_with_its_ties():
@@ -531,7 +531,7 @@ def test_kth_largest_mask_is_top_k_with_its_ties():
         _, idx = jax.lax.top_k(scores, min(k, 200))
         want = np.zeros((16, 200), bool)
         np.put_along_axis(want, np.asarray(idx), True, axis=1)
-        assert np.array_equal(np.asarray(lm.kth_largest_mask(scores, k)), want), k
+        assert np.array_equal(np.asarray(lm.attention.kth_largest_mask(scores, k)), want), k
 
 
 def test_selection_does_not_depend_on_the_slice_of_queries_it_is_made_in():
@@ -558,14 +558,14 @@ def test_selection_is_causal_has_min_t_plus_1_k_members_and_dense_when_short():
         "q_norm": jnp.ones(arch.q_lora_rank), "kv_norm": jnp.ones(arch.kv_lora_rank)}
     w = jax.tree_util.tree_map(lambda a: a * 8, w)        # scores that differ
     x = jax.random.normal(jax.random.key(1), (2, 50, arch.hidden), jnp.float32)
-    c_q = lm.rms_norm(lm._mm(x, w["q_a"]), g["q_norm"], arch.eps)
+    c_q = lm.common.rms_norm(lm.common._mm(x, w["q_a"]), g["q_norm"], arch.eps)
     picked = np.asarray(lm.select_keys(arch, w["index"], x, c_q))
     assert picked.shape == (2, 50, 50) and not np.triu(picked, 1).any()
     assert np.array_equal(picked.sum(-1),
                           np.tile(np.minimum(np.arange(50) + 1, 8), (2, 1)))
     assert picked[:, np.arange(8), :][:, :, :8].sum() == 2 * 36   # all causal keys
     # the picked keys are top_k's over the scores the indexer's parts give
-    q, k, wt = lm.index_scores(arch, w["index"], x, c_q)
+    q, k, wt = lm.attention.index_scores(arch, w["index"], x, c_q)
     score = jnp.einsum("bqj,bqjs->bqs", wt, jax.nn.relu(jnp.einsum(
         "bqjd,bsd->bqjs", q, k, preferred_element_type=jnp.float32)))
     score = jnp.where(np.tril(np.ones((50, 50), bool)), score, -jnp.inf)
@@ -616,15 +616,15 @@ def test_a_packed_selection_unpacks_to_itself(shape):
     """32 queries a word, a number of queries that is no multiple of 32
     padded with queries that pick nothing."""
     picked = jax.random.uniform(jax.random.key(sum(shape)), shape) < 0.4
-    bits = lm.pack_selection(picked)
+    bits = lm.attention.pack_selection(picked)
     words = -(-shape[-2] // 32)
     assert bits.dtype == jnp.uint32 and bits.shape == (
         shape[0], words, shape[-1])
-    assert np.array_equal(np.asarray(lm.unpack_selection(bits, shape[-2])),
+    assert np.array_equal(np.asarray(lm.attention.unpack_selection(bits, shape[-2])),
                           np.asarray(picked))
     # a query behind the last reads 0, whatever the words hold
-    assert not np.asarray(lm.unpack_selection(
-        lm.pack_selection(jnp.ones(shape, bool)), 32 * words)
+    assert not np.asarray(lm.attention.unpack_selection(
+        lm.attention.pack_selection(jnp.ones(shape, bool)), 32 * words)
     )[:, shape[-2]:].any()
 
 
@@ -651,9 +651,9 @@ def test_what_the_checkpoint_keeps_moves_no_bit_of_loss_or_gradient(
     module, v, tokens = _sparse_loss_and_gradient(seed)
     vg = lambda: jax.jit(jax.value_and_grad(lambda q: module.loss(  # noqa: E731
         {"base": v["base"], "params": q}, tokens)[0]))(v["params"])
-    assert lm._kept_names(SPARSE) == (lm.DSA_PICKED, lm.ATTN_SAVED)
+    assert lm.model._kept_names(SPARSE) == (lm.attention.DSA_PICKED, lm.attention.ATTN_SAVED)
     l_kept, g_kept = vg()
-    monkeypatch.setattr(lm, "_kept_names", lambda arch: ())
+    monkeypatch.setattr(lm.model, "_kept_names", lambda arch: ())
     l_none, g_none = vg()
     assert float(l_kept) == float(l_none)
     flat = jax.tree_util.tree_leaves_with_path
@@ -688,8 +688,8 @@ def test_the_gradient_runs_the_indexer_and_the_forward_kernel_once(
     group call; with nothing kept (the form before PR 42) each twice. The
     gradient kernel once a group call either way."""
     if kept is not None:
-        monkeypatch.setattr(lm, "_kept_names", lambda arch: kept)
-    monkeypatch.setattr(lm, "HEADS_A_CALL", 2)
+        monkeypatch.setattr(lm.model, "_kept_names", lambda arch: kept)
+    monkeypatch.setattr(lm.attention, "HEADS_A_CALL", 2)
     module, v, tokens = _sparse_loss_and_gradient(1)
     closed = jax.make_jaxpr(jax.value_and_grad(lambda q: module.loss(
         {"base": v["base"], "params": q}, tokens)[0]))(v["params"])
@@ -723,7 +723,7 @@ def test_loss_with_a_lean_tail_is_the_loss(case, monkeypatch):
     vg = lambda: jax.jit(jax.value_and_grad(lambda q: module.loss(  # noqa: E731
         {"base": v["base"], "params": q}, tokens)[0]))(v["params"])
     l_kept, g_kept = vg()
-    monkeypatch.setattr(lm, "STREAM_BYTES", 0)
+    monkeypatch.setattr(lm.model, "STREAM_BYTES", 0)
     l_lean, g_lean = vg()
     assert float(l_lean) == pytest.approx(float(l_kept), rel=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(g_lean),
@@ -736,13 +736,13 @@ def test_dense_mlp_by_parts_is_the_mlp(monkeypatch):
         jnp.bfloat16), "down": jax.random.normal(jax.random.key(1), (64, 32)
                                                  ).astype(jnp.bfloat16)}
     x = jax.random.normal(jax.random.key(2), (2, 24, 32))
-    want = lm.glu(w, x)
-    assert lm.glu_by_parts(w, x) is not None
-    monkeypatch.setattr(lm, "GLU_BYTES", 2 * 24 * 128 * 4 // 4)   # four parts
-    got = lm.glu_by_parts(w, x)
+    want = lm.experts.glu(w, x)
+    assert lm.experts.glu_by_parts(w, x) is not None
+    monkeypatch.setattr(lm.experts, "GLU_BYTES", 2 * 24 * 128 * 4 // 4)   # four parts
+    got = lm.experts.glu_by_parts(w, x)
     assert jnp.allclose(got, want, rtol=1e-6, atol=1e-6)
-    g = jax.grad(lambda a: jnp.sum(lm.glu_by_parts(w, a) ** 2))(x)
-    assert jnp.allclose(g, jax.grad(lambda a: jnp.sum(lm.glu(w, a) ** 2))(x),
+    g = jax.grad(lambda a: jnp.sum(lm.experts.glu_by_parts(w, a) ** 2))(x)
+    assert jnp.allclose(g, jax.grad(lambda a: jnp.sum(lm.experts.glu(w, a) ** 2))(x),
                         rtol=1e-5, atol=1e-5)
 
 
@@ -954,3 +954,87 @@ def test_serial_backend_trains_what_vmap_trains():
     for a, b in zip(jax.tree_util.tree_leaves(p_v), jax.tree_util.tree_leaves(p_s)):
         assert jnp.allclose(a, b, rtol=1e-5, atol=1e-6)
     assert jnp.allclose(m_v, m_s, rtol=1e-5, atol=1e-6)
+
+
+# --------------------------------------------------------------------------
+# the package: one plan an expert layer, imports that point one way
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("name", sorted(lm.PRESETS))
+def test_with_counts_reports_the_rows_the_plan_gives_the_products(
+        name, monkeypatch):
+    """The rows `_with_counts` puts into `loss`'s loads are the rows
+    `held_experts` hands its grouped products under the same plan
+    (`expert_plan`): every `_gmm_call` of a traced layer is one the plan
+    names (the front's rows and tile, a block's), and at any count of held
+    pairs the front's rows plus a block's for each trip of `_held_blocks`'s
+    loop is what the loads carry. At the cells' 8,192 tokens for the
+    published presets (traced as shapes: nothing is computed)."""
+    arch = lm.PRESETS[name]
+    tokens = 96 if "tiny" in name else 8192
+    k, held = arch.experts_per_tok, arch.held_experts
+    scanned = lm.model.stacked(arch)
+    plan = lm.experts.expert_plan(arch, tokens * k, scanned)
+    seen = []
+
+    def record(x, w, sizes, transpose, rows=lm.experts.GMM_ROWS):
+        seen.append((x.shape[0], rows))
+        return jnp.zeros((x.shape[0], w.shape[1 if transpose else 2]),
+                         jnp.float32)
+
+    monkeypatch.setattr(lm.experts, "_gmm_call", record)
+    n = held * (arch.expert_layers if scanned else 1)
+    shape = jax.ShapeDtypeStruct
+    jax.eval_shape(
+        lambda w, x, idx, ws: lm.held_experts(
+            arch, w, x, idx, ws, at=0 if scanned else None),
+        {"gate_up": shape((n, arch.hidden, 2 * arch.moe_intermediate),
+                          jnp.bfloat16),
+         "down": shape((n, arch.moe_intermediate, arch.hidden), jnp.bfloat16)},
+        shape((tokens, arch.hidden), jnp.float32),
+        shape((tokens, k), jnp.int32), shape((tokens, k), jnp.float32))
+    front = (plan.front + plan.spare, plan.tile)
+    block = (plan.rows, lm.experts.GMM_ROWS)
+    behind = plan.front < tokens * k
+    assert sorted(seen) == sorted([front] * 2 * bool(plan.front)
+                                  + [block] * 2 * behind)
+    for pairs in (0, 1, plan.front, plan.front + 1, tokens * k):
+        loads = jnp.zeros((2, held), jnp.int32).at[:, 0].set(pairs)
+        given = lm.model._with_counts(arch, loads, None, 1, tokens + 2)[
+            0, held + 1]
+        trips = max(-(-pairs // plan.rows) - plan.front // plan.rows, 0)
+        assert int(given) == front[0] * bool(plan.front) + (
+            block[0] * trips * behind), pairs
+
+
+def test_the_packages_imports_point_one_way():
+    """`hefl_tpu/models/lm/`: `common` imports no module of the package;
+    `attention`, `kda` and `experts` import `common` alone; `model` imports
+    those four; nothing imports `model` but `__init__` (read with `ast`:
+    no import is run)."""
+    import ast
+
+    package = os.path.join(ROOT, "hefl_tpu", "models", "lm")
+    rank = {"common": 0, "attention": 1, "kda": 1, "experts": 1, "model": 2}
+    found = set(f[:-3] for f in os.listdir(package) if f.endswith(".py"))
+    assert found == set(rank) | {"__init__"}
+    for module, level in rank.items():
+        with open(os.path.join(package, module + ".py")) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            names = []
+            if isinstance(node, ast.ImportFrom) and node.module:
+                assert node.level == 0, (module, "a relative import")
+                if node.module == "hefl_tpu.models.lm":
+                    names = [a.name for a in node.names]
+                elif node.module.startswith("hefl_tpu.models.lm."):
+                    names = [node.module.split(".")[3]]
+                elif node.module == "hefl_tpu.models":
+                    names = [a.name for a in node.names if a.name == "lm"]
+            elif isinstance(node, ast.Import):
+                names = [a.name.split(".")[3] for a in node.names
+                         if a.name.startswith("hefl_tpu.models.lm.")]
+            for other in names:
+                assert other in rank and rank[other] < level, (
+                    f"{module} imports {other}")

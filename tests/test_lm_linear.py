@@ -163,10 +163,11 @@ def test_the_decays_gradient_gap_is_the_operands_precision(case, monkeypatch):
     gap = lambda g: float(  # noqa: E731
         np.linalg.norm(flat(g) - want) / np.linalg.norm(want))
     assert 5e-3 < gap(case["g_sys"]) < 0.05
-    monkeypatch.setattr(lm, "kda_recurrence", functools.partial(
+    monkeypatch.setattr(lm.kda, "kda_recurrence", functools.partial(
         lm.kda_recurrence, operands=jnp.float32))
-    monkeypatch.setattr(lm, "_mm", lambda x, w: jnp.dot(
-        x, w.astype(jnp.float32), precision=lm.HIGHEST))
+    for module in (lm.attention, lm.kda, lm.experts, lm.model):   # every `_mm`
+        monkeypatch.setattr(module, "_mm", lambda x, w: jnp.dot(
+            x, w.astype(jnp.float32), precision=lm.common.HIGHEST))
     base = case["v"]["base"]
     exact = jax.jit(jax.grad(lambda p: case["module"].loss(
         {"params": p, "base": base}, case["tokens"])[0]))(case["v"]["params"])
@@ -305,11 +306,11 @@ def test_the_state_crosses_a_chunks_edge(ref):
 def test_the_triangular_system_is_solved_exactly_in_sub_blocks():
     n = jnp.tril(jax.random.normal(jax.random.key(1), (3, 16, 16)), -1)
     for block in (4, 8, 16):
-        inv = lm._unit_lower_inverse(n, block)
+        inv = lm.kda._unit_lower_inverse(n, block)
         assert float(jnp.max(jnp.abs(
             jnp.matmul(inv, jnp.eye(16) + n, precision="highest")
             - jnp.eye(16)))) < 1e-4
-    grad = jax.grad(lambda n: jnp.sum(lm._unit_lower_inverse(n, 4) ** 2))(n)
+    grad = jax.grad(lambda n: jnp.sum(lm.kda._unit_lower_inverse(n, 4) ** 2))(n)
     want = jax.grad(lambda n: jnp.sum(jnp.linalg.inv(jnp.eye(16) + n) ** 2))(n)
     assert float(jnp.max(jnp.abs(grad - want))) < 1e-3 * float(
         jnp.max(jnp.abs(want)))
@@ -385,7 +386,7 @@ def _by_chunk(t, chunk: int = WIDE.kda_chunk):
 @functools.lru_cache(maxsize=None)
 def _fronts():
     def xla(made, *leaves):
-        front = lm._kda_front_xla(WIDE, made, *leaves)
+        front = lm.kda._kda_front_xla(WIDE, made, *leaves)
         return (*(_by_chunk(t) for t in front),
                 made[..., 4 * WIDE.heads * WIDE.kda_head_dim:])
 
@@ -394,7 +395,7 @@ def _fronts():
             return sum(jnp.sum(o * w) for o, w in zip(
                 front(made, conv, a_log, bias), weights))
         return jax.jit(jax.grad(run, argnums=(0, 2, 3)))
-    kernel = functools.partial(lm._kda_front, WIDE)
+    kernel = functools.partial(lm.kda._kda_front, WIDE)
     return jax.jit(xla), jax.jit(kernel), weighed(xla), weighed(kernel)
 
 
@@ -495,9 +496,9 @@ def test_the_layer_with_the_kernels_is_the_layer_without(monkeypatch):
     x = jax.random.normal(jax.random.key(9), (2, 150, width))
     both = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
         lambda g, x: jnp.sum(lm.kda_layer(WIDE, w, g, x) ** 2), (0, 1)))(g, x)
-    assert lm.kda_front_kernel(WIDE) and not lm.kda_front_kernel(TINY)
+    assert lm.kda.kda_front_kernel(WIDE) and not lm.kda.kda_front_kernel(TINY)
     got = both()
-    monkeypatch.setattr(lm, "kda_front_kernel", lambda arch: False)
+    monkeypatch.setattr(lm.kda, "kda_front_kernel", lambda arch: False)
     want = both()
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
@@ -549,11 +550,11 @@ def test_the_four_shares_add_up_to_the_uncut_layer(ref, conf):
         share = dataclasses.replace(TINY, held_start=start)
         w = {"experts": {k: t[start:start + held] for k, t in experts.items()},
              "bias": layer["bias"], "shared": layer["shared"]}
-        y, load, _ = lm.expert_layer(share, w, router, x[None])
+        y, load, _ = lm.experts.expert_layer(share, w, router, x[None])
         parts.append(y[0])
         pairs += int(jnp.sum(load))
     assert len(parts) == 4 and pairs == 40 * TINY.experts_per_tok
-    mine = lm.glu(layer["shared"], x)            # what every chip computes alike
+    mine = lm.experts.glu(layer["shared"], x)            # what every chip computes alike
     whole = sum(parts) - 3 * mine
     want = routed + shared
     assert float(jnp.max(jnp.abs(whole - want))) < 0.03 * float(jnp.std(want))
@@ -589,7 +590,7 @@ def test_held_pairs_behind_the_front_go_through_the_blocks():
         np.asarray(idx).ravel(), minlength=6)[:4])
     # and the order by counting is the stable sort's
     key = jnp.where(idx < 4, idx, 4).reshape(-1)
-    pos, order, counts = lm._counted_order(key, 5)
+    pos, order, counts = lm.experts._counted_order(key, 5)
     assert np.array_equal(np.asarray(order), np.argsort(np.asarray(key),
                                                         kind="stable"))
     assert np.array_equal(np.asarray(order)[np.asarray(pos)], np.arange(t * 2))

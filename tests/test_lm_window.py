@@ -170,7 +170,7 @@ def test_gauges_count_the_layers_and_the_windows_blocks(case, ref):
     assert g["swa.block_pairs_over_window_pairs"] == pytest.approx(
         128 * 128 / ref.window_pairs(128, 8))
     # at the benchmark's size: two blocks of 128 a query block but the first
-    _, ratio = lm._grouped_kernel(8192, 8, 128, 128, True)
+    _, ratio = lm.attention._grouped_kernel(8192, 8, 128, 128, True)
     assert ratio == pytest.approx(127 * 128 * 128 / ref.window_pairs(8192, 128))
     assert 1.99 < ratio < 2.0
     create_model("smallcnn", num_classes=2, input_shape=(16, 16, 3))
@@ -184,7 +184,7 @@ def test_loads_carry_the_rows_given_without_an_indexer(case):
     gauge from them and leaves the indexer's alone."""
     counted = np.asarray(case.counted)
     held = TINY.held_experts
-    assert counted.shape == (3, held + lm.COUNTED)
+    assert counted.shape == (3, held + lm.model.COUNTED)
     assert np.array_equal(counted[:, :held], np.asarray(case.loads))
     pairs = POSITIONS * TINY.experts_per_tok
     assert counted[:, held:].tolist() == [[-1, pairs, 0, 0]] * 3
@@ -197,12 +197,12 @@ def test_loads_carry_the_rows_given_without_an_indexer(case):
     # a chip that holds few of the experts is given blocks of its held pairs
     few = dataclasses.replace(TINY, n_experts=16, pair_block=32)
     loads = jnp.asarray([[3, 0, 40, 2], [0, 0, 0, 0]], jnp.int32)
-    rows = np.asarray(lm._with_counts(few, loads, None, 1, POSITIONS + 2))
+    rows = np.asarray(lm.model._with_counts(few, loads, None, 1, POSITIONS + 2))
     assert rows[:, held:].tolist() == [[-1, 64, 0, 0], [-1, 0, 0, 0]]
     # ... the first `pair_front` of them in one product, a tile behind them
     ahead = dataclasses.replace(few, pair_front=64)
     loads = jnp.asarray([[3, 0, 40, 2], [0, 0, 0, 0], [60, 0, 40, 0]], jnp.int32)
-    rows = np.asarray(lm._with_counts(ahead, loads, None, 1, POSITIONS + 2))
+    rows = np.asarray(lm.model._with_counts(ahead, loads, None, 1, POSITIONS + 2))
     assert rows[:, held + 1].tolist() == [64 + 512, 64 + 512, 128 + 512]
 
 
@@ -218,11 +218,11 @@ def test_the_front_of_the_held_pairs_is_the_blocks_layer(front, lead, routing,
     gathers every pair of a token or adds those behind the first in blocks
     (`SUM_LEAD` 1 of the 2 a token has here: two blocks of 48, the second
     padded, where every token has two held pairs)."""
-    monkeypatch.setattr(lm, "SUM_LEAD", lead)
-    monkeypatch.setattr(lm, "SUM_BLOCK", 48)
+    monkeypatch.setattr(lm.experts, "SUM_LEAD", lead)
+    monkeypatch.setattr(lm.experts, "SUM_BLOCK", 48)
     few = dataclasses.replace(TINY, n_experts=16, pair_block=32)
     ahead = dataclasses.replace(few, pair_front=front)
-    assert lm.front_pairs(few, 128) == 0 and lm.front_pairs(ahead, 128) == front
+    assert lm.experts.front_pairs(few, 128) == 0 and lm.experts.front_pairs(ahead, 128) == front
     w = jax.eval_shape(lm.FrozenBaseLM(num_classes=VOCAB, arch=few).init_base)
     w = jax.tree_util.tree_map(
         lambda a: 0.1 * jax.random.normal(jax.random.key(a.size), a.shape,
@@ -369,7 +369,7 @@ def test_only_the_first_dims_of_a_head_turn(monkeypatch):
         seen.update(q=q, k=k, v=v, window=window, scale=scale, sinks=sinks)
         return jnp.zeros(q.shape[:3] + (v.shape[-1],), jnp.float32)
 
-    monkeypatch.setattr(lm, "grouped_heads", heads)
+    monkeypatch.setattr(lm.attention, "grouped_heads", heads)
     base = jax.tree_util.tree_map(
         lambda s: 0.1 * jax.random.normal(jax.random.key(len(s)), s.shape, s.dtype),
         jax.eval_shape(lm.FrozenBaseLM(VOCAB, TINY).init_base))
@@ -387,7 +387,7 @@ def test_only_the_first_dims_of_a_head_turn(monkeypatch):
         assert (seen["sinks"] is None) == (kind == 0)
         assert seen["scale"] == pytest.approx(dq ** -0.5)
         for name, heads_ in (("q", 4), ("k", kv)):
-            plain = lm._mm(x, w[name]).reshape(1, 16, heads_, dq)
+            plain = lm.common._mm(x, w[name]).reshape(1, 16, heads_, dq)
             assert jnp.array_equal(seen[name][..., dr:], plain[..., dr:])
             assert jnp.allclose(seen[name][:, 0], plain[:, 0], atol=1e-6)
             assert not jnp.allclose(seen[name][:, 5, :, :dr], plain[:, 5, :, :dr],
@@ -396,11 +396,11 @@ def test_only_the_first_dims_of_a_head_turn(monkeypatch):
             pair = lambda t: t[..., :dr // 2] ** 2 + t[..., dr // 2:dr] ** 2  # noqa: E731
             assert jnp.allclose(pair(seen[name]), pair(plain), rtol=1e-4,
                                 atol=1e-6)
-        assert jnp.allclose(seen["v"], TINY.value_scale * lm._mm(
+        assert jnp.allclose(seen["v"], TINY.value_scale * lm.common._mm(
             x, w["v"]).reshape(1, 16, kv, -1))
     # the two kinds turn at different bases
-    a = lm.rope(x[..., :dr].reshape(1, 16, 1, dr), TINY.rope_thetas[0], None, False)
-    b = lm.rope(x[..., :dr].reshape(1, 16, 1, dr), TINY.rope_thetas[1], None, False)
+    a = lm.common.rope(x[..., :dr].reshape(1, 16, 1, dr), TINY.rope_thetas[0], None, False)
+    b = lm.common.rope(x[..., :dr].reshape(1, 16, 1, dr), TINY.rope_thetas[1], None, False)
     assert not jnp.allclose(a, b, atol=1e-3)
 
 
@@ -483,7 +483,7 @@ def test_the_two_shares_add_up_to_the_uncut_layer(ref):
         jnp.std(uncut))
     # and the layer is its routed part alone
     flat = x.reshape(1, 40, -1)
-    y, _, _ = lm.expert_layer(TINY, {"experts": {
+    y, _, _ = lm.experts.expert_layer(TINY, {"experts": {
         k: v[:held] for k, v in w["experts"].items()}, "bias": w["bias"]},
         router, flat)
     assert jnp.allclose(y[0], parts[0], rtol=1e-5, atol=1e-6)
@@ -656,7 +656,7 @@ def test_the_older_models_traced_programs_are_unchanged(name, positions, digest)
     """The loss and its gradient of the two DeepSeek-V3-shaped presets,
     traced: deepseek's digest is PR 42's own, read from its tree (its
     attention layers pack and name their selection and their checkpoint
-    keeps it and the kernel's output and log-sum-exp, `lm._kept`: the one
+    keeps it and the kernel's output and log-sum-exp, `lm.model._kept`: the one
     change to its program since commit c298e53, PR 32: 9181a7b43929e507
     before); the three other digests stood through that change, which is
     the proof that `_kept` answers those models as before. joyai's is PR

@@ -95,7 +95,7 @@ def test_grouped_expert_product_compiles_for_described_v5e(
     experts), frozen matrix read as it lies in both directions."""
     from hefl_tpu.models import lm
 
-    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    monkeypatch.setattr(lm.common, "_interpret", lambda: False)
     rows, held = 2 * 4096 * 8, 128
     w_shape = (held, n, k) if transpose else (held, k, n)
     args = [
@@ -107,7 +107,7 @@ def test_grouped_expert_product_compiles_for_described_v5e(
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = jax.jit(
-            lambda x, w, s: lm._gmm_call(x, w, s, transpose)
+            lambda x, w, s: lm.experts._gmm_call(x, w, s, transpose)
         ).lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
@@ -135,12 +135,12 @@ def test_one_block_expert_layer_moves_its_rows_by_gathers_on_a_described_v5e(
     PERF.md section 6, PR 40)."""
     from hefl_tpu.models import lm
 
-    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    monkeypatch.setattr(lm.common, "_interpret", lambda: False)
     arch = lm.PRESETS["joyai_llm_flash"]
     t, k, d, f, held = (8192, arch.experts_per_tok, arch.hidden,
                         arch.moe_intermediate, arch.held_experts)
     pairs = t * k
-    assert lm.pair_blocks(arch, pairs) == (1, 65536)
+    assert lm.experts.pair_blocks(arch, pairs) == (1, 65536)
     on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=one_chip)
     w = {"gate_up": on_chip((held, d, 2 * f), jnp.bfloat16),
@@ -183,7 +183,7 @@ def test_fused_attention_gradient_compiles_for_described_v5e(
     layout changes are under 4)."""
     from hefl_tpu.models import lm
 
-    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    monkeypatch.setattr(lm.common, "_interpret", lambda: False)
     arch = lm.PRESETS["joyai_llm_flash"]
     dq = arch.qk_nope_head_dim + arch.qk_rope_head_dim
     args = [jax.ShapeDtypeStruct((2, 4096, arch.heads, d), jnp.bfloat16,
@@ -216,7 +216,7 @@ def test_linear_recurrence_and_sort_free_experts_compile_for_described_v5e(
     wherever one stands)."""
     from hefl_tpu.models import lm
 
-    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    monkeypatch.setattr(lm.common, "_interpret", lambda: False)
     arch = lm.PRESETS["ling_3_flash"]
     shape = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         s, dtype, sharding=one_chip)
@@ -233,7 +233,7 @@ def test_linear_recurrence_and_sort_free_experts_compile_for_described_v5e(
              "down": shape(n, f, d, dtype=jnp.bfloat16)}
         experts = jax.jit(jax.grad(
             lambda w, x, key, pw: jnp.sum(
-                lm._held_counted(arch, w, x, key, pw, at=2)[0] ** 2),
+                lm.experts._held_counted(arch, w, x, key, pw, at=2)[0] ** 2),
             (1, 3))).lower(w, shape(t, d), shape(t * k, dtype=jnp.int32),
                            shape(t, k)).compile()
     finally:
@@ -247,7 +247,7 @@ def test_linear_recurrence_and_sort_free_experts_compile_for_described_v5e(
 
 
 def test_linear_front_kernels_compile_for_described_v5e(one_chip, monkeypatch):
-    """A linear layer's front at Ling-3.0-flash's widths (`lm._kda_front`
+    """A linear layer's front at Ling-3.0-flash's widths (`lm.kda._kda_front`
     over `made` f32[1, 8192, 20480]: 32 heads of 128, chunks of 64, taps of
     4): the forward kernel and the gradient's lower through Mosaic
     (sublane rolls of 136- and 144-row tiles, a halo block of 8 rows on
@@ -259,9 +259,9 @@ def test_linear_front_kernels_compile_for_described_v5e(one_chip, monkeypatch):
     bitcasts of the loops' results."""
     from hefl_tpu.models import lm
 
-    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    monkeypatch.setattr(lm.common, "_interpret", lambda: False)
     arch = lm.PRESETS["ling_3_flash"]
-    assert lm.kda_front_kernel(arch)
+    assert lm.kda.kda_front_kernel(arch)
     h, d, width, s = arch.heads, arch.kda_head_dim, arch.hidden, 8192
     shape = lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         s, dtype, sharding=one_chip)
@@ -271,11 +271,11 @@ def test_linear_front_kernels_compile_for_described_v5e(one_chip, monkeypatch):
          "o": shape(h * d, width, dtype=jnp.bfloat16)}
     g = {"A_log": shape(h), "dt_bias": shape(h * d), "o_norm": shape(d)}
     layer = jax.checkpoint(lambda g, x, w: lm.kda_layer(arch, w, g, x),
-                           policy=lm._kept(arch))
+                           policy=lm.model._kept(arch))
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        front = jax.jit(lambda *a: lm._kda_front(arch, *a)).lower(
+        front = jax.jit(lambda *a: lm.kda._kda_front(arch, *a)).lower(
             shape(1, s, 5 * h * d), w["conv"], g["A_log"], g["dt_bias"]).compile()
         grad = jax.jit(jax.grad(lambda g, x, w: jnp.sum(layer(g, x, w) ** 2),
                                 (0, 1))).lower(g, shape(1, s, width), w).compile()
@@ -309,7 +309,7 @@ def test_grouped_attention_gradient_compiles_for_described_v5e(
     KV head's float32 dq a key block in the global kind."""
     from hefl_tpu.models import lm
 
-    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    monkeypatch.setattr(lm.common, "_interpret", lambda: False)
     arch = lm.PRESETS["mimo_v2_flash"]
     dq, s = arch.qk_nope_head_dim + arch.qk_rope_head_dim, 8192
     shape = lambda *dims, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
@@ -376,7 +376,7 @@ def test_sparse_round_program_fits_a_described_v5e(one_chip, monkeypatch):
     described chip: the selection's attention and the grouped product lower
     through Mosaic. Since PR 42 the six attention layers keep their packed
     selection and their kernel's output and log-sum-exp for the gradient
-    (`lm._kept`), so the forward kernel with residuals has six call sites
+    (`lm.model._kept`), so the forward kernel with residuals has six call sites
     (the step's forward; twelve with nothing kept), validation's without
     residuals six and the gradient's six, and arguments (the 7.84 GB base),
     outputs and temporaries read 16.67 GB by `memory_analysis()` (13.94
@@ -395,7 +395,7 @@ def test_sparse_round_program_fits_a_described_v5e(one_chip, monkeypatch):
     from hefl_tpu.fl import TrainConfig
     from hefl_tpu.models import lm
 
-    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    monkeypatch.setattr(lm.common, "_interpret", lambda: False)
     # the program's base is its last argument: no 7.84 GB made here
     monkeypatch.setattr(fedavg, "with_frozen_base", lambda module, fn: fn)
     mesh = Mesh(np.array([one_chip._device]), ("clients",))
@@ -456,7 +456,7 @@ def test_window_round_program_fits_a_described_v5e(one_chip, monkeypatch):
     from hefl_tpu.fl import TrainConfig
     from hefl_tpu.models import lm
 
-    monkeypatch.setattr(lm, "_interpret", lambda: False)
+    monkeypatch.setattr(lm.common, "_interpret", lambda: False)
     # the program's base is its last argument: no 6.85 GB made here
     monkeypatch.setattr(fedavg, "with_frozen_base", lambda module, fn: fn)
     mesh = Mesh(np.array([one_chip._device]), ("clients",))
