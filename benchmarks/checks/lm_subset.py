@@ -140,6 +140,7 @@ def _ref_call(ref, conf, variant, which: int, p, base, tokens):
                    (out[0] if which == VG else out)[1][2]["max_load"])
         if load <= cap:
             return out
+        del out   # 0.7 GB a sequence at joyai's size, before the next rung
     raise RuntimeError(f"one expert was routed {load} of {t} tokens, more "
                        f"than {CAP_LADDER[-1]} times the mean: widen CAP_LADDER")
 
@@ -237,10 +238,16 @@ def model_numbers(module, ref, conf, variables, tokens, variant=None) -> dict:
     # the system (or its stand-in) on the whole batch
     if variant is None:
         sys_vg, sys_logits = _sys_fns(module)
-        l_sys, g_sys = sys_vg(p, base, tokens)
-        z1, z2, (loads, sel_sys) = sys_logits(p, base, tokens)
+        # What the system returns waits on the host, the reference's gradient
+        # is summed there and a sequence's arrays go at the end of its turn
+        # below, so that the chip holds the base alone when a reference
+        # program loads: the gradient at the ladder's second rung reserves
+        # 6.71 GB at the bottom of a chip of 16.9 beside the 6.64 GB base,
+        # and with the batch's outputs there 6.07 were free (PERF.md, PR 45).
+        l_sys, g_sys = jax.device_get(sys_vg(p, base, tokens))
+        z1, z2, (loads, sel_sys) = jax.device_get(sys_logits(p, base, tokens))
         dropped = int(np.sum(np.abs(
-            np.asarray(held_pairs(sel_sys)) - np.asarray(loads).sum(-1))))
+            np.asarray(held_pairs(sel_sys)) - loads.sum(-1))))
     else:
         # forward only: a stand-in's gradient is not read
         dropped, g_sys = 0, None
@@ -252,8 +259,9 @@ def model_numbers(module, ref, conf, variables, tokens, variant=None) -> dict:
     for i in range(n):
         seq = tokens[i:i + 1]
         (_, (r1, r2, aux)), g = call(None, VG, p, base, seq)
+        g = jax.device_get(g)
         g_ref = g if g_ref is None else jax.tree_util.tree_map(
-            jnp.add, g_ref, g)
+            np.add, g_ref, g)
         if variant is None:
             s1, s2 = z1[i:i + 1], z2[i:i + 1]
             sel = sel_sys[:, i * t_seq:(i + 1) * t_seq]
@@ -271,6 +279,8 @@ def model_numbers(module, ref, conf, variables, tokens, variant=None) -> dict:
         worst_fp8 = max(worst_fp8, float(err(f1, r1, f_mask)),
                         float(err(f2, r2, f_mask)))
         ce_parts.append([float(v) for v in ce(s1, s2, seq)])
+        del _, r1, r2, aux, s1, s2, sel, sel_router, share_fwd, mask
+        del f1, f2, f_aux, f_mask
     g_ref = jax.tree_util.tree_map(lambda a: a / n, g_ref)
     want = float(np.mean([a + conf["held"]["mtp_loss_weight"] * b
                           for a, b in ce_parts]))

@@ -1,4 +1,4 @@
-"""Model (`models/lm.select_keys`): (query, key) pairs the indexers selected
+"""Model (`models/lm/attention.select_keys`): (query, key) pairs the indexers selected
 over the causal pairs they selected from, every attention layer of the last
 evaluation forward, percent: the program's gauge `dsa.selected_share`,
 counted from the selections themselves. min(t + 1, index_topk) keys a query:

@@ -1,4 +1,4 @@
-"""Model (`models/lm.causal_attention`): attention layers of the last traced
+"""Model (`models/lm/attention.causal_attention`): attention layers of the last traced
 forward that went through the fused Pallas kernel (no float32 score block in
 HBM): the program's gauge `model.fused_attention_layers`. 6 for the
 `joyai-llm-flash-l5e128` cut (1 dense + 4 expert layers + the prediction

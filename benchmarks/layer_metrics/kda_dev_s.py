@@ -1,4 +1,4 @@
-"""Token model (`models/lm.kda_layer`): device seconds per traced round of the
+"""Token model (`models/lm/kda.kda_layer`): device seconds per traced round of the
 training step's ops under `hefl.kda` (inside `hefl.sgd_core`: a part of
 `sgd_dev_s`; validation's and evaluation's are in `val_dev_s` and
 `evaluate_dev_s`): the linear-attention layers whole (projections, short

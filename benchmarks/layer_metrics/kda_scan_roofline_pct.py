@@ -1,4 +1,4 @@
-"""Token model (`models/lm.kda_recurrence`): the time the recurrence of a
+"""Token model (`models/lm/kda.kda_recurrence`): the time the recurrence of a
 round's trained tokens must take on this chip over the time it took
 (`kda_scan_dev_s`), percent, never clamped. The time it must take is the
 larger of its floating-point work over the chip's bf16 peak and its bytes

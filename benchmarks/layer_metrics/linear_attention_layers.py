@@ -1,4 +1,4 @@
-"""Model (`models/lm.hybrid_layers`): layers of the last traced forward whose
+"""Model (`models/lm/model.hybrid_layers`): layers of the last traced forward whose
 attention is the delta-rule recurrence: the program's gauge
 `model.linear_attention_layers`. 5 for the `ling-3-flash-l6e128` cut
 (published layers 1-6: five linear layers to one latent layer); a model

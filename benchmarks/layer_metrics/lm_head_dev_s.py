@@ -1,4 +1,4 @@
-"""Token model (`models/lm`): device seconds per traced round of the training
+"""Token model (`models/lm/`): device seconds per traced round of the training
 step's ops under `hefl.lm_head` or `hefl.mtp` (inside `hefl.sgd_core`: a
 part of `sgd_dev_s`) and under no attention or expert-layer scope: head
 logits and cross-entropy, and of the multi-token-prediction module (joyai,

@@ -1,4 +1,4 @@
-"""Token model (`models/lm`): device seconds per traced round of the training
+"""Token model (`models/lm/`): device seconds per traced round of the training
 step's ops under `hefl.moe.route`, `hefl.moe.experts` or `hefl.moe_gmm`
 (inside `hefl.sgd_core`: a part of `sgd_dev_s`): the expert layer whole
 (router, sort, grouped product, un-sort), the prediction module's own

@@ -1,4 +1,4 @@
-"""Model (`models/lm.held_experts`): rows the grouped expert product was
+"""Model (`models/lm/experts.held_experts`): rows the grouped expert product was
 given over the (token, held expert) pairs it had to compute, worst expert
 layer, in the last evaluation forward: the program's gauge
 `moe.rows_over_held_pairs`. A chip that holds 8 of 256 experts and gave the

@@ -1,4 +1,4 @@
-"""Model (`models/lm.frozen_base`): seconds of set-up spent making the frozen
+"""Model (`models/lm/model.frozen_base`): seconds of set-up spent making the frozen
 base on the device from the seed, leaf by leaf in bfloat16: the sum of the
 `hefl.setup.base` spans that ended before the window opened (a process
 makes a seed's base once; the later calls' spans find it made). A program

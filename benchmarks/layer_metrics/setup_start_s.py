@@ -1,7 +1,8 @@
 """Driver layer: seconds of set-up in `run_experiment`'s start, both calls:
 the `hefl.setup.*` spans other than the data's (staging to the device,
-model, HE context, pre-flight, keys, the roofline's cost-analysis compile,
-the stream engine), summed over those that ended before the window opened."""
+model, the frozen base, HE context, pre-flight, keys, the stream engine;
+the roofline's cost-analysis compile went in PR 29), summed over those that
+ended before the window opened."""
 
 import span_metrics as sm
 

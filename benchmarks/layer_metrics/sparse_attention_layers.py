@@ -1,4 +1,4 @@
-"""Model (`models/lm.selected_attention`): attention layers of the last
+"""Model (`models/lm/attention.selected_attention`): attention layers of the last
 traced forward whose softmax ran over an indexer's selection alone: the
 program's gauge `model.sparse_attention_layers`. 6 for the
 `deepseek-v32-exp-l5e8` cut (1 dense + 4 expert layers + the prediction

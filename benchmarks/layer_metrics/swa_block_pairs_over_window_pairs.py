@@ -1,4 +1,4 @@
-"""Model (`models/lm._grouped_kernel`): the (query, key) pairs inside the
+"""Model (`models/lm/attention._grouped_kernel`): the (query, key) pairs inside the
 blocks the window layers' kernel computes over the pairs the window allows,
 sum_t min(t + 1, window): the program's gauge
 `swa.block_pairs_over_window_pairs`, set where the kernel is built from its

@@ -1,4 +1,4 @@
-"""Model (`models/lm.grouped_heads`): attention layers of the last traced
+"""Model (`models/lm/attention.grouped_heads`): attention layers of the last traced
 forward that attend a window of keys alone (a query's `window` last keys,
 its own among them): the program's gauge `model.window_attention_layers`. 5
 for the `mimo-v2-flash-l7e16` cut (published layers 0-6: five window layers

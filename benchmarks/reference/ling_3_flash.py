@@ -582,6 +582,45 @@ def kda_scan_bytes(conf, itemsize: int = 4) -> int:
     return (5 * h * d + h) * itemsize
 
 
+def kda_front_flops(conf, backward: bool = False) -> int:
+    """Floating-point operations of a linear layer's front (everything
+    between the projections' one product and the recurrence's operands) for
+    one position of one layer in one pass, from the module's equations above,
+    a transcendental counted as one operation. Forward, a channel of H d: a
+    short convolution of K taps 2 K (q, k and v); SiLU 4 (exp, add,
+    reciprocal, multiply); the L2 norm a head 3 (square, sum, scale; q and
+    k) and 2 a head (add eps, rsqrt); q's scale 1; the decay 6 (add the
+    bias, times exp(A_log), the sigmoid's 3, times the lower bound); the
+    move into chunks none: (3 (2 K + 4) + 2 x 3 + 1 + 6) H d + 4 H. Backward
+    (the forward's intermediates made again, as a pass over the product
+    alone must): a stream's convolution, sigmoid and product 2 K + 4 again,
+    the cotangent through SiLU 5 and the convolution's transpose 2 K (q, k
+    and v); through the norm 3 + 6 a channel and 4 a head (q and k); the
+    decay's 13 (the gate made again 5, its derivative 3, times the
+    cotangent and exp(A_log) 2, the two sums down the rows for dt_bias and
+    A_log 3); the output gate's cotangent is passed on:
+    (3 (4 K + 9) + 2 x 9 + 13) H d + 8 H."""
+    n = conf["num_attention_heads"] * conf["head_dim"]
+    taps = conf["short_conv_kernel_size"]
+    if backward:
+        return ((3 * (4 * taps + 9) + 2 * 9 + 13) * n
+                + 8 * conf["num_attention_heads"])
+    return ((3 * (2 * taps + 4) + 2 * 3 + 1 + 6) * n
+            + 4 * conf["num_attention_heads"])
+
+
+def kda_front_bytes(conf, backward: bool = False, itemsize: int = 4) -> int:
+    """Bytes a linear layer's front must move for one position of one layer
+    in one pass, each operand read once and each result written once in the
+    `itemsize` the program gives them (float32; the taps, exp(A_log) and
+    dt_bias are a layer's, not a position's). Forward: the product's q, k, v
+    and decay columns read, q, k, v and g written: 8 H d. Backward: those
+    four columns read again, the four cotangents and the output gate's read,
+    the product's cotangent written, five columns wide: 14 H d."""
+    n = conf["num_attention_heads"] * conf["head_dim"]
+    return (14 if backward else 8) * n * itemsize
+
+
 def forward_flops(conf, seq: int) -> dict:
     """Forward FLOPs of one token at sequence length `seq`, by the model's
     own count, whatever form computes it: the latent layer's scores and

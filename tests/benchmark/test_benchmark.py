@@ -138,17 +138,21 @@ def test_a_number_without_a_limit_is_a_fault_of_the_cells_files(
                   workload="planted.sync_tiny")
 
 
+def the_image_cells_run_the_default_check(
+        run, path, cells=("medcnn.sync_e10", "resnet20.sync_e1")) -> None:
+    for workload in cells:
+        cell = run.load_cell(path, workload)
+        assert "check" not in cell["config"]
+        assert cell["check"] == os.path.join(BENCH, "checks", "image_classifier.py")
+
+
 def test_the_default_check_and_an_unknown_one(run, tmp_path):
     """A configuration without `check` runs `image_classifier`: that default
     is what carries the three configurations that were there before the key
     (their files have none). A name with no file is refused when the cell is
     loaded, before any set-up."""
-    for bench, workload in [(TINY, "tiny.sync_tiny"),
-                            (os.path.join(ROOT, "BENCHMARK.json"), "medcnn.sync_e10"),
-                            (os.path.join(ROOT, "BENCHMARK.json"), "resnet20.sync_e1")]:
-        cell = run.load_cell(bench, workload)
-        assert "check" not in cell["config"]
-        assert cell["check"] == os.path.join(BENCH, "checks", "image_classifier.py")
+    the_image_cells_run_the_default_check(run, TINY, ("tiny.sync_tiny",))
+    the_image_cells_run_the_default_check(run, os.path.join(ROOT, "BENCHMARK.json"))
     assert run.load_cell(TINY, "planted.sync_tiny")["check"] == os.path.join(
         HERE, "tiny", "checks", "planted.py")
     with open(TINY) as f:
@@ -362,6 +366,10 @@ _UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 @pytest.mark.parametrize("path", [os.path.join(ROOT, "BENCHMARK.json"), TINY])
 def test_benchmark_json_keeps_the_contract(run, path):
+    keeps_the_contract(run, path)
+
+
+def keeps_the_contract(run, path) -> None:
     with open(path) as f:
         bench = json.load(f)
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
@@ -412,6 +420,10 @@ def test_benchmark_json_keeps_the_contract(run, path):
             "device_trace", "program_span", "program_counter", "host_clock")
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert _UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+
+
+HELD = (keeps_the_contract,     # of a copy with additions too:
+        the_image_cells_run_the_default_check)  # `test_benchmark_additions.py`
 
 
 def test_a_mix_may_set_any_experiment_field(run):

@@ -38,7 +38,10 @@ CONTROLS = {"control_fp8", "control_router_bf16", "control_dropped_expert",
             "control_gate_a_channel", "control_no_gate",
             "control_kinds_exchanged"}
 READERS = ("kda_dev_s", "kda_scan_dev_s", "linear_attention_layers",
-           "kda_scan_roofline_pct")
+           "kda_scan_roofline_pct", "kda_front_kernel_layers",
+           "kda_front_kernel_dev_s", "kda_front_roofline_pct")  # listed: PR 45
+OLDER = ("medcnn.sync_e10", "resnet20.sync_e1", "joyai-flash.sync_s4k",
+         "deepseek-v32.sync_s8k", "mimo-v2-flash.sync_s8k")  # cells before it
 JOINED = ("sgd_dev_s", "val_dev_s", "he_round_dev_s", "evaluate_dev_s",
           "decrypt_ops_dev_s", "unscoped_dev_share", "attention_dev_s",
           "attention_kernel_dev_s", "moe_dev_s", "moe_gmm_dev_s",
@@ -75,64 +78,84 @@ def test_the_check_the_reference_and_the_readers_are_found_by_name(
         assert callable(getattr(check, fn))
     ref = cell["module"]("reference", cell["config"]["reference"])
     for fn in ("init", "forward", "loss", "forward_flops", "kda_scan_flops",
-               "kda_scan_bytes", "block", "delta_rule"):
+               "kda_scan_bytes", "kda_front_flops", "kda_front_bytes", "block",
+               "delta_rule"):
         assert callable(getattr(ref, fn))
     assert set(cell["config"]["limits"]) == NUMBERS
     for m in cell["per_layer"]:
         assert callable(cell["module"]("layer_metrics", m["name"]).read)
     assert set(READERS) <= {m["name"] for m in cell["per_layer"]}
-    # the benchmark's own cell: the same check, the limits naming exactly the
-    # numbers it gives, a reason beside each, the older lists it joined, the
-    # mix the benchmark had, and one chip; the four readers this model brings
-    # have no entry there yet (the test below says why)
-    bench = os.path.join(ROOT, "BENCHMARK.json")
-    real = run.load_cell(bench, REAL)
-    assert real["check"] == cell["check"]
+    lings_cell_is_found_by_name(run, os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def lings_cell_is_found_by_name(run, path) -> None:
+    """The benchmark's own cell, looked up by name in the BENCHMARK.json at
+    `path`, wherever it stands among the cells: the same check, the limits
+    naming exactly the numbers it gives, a reason beside each, the older
+    lists it joined, the mix the benchmark had, one chip, and the seven
+    readers this model brings each with an entry that names this cell and
+    none of the cells that were there before it."""
+    real = run.load_cell(path, REAL)
+    assert real["check"] == os.path.join(BENCH, "checks", "lm_linear_subset.py")
     assert set(real["config"]["limits"]) == NUMBERS
     assert NUMBERS <= set(real["config"]["limit_reasons"])
     assert real["cell"]["chips"] == 1 and len(real["cell"]["why"]) <= 200
     assert real["cell"]["traffic"] == "sync_s8k"
     names = {m["name"] for m in real["per_layer"]}
-    assert set(JOINED) | {"train_mfu", "peak_hbm_gb"} <= names
-    with open(bench) as f:
+    assert set(JOINED) | set(READERS) | {"train_mfu", "peak_hbm_gb"} <= names
+    with open(path) as f:
         whole = json.load(f)
-    assert not set(READERS) & {m["name"] for m in whole["per_layer"]}
-    assert whole["workloads"][-1]["name"] == REAL
-    assert whole["configs"][-1]["reduced"] == real["config"]["reduced"]
+    entries = {m["name"]: m for m in whole["per_layer"]}
+    for name in READERS:
+        assert REAL in entries[name]["workloads"]
+        assert not set(OLDER) & set(entries[name]["workloads"])
+    conf = next(c for c in whole["configs"]
+                if c["name"] == real["cell"]["config"])
+    assert conf["reduced"] == real["config"]["reduced"]
 
 
-def test_every_per_layer_entry_has_a_reader_and_names_cells_that_exist():
-    """BENCHMARK.json beside `test_device_scopes.py`, which holds PR 36's
-    eighteen entries to be the file's last and two of their lists to name
-    every cell, while the driver takes a new entry at a list's end alone (it
-    refused this PR's four in front of that block as a change to
-    `sgd_dev_s`). So the four readers this PR brings wait, without an entry,
-    for a `benchmark` PR that may edit that test; the new cell is the last
-    of every list whose reader finds something to read in it (the chip's
-    traced run, PERF.md), and every entry has a reader and names cells that
-    exist."""
+def every_entry_has_a_reader_and_names_cells_that_exist(run, path) -> None:
+    """Of the BENCHMARK.json at `path`, whatever later PRs appended to it:
+    PR 36's eighteen entries are one block found by name
+    (`test_device_scopes.py`), every entry has a reader under `paths` and
+    names cells that exist, each once; ling's cell is in every older list
+    whose reader finds something to read in it (the chip's traced run,
+    PERF.md) and in its own readers' lists, and in no other list that was
+    there when its readers were listed. What is appended after them is the
+    appending PR's to hold."""
     scopes = importlib.util.spec_from_file_location(
         "_scopes_test", os.path.join(HERE, "test_device_scopes.py"))
     older = importlib.util.module_from_spec(scopes)
     scopes.loader.exec_module(older)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    older.the_eighteen_are_one_block_and_name_cells_by_kind(run, path)
+    with open(path) as f:
         bench = json.load(f)
     cells = [w["name"] for w in bench["workloads"]]
     names = [m["name"] for m in bench["per_layer"]]
-    assert cells[-1] == REAL
-    assert names[-len(older.NEW):] == list(older.NEW)
-    assert not set(READERS) & set(names)
-    for name in READERS:
-        assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".py"))
-    for m in bench["per_layer"]:
-        assert os.path.exists(os.path.join(BENCH, "layer_metrics",
-                                           m["name"] + ".py"))
+    assert REAL in cells and len(set(cells)) == len(cells)
+    assert set(READERS) <= set(names)
+    mine = max(names.index(name) for name in READERS)
+    for at, m in enumerate(bench["per_layer"]):
+        assert os.path.exists(run._find(bench["paths"], "layer_metrics",
+                                        m["name"] + ".py"))
         listed = m.get("workloads", cells)
         assert set(listed) <= set(cells) and len(set(listed)) == len(listed)
         if m["name"] in JOINED:
-            assert listed[-1] == REAL and len(listed) > 1
-        else:
+            assert REAL in listed and len(listed) > 1
+        elif m["name"] in READERS:
+            assert REAL in listed
+        elif at < mine:
             assert "workloads" not in m or REAL not in listed
+
+
+HELD = (lings_cell_is_found_by_name,    # of a copy with additions too:
+        every_entry_has_a_reader_and_names_cells_that_exist)
+# `test_benchmark_additions.py`
+
+
+def test_every_per_layer_entry_has_a_reader_and_names_cells_that_exist(run):
+    every_entry_has_a_reader_and_names_cells_that_exist(
+        run, os.path.join(ROOT, "BENCHMARK.json"))
 
 
 def test_a_rounds_work_counts_the_recurrence_by_the_models_own_count(
@@ -157,6 +180,36 @@ def test_a_rounds_work_counts_the_recurrence_by_the_models_own_count(
     assert work["train_flops_per_round"] == pytest.approx(57.9e12, rel=5e-3)
 
 
+def test_the_fronts_counts_at_the_published_widths(run):
+    """`kda_front_flops` / `kda_front_bytes` by hand at H d = 32 x 128 = 4,096
+    channels and 4 taps, a position a layer a pass, and the passes of the
+    benchmark's own round; the bytes bound the time both ways."""
+    conf = run._module_at(os.path.join(
+        BENCH, "layer_metrics", "kda_scan_roofline_pct.py")).configuration()
+    front = run._module_at(os.path.join(
+        BENCH, "layer_metrics", "kda_front_roofline_pct.py"))
+    ref = run._module_at(os.path.join(BENCH, "reference", "ling_3_flash.py"))
+    assert (conf["num_attention_heads"], conf["head_dim"],
+            conf["short_conv_kernel_size"], conf["positions"]) == (32, 128, 4, 8192)
+    # forward: three convolutions of 4 taps (8) and SiLU (4), two head norms
+    # (3 a channel, 2 a head), q's scale (1), the decay (6)
+    assert ref.kda_front_flops(conf) == (
+        3 * 12 + 2 * 3 + 1 + 6) * 4096 + 4 * 32 == 200_832
+    # backward: made again, through SiLU and the transposed taps (25) three
+    # times, through two norms (9 a channel, 4 a head), the decay's 13
+    assert ref.kda_front_flops(conf, backward=True) == (
+        3 * 25 + 2 * 9 + 13) * 4096 + 8 * 32 == 434_432
+    assert ref.kda_front_bytes(conf) == 8 * 4096 * 4 == 131_072
+    assert ref.kda_front_bytes(conf, backward=True) == 14 * 4096 * 4 == 229_376
+    assert front.passes(conf, 2) == (35, 10)
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    for back in (False, True):      # a pass: 1.311 ms and 2.294 ms by bytes
+        by_bytes = ref.kda_front_bytes(conf, back) / 819e9
+        assert by_bytes > 100 * ref.kda_front_flops(conf, back) / 197e12
+    assert front.must_take_s(conf, 2, peaks) == pytest.approx(
+        35 * 1.3110e-3 + 10 * 2.2943e-3, rel=1e-4)
+
+
 def test_tiny_linear_cell_end_to_end(run, monkeypatch, tmp_path, capsys):
     monkeypatch.setenv("HEFL_EVENTS", "1")
     result = run.run_cell(TINY, CELL, 4100000007, 1.0, False,
@@ -179,6 +232,7 @@ def test_tiny_linear_cell_end_to_end(run, monkeypatch, tmp_path, capsys):
     assert counters["model.linear_attention_layers"] == 3
     assert counters["model.gated_attention_layers"] == 1
     assert counters["model.fused_attention_layers"] == 1
+    assert counters["model.kda_front_kernel_layers"] == 0   # heads of 16: XLA's
     assert 1.0 <= counters["moe.rows_over_held_pairs"] < 8.0
     check_round = next(ln["check_round"] for ln in lines if "check_round" in ln)
     assert check_round["ciphertext_rows_a_client"] == 16
@@ -191,17 +245,27 @@ def test_tiny_linear_cell_end_to_end(run, monkeypatch, tmp_path, capsys):
     assert reader.read({}, None) == 3.0
     obs_metrics.gauge("model.linear_attention_layers").set(0)
     assert reader.read({}, None) is None
-    # no device trace on the CPU: the three that read one say nothing
-    for name in ("kda_dev_s", "kda_scan_dev_s", "kda_scan_roofline_pct"):
+    # the front of this preset (heads of 16) is XLA's: the count of kernel
+    # layers is 0 and left out; where the kernel pair runs it is the gauge
+    reader = run.load_cell(TINY, CELL)["module"](
+        "layer_metrics", "kda_front_kernel_layers")
+    assert reader.read({}, None) is None
+    obs_metrics.gauge("model.kda_front_kernel_layers").set(3)
+    assert reader.read({}, None) == 3.0
+    obs_metrics.gauge("model.kda_front_kernel_layers").set(0)
+    # no device trace on the CPU: the five that read one say nothing
+    for name in ("kda_dev_s", "kda_scan_dev_s", "kda_scan_roofline_pct",
+                 "kda_front_kernel_dev_s", "kda_front_roofline_pct"):
         reader = run.load_cell(TINY, CELL)["module"]("layer_metrics", name)
         assert reader.read({"samples_per_round": 2, "peaks": {}}, None) is None
 
 
 def test_the_new_readers_on_a_recorded_trace(run, monkeypatch, tmp_path):
     """The chip's recorded trace (a round of the image model: no linear
-    layer in it) leaves all three out; with the recurrence's scopes among its
-    paths they read them, inside the training step alone, and the roofline
-    share is the model's own count over what they read."""
+    layer in it) leaves all five out; with the recurrence's scopes among its
+    paths and the front's kernels among its families they read them, the
+    scopes inside the training step alone and the kernels wherever they ran,
+    and each roofline share is the model's own count over what was read."""
     import device_scopes
     from hefl_tpu.obs import events
 
@@ -218,7 +282,8 @@ def test_the_new_readers_on_a_recorded_trace(run, monkeypatch, tmp_path):
         BENCH, "layer_metrics", name + ".py")).read(record, trace)
     try:
         assert device_scopes.record(trace) is not None
-        for name in ("kda_dev_s", "kda_scan_dev_s", "kda_scan_roofline_pct"):
+        for name in ("kda_dev_s", "kda_scan_dev_s", "kda_scan_roofline_pct",
+                     "kda_front_kernel_dev_s", "kda_front_roofline_pct"):
             assert read(name) is None
         rec = device_scopes.record(trace)
         rec["paths"].update({
@@ -232,6 +297,20 @@ def test_the_new_readers_on_a_recorded_trace(run, monkeypatch, tmp_path):
         must = 3 * 5 * 2 * 8192 * 82048 / 819e9
         assert read("kda_scan_roofline_pct") == pytest.approx(100 * must / 0.25)
         assert 0 < read("kda_scan_roofline_pct") < 100
+        # the front's kernel pair, by family, wherever the calls ran (two
+        # traced rounds: 70 forward calls, 20 backward); another kernel's
+        # family is not read
+        rec["families"].update({
+            "kda_front_fwd": {"device_seconds": 0.12, "op_events": 70},
+            "kda_front_bwd": {"device_seconds": 0.10, "op_events": 20},
+            "kda_frontier": {"device_seconds": 9.0, "op_events": 1}})
+        assert read("kda_front_kernel_dev_s") == pytest.approx(0.11)
+        # a round of 2 trained sequences: 5 layers x (2 x 2 + 2 validated + 1
+        # evaluated) = 35 forward passes of 8 H d float32 a position and 5 x
+        # 2 = 10 backward passes of 14 H d, over 819 GB/s
+        must = 8192 * 4096 * 4 * (35 * 8 + 10 * 14) / 819e9
+        assert read("kda_front_roofline_pct") == pytest.approx(100 * must / 0.11)
+        assert 0 < read("kda_front_roofline_pct") < 100
     finally:
         device_scopes._attribution.cache_clear()
 

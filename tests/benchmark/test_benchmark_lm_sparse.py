@@ -29,6 +29,10 @@ NUMBERS = {"logit_err_vs_fp8", "logit_err_late_vs_fp8", "route_agree_share",
            "selected_count_gap", "loss_gap", "grad_norm_gap", "dropped_pairs",
            "step_norm_gap", "leaf_step_gap", "val_loss_gap", "he_avg_err",
            "base_moved"}
+READERS = ("dsa_selected_share", "sparse_attention_layers",
+           "dsa_kept_selection_layers", "dsa_kept_attention_layers")
+JOINED = ("encrypt_rows", "setup_base_s", "moe_load_max_over_mean",
+          "fused_attention_layers")     # joyai's lists, joined in PR 45
 
 
 @pytest.fixture(scope="module")
@@ -63,29 +67,38 @@ def test_the_check_the_reference_and_the_readers_are_found_by_name(
     assert set(cell["config"]["limits"]) == NUMBERS
     for m in cell["per_layer"]:
         assert callable(cell["module"]("layer_metrics", m["name"]).read)
-    # the benchmark's own cell: the same check, limits of its own, the three
-    # readers this model brings listed for it alone, and one chip
-    real = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"), REAL)
-    assert real["check"] == cell["check"]
+    deepseeks_cell_is_found_by_name(run, os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def deepseeks_cell_is_found_by_name(run, path) -> None:
+    """The benchmark's own cell, looked up by name in the BENCHMARK.json at
+    `path`: the same check, limits of its own, one chip, the readers this
+    model brings (PR 31's three, PR 42's two kept-for-the-gradient counts:
+    listed since PR 45), the four lists of the first token model's metrics
+    that it joined (PR 45: the program sets all four here), and no cell
+    without an indexer in the lists of what an indexer alone sets."""
+    real = run.load_cell(path, REAL)
+    assert real["check"] == os.path.join(BENCH, "checks", "lm_sparse_subset.py")
     assert set(real["config"]["limits"]) == NUMBERS
     assert real["cell"]["chips"] == 1 and len(real["cell"]["why"]) <= 200
     names = {m["name"] for m in real["per_layer"]}
-    assert {"dsa_selected_share", "sparse_attention_layers",
-            "moe_rows_over_held_pairs", "train_mfu", "peak_hbm_gb"} <= names
-    # the lists of the first token model's metrics are as they were
-    assert not names & {"encrypt_rows", "setup_base_s",
-                        "moe_load_max_over_mean", "fused_attention_layers"}
-    joyai = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"),
-                          "joyai-flash.sync_s4k")
-    assert not {m["name"] for m in joyai["per_layer"]} & {
-        "dsa_selected_share", "sparse_attention_layers",
-        "moe_rows_over_held_pairs"}
+    assert set(READERS) | set(JOINED) | {
+        "moe_rows_over_held_pairs", "train_mfu", "peak_hbm_gb"} <= names
+    for other in ("joyai-flash.sync_s4k", "mimo-v2-flash.sync_s8k",
+                  "ling-3-flash.sync_s8k"):
+        theirs = run.load_cell(path, other)
+        assert not {m["name"] for m in theirs["per_layer"]} & set(READERS)
 
 
 def test_the_benchmarks_configuration_is_the_catalogs_row(run):
+    the_configuration_is_the_catalogs_row(
+        run, os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def the_configuration_is_the_catalogs_row(run, path) -> None:
     """Every number of the catalog's `config` under its key; the three cut
     keys listed; no width among them."""
-    real = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"), REAL)["config"]
+    real = run.load_cell(path, REAL)["config"]
     published = {
         "attention_bias": False, "ep_size": 1, "first_k_dense_replace": 3,
         "hidden_size": 7168, "index_head_dim": 128, "index_n_heads": 64,
@@ -112,6 +125,11 @@ def test_the_benchmarks_configuration_is_the_catalogs_row(run):
     for key in ("source", "published", "deployment", "assumed"):
         assert real[key]
     assert "env" not in real
+
+
+HELD = (deepseeks_cell_is_found_by_name,    # of a copy with additions too:
+        the_configuration_is_the_catalogs_row)
+# `test_benchmark_additions.py`
 
 
 def test_a_rounds_work_counts_selected_pairs(run, cell, check):
@@ -160,14 +178,27 @@ def test_tiny_sparse_cell_end_to_end(run, monkeypatch, tmp_path, capsys):
     # with the routers, round by round)
     from hefl_tpu.obs import metrics as obs_metrics
 
+    # every attention layer keeps its selection and its kernel's output
+    assert counters["dsa.kept_selection_layers"] == 4
+    assert counters["dsa.kept_attention_layers"] == 4
     for name, key in (("dsa_selected_share", "dsa.selected_share"),
                       ("sparse_attention_layers", "model.sparse_attention_layers"),
+                      ("dsa_kept_selection_layers", "dsa.kept_selection_layers"),
+                      ("dsa_kept_attention_layers", "dsa.kept_attention_layers"),
                       ("moe_rows_over_held_pairs", "moe.rows_over_held_pairs")):
         reader = run.load_cell(TINY, CELL)["module"]("layer_metrics", name)
         assert reader.read({}, None) == pytest.approx(
             obs_metrics.gauge(key).value)
     assert obs_metrics.gauge("dsa.selected_share").value == pytest.approx(
         counters["dsa.selected_share"])
+    # a count that reads 0 (a model that keeps nothing, or has no indexer)
+    # is left out of the line, not reported as 0
+    for name in ("selection", "attention"):
+        reader = run.load_cell(TINY, CELL)["module"](
+            "layer_metrics", f"dsa_kept_{name}_layers")
+        assert reader.read({}, None) == 4.0
+        obs_metrics.gauge(f"dsa.kept_{name}_layers").set(0)
+        assert reader.read({}, None) is None
 
 
 def test_the_float8_stand_in_of_the_indexer_has_float8s_values(check):

@@ -34,6 +34,9 @@ CONTROLS = {"control_fp8", "control_router_bf16", "control_dropped_expert",
             "control_kinds_exchanged", "control_thetas_exchanged",
             "control_all_rotated", "control_no_value_scale",
             "control_kv_head_mod"}
+READERS = ("window_attention_layers", "swa_block_pairs_over_window_pairs")
+JOINED = ("encrypt_rows", "setup_base_s", "moe_load_max_over_mean",
+          "fused_attention_layers", "moe_rows_over_held_pairs")  # since PR 45
 
 
 @pytest.fixture(scope="module")
@@ -68,32 +71,42 @@ def test_the_check_the_reference_and_the_readers_are_found_by_name(
     assert set(cell["config"]["limits"]) == NUMBERS
     for m in cell["per_layer"]:
         assert callable(cell["module"]("layer_metrics", m["name"]).read)
-    # the benchmark's own cell: the same check, the limits naming exactly the
-    # numbers it gives, a reason beside each, the two readers this model
-    # brings listed for it alone, the mix the benchmark had, and one chip
-    real = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"), REAL)
-    assert real["check"] == cell["check"]
+    mimos_cell_is_found_by_name(run, os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def mimos_cell_is_found_by_name(run, path) -> None:
+    """The benchmark's own cell, looked up by name in the BENCHMARK.json at
+    `path`: the same check, the limits naming exactly the numbers it gives, a
+    reason beside each, the mix the benchmark had, one chip, the two readers
+    this model brings, the five lists of the older token models' metrics
+    that it joined (PR 45: the program sets all five here), none of what an
+    indexer alone sets, and no cell without window layers in its readers'
+    lists."""
+    real = run.load_cell(path, REAL)
+    assert real["check"] == os.path.join(BENCH, "checks", "lm_window_subset.py")
     assert set(real["config"]["limits"]) == NUMBERS
     assert NUMBERS <= set(real["config"]["limit_reasons"])
     assert real["cell"]["chips"] == 1 and len(real["cell"]["why"]) <= 200
     assert real["cell"]["traffic"] == "sync_s8k"
     names = {m["name"] for m in real["per_layer"]}
-    assert {"window_attention_layers", "swa_block_pairs_over_window_pairs",
-            "train_mfu", "peak_hbm_gb"} <= names
-    # the lists of the older token models' metrics are as they were
-    assert not names & {"encrypt_rows", "setup_base_s", "moe_load_max_over_mean",
-                        "fused_attention_layers", "dsa_selected_share",
-                        "sparse_attention_layers", "moe_rows_over_held_pairs"}
-    for other in ("joyai-flash.sync_s4k", "deepseek-v32.sync_s8k"):
-        theirs = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"), other)
-        assert not {m["name"] for m in theirs["per_layer"]} & {
-            "window_attention_layers", "swa_block_pairs_over_window_pairs"}
+    assert set(READERS) | set(JOINED) | {"train_mfu", "peak_hbm_gb"} <= names
+    assert not names & {"dsa_selected_share", "sparse_attention_layers",
+                        "dsa_kept_selection_layers", "dsa_kept_attention_layers"}
+    for other in ("joyai-flash.sync_s4k", "deepseek-v32.sync_s8k",
+                  "ling-3-flash.sync_s8k"):
+        theirs = run.load_cell(path, other)
+        assert not {m["name"] for m in theirs["per_layer"]} & set(READERS)
 
 
 def test_the_benchmarks_configuration_is_the_catalogs_row(run):
+    the_configuration_is_the_catalogs_row(
+        run, os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def the_configuration_is_the_catalogs_row(run, path) -> None:
     """Every number of the catalog's `config` under its key; the three cut
     keys listed; no width among them; the two lists a layer kept whole."""
-    real = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"), REAL)["config"]
+    real = run.load_cell(path, REAL)["config"]
     published = {
         "attention_value_scale": 0.707, "hidden_size": 4096,
         "intermediate_size": 16384, "max_position_embeddings": 262144,
@@ -124,6 +137,11 @@ def test_the_benchmarks_configuration_is_the_catalogs_row(run):
         assert real[key]
     assert "env" not in real
     assert real["experiment"]["dataset"] == "tokens-v19072-s8192"
+
+
+HELD = (mimos_cell_is_found_by_name,    # of a copy with additions too:
+        the_configuration_is_the_catalogs_row)
+# `test_benchmark_additions.py`
 
 
 def test_a_rounds_work_counts_the_windows_pairs(run, cell, check):

@@ -188,12 +188,32 @@ def test_a_model_layer_metric_is_a_part_of_the_step(ds, run, monkeypatch, name):
     assert token + image + CHAINS["hefl.sgd_core"] == pytest.approx(step)
 
 
-def test_every_new_entry_has_a_reader_and_names_cells_that_exist():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def cells_by_kind(bench: dict) -> tuple[set, set]:
+    """(image cells, token cells) of a loaded BENCHMARK.json, read from the
+    configurations' files: a configuration without the key `check` runs the
+    image classifier's, one whose `check` starts with `lm_` a token model's."""
+    checks = {}
+    for c in bench["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            checks[c["name"]] = json.load(f).get("check")
+    image = {w["name"] for w in bench["workloads"] if checks[w["config"]] is None}
+    token = {w["name"] for w in bench["workloads"]
+             if (checks[w["config"]] or "").startswith("lm_")}
+    return image, token
+
+
+def the_eighteen_are_one_block_and_name_cells_by_kind(run, path) -> None:
+    """What must stay true of the BENCHMARK.json at `path` however much is
+    appended after PR 36's entries: they are one block in `NEW`'s order,
+    found by name (later PRs append after it), each with a reader, and their
+    lists follow the kinds of the cells, whichever cells there are."""
+    with open(path) as f:
         bench = json.load(f)
     cells = {w["name"] for w in bench["workloads"]}
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-len(NEW):]] == list(NEW)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW[0])
+    assert names[first:first + len(NEW)] == list(NEW)
     for name in NEW:
         m = entries[name]
         assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".py"))
@@ -201,8 +221,18 @@ def test_every_new_entry_has_a_reader_and_names_cells_that_exist():
         assert (m["source"], m["moves"], m["better"]) == (
             "device_trace", "round_s", "lower")
         assert m["unit"] == ("%" if name.endswith("_share") else "s")
-    image = {"medcnn.sync_e10", "resnet20.sync_e1"}
+    image, token = cells_by_kind(bench)
+    assert image and token and not image & token
     assert set(entries["conv_dev_s"]["workloads"]) == image
     assert set(entries["adam_dev_s"]["workloads"]) == image  # PERF.md, PR 36
-    assert set(entries["attention_dev_s"]["workloads"]) == cells - image
+    assert set(entries["attention_dev_s"]["workloads"]) == token
     assert set(entries["sgd_dev_s"]["workloads"]) == cells
+
+
+HELD = (the_eighteen_are_one_block_and_name_cells_by_kind,)  # of a copy too:
+# `test_benchmark_additions.py`
+
+
+def test_every_new_entry_has_a_reader_and_names_cells_that_exist(run):
+    the_eighteen_are_one_block_and_name_cells_by_kind(
+        run, os.path.join(ROOT, "BENCHMARK.json"))
